@@ -10,6 +10,9 @@ import numpy as np
 
 from repro.core import build_ivf, true_neighbors
 from repro.data.vectors import glove_like
+from repro.utils import enable_compile_cache
+
+enable_compile_cache()
 
 # benchmark scale (1-core CPU container): see DESIGN.md §7 — relative claims
 # at 100k–200k scale; the paper's billion-scale gains extrapolate per Fig 10.

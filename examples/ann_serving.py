@@ -17,7 +17,6 @@ from repro.core import true_neighbors                          # noqa: E402
 from repro.core.distributed import (build_sharded_ivf,         # noqa: E402
                                     make_distributed_search)
 from repro.data.vectors import make_manifold                   # noqa: E402
-from repro.launch.mesh import set_mesh                         # noqa: E402
 
 
 def main():
@@ -35,7 +34,7 @@ def main():
                                     train_iters=6)
         build_s = time.time() - t0
         search = make_distributed_search(mesh, ("data",), top_t=6, final_k=10)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             jsearch = jax.jit(search)
             ids, _ = jsearch(sharded, jnp.asarray(ds.Q))   # compile
             t0 = time.time()

@@ -81,7 +81,7 @@ def _inject_f64_leak() -> List[Finding]:
         return TraceSpec(fn=lambda x: x.astype(jnp.float64).sum(),
                          args=(X,), dims={})
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         return check_contract(reg["injected_f64"])
 
 

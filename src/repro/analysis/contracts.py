@@ -42,8 +42,8 @@ from repro.analysis.jaxpr_walk import (jaxpr_outvals,  # noqa: F401
 # serving or build trace: a host round-trip inside a jit region serializes
 # the pipeline behind Python.
 HOST_CALLBACK_PRIMITIVES = frozenset({
-    "pure_callback", "io_callback", "debug_callback", "outside_call",
-    "host_callback_call", "callback",
+    "pure_callback", "io_callback", "debug_callback", "debug_print",
+    "outside_call", "host_callback_call", "callback",
 })
 
 # Shared tiny-fixture scale. N_TRACE and C_TRACE sized so contract checks

@@ -29,8 +29,8 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core.build import build_ivf_sharded, spill_plan
 from repro.core.router import FlatRouter, TreeRouter
-from repro.core.search import (_pad_topk, _search_block, dedup_topk_window,
-                               pack_ivf, window_pq_scores)
+from repro.core.search import (EXACT, _pad_topk, _search_block,
+                               dedup_topk_window, pack_ivf, window_pq_scores)
 from repro.kernels.soar_assign import assign_fused
 
 
@@ -176,8 +176,6 @@ def make_sharded_assign(mesh, axes: Tuple[str, ...], *,
     Pairs with the serving local-search paths above, which consume the
     resulting per-shard CSR.
     """
-    from jax.experimental.shard_map import shard_map
-
     eff_lam, eff_spills = spill_plan(spill_mode, lam, n_spills)
 
     def local(Xs, C):
@@ -185,8 +183,8 @@ def make_sharded_assign(mesh, axes: Tuple[str, ...], *,
                             chunk=chunk)
 
     a = axes if len(axes) > 1 else axes[0]
-    return shard_map(local, mesh=mesh, in_specs=(P(a), P()),
-                     out_specs=P(a), check_rep=False)
+    return jax.shard_map(local, mesh=mesh, in_specs=(P(a), P()),
+                         out_specs=P(a), check_vma=False)
 
 
 def abstract_sharded_ivf(n_shards: int, n_local: int, n_partitions: int,
@@ -278,8 +276,6 @@ def _shard_map_variants(local_search, mesh, spec, axes, with_filter,
     """shard_map wiring shared by both distributed search makers: the
     optional filter bitmap, router-table, and health-mask args extend
     in_specs in a fixed order (ivf, Q[, filt][, router][, health])."""
-    from jax.experimental.shard_map import shard_map
-
     a = axes if len(axes) > 1 else axes[0]
     specs = [spec, P()]
     if with_filter:
@@ -296,8 +292,8 @@ def _shard_map_variants(local_search, mesh, spec, axes, with_filter,
         health = next(it) if with_health else None
         return local_search(ivf, Q, filt, srt, health)
 
-    return shard_map(fn, mesh=mesh, in_specs=tuple(specs),
-                     out_specs=(P(), P()), check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=tuple(specs),
+                         out_specs=(P(), P()), check_vma=False)
 
 
 def _mask_unhealthy(ids, vals, health):
@@ -356,8 +352,6 @@ def make_replicated_search(mesh, axes: Tuple[str, ...], *, top_t: int,
     single-device path (same data, full coverage), flagged
     `SearchResult.degraded`.
     """
-    from jax.experimental.shard_map import shard_map
-
     if params is not None:
         p = params.validate(default_top_t=top_t,
                             default_rerank=rerank_budget)
@@ -373,8 +367,8 @@ def make_replicated_search(mesh, axes: Tuple[str, ...], *, top_t: int,
     fn = (local if with_filter
           else (lambda packed, Q: local(packed, Q)))
     specs = [P(), P(a)] + ([P()] if with_filter else [])
-    return shard_map(fn, mesh=mesh, in_specs=tuple(specs),
-                     out_specs=(P(a), P(a)), check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=tuple(specs),
+                         out_specs=(P(a), P(a)), check_vma=False)
 
 
 def _apply_params(params, top_t, final_k):
@@ -438,7 +432,8 @@ def make_distributed_search(mesh, axes: Tuple[str, ...], *, top_t: int,
         if filt is not None:
             valid = valid & (filt[0][jnp.maximum(ids, 0)] > 0)
             ids = jnp.where(valid, ids, -1)    # filtered ≡ padding for dedup
-        scores = jnp.einsum("qwd,qd->qw", rerank[jnp.maximum(ids, 0)], Q)
+        scores = jnp.einsum("qwd,qd->qw", rerank[jnp.maximum(ids, 0)], Q,
+                            precision=EXACT)
         scores = jnp.where(valid, scores, -jnp.inf)
         ids, vals = dedup_topk_window(ids, scores, final_k, multiplicity)
         # a tombstone-heavy mutable shard (sharded_from_indexes) can have a
@@ -523,7 +518,8 @@ def make_distributed_search_pq(mesh, axes: Tuple[str, ...], *, top_t: int,
             approx = approx + jnp.repeat(psc, pmax, axis=-1)
             approx = jnp.where(valid, approx, -jnp.inf)
             bi, bv = dedup_topk_window(ids, approx, rerank_k, multiplicity)
-            exact = jnp.einsum("qbd,qd->qb", rerank[jnp.maximum(bi, 0)], Qb)
+            exact = jnp.einsum("qbd,qd->qb", rerank[jnp.maximum(bi, 0)], Qb,
+                               precision=EXACT)
             exact = jnp.where(jnp.isfinite(bv), exact, -jnp.inf)
             v, pos = jax.lax.top_k(exact, min(final_k, exact.shape[-1]))
             gi, v = _pad_topk(jnp.take_along_axis(bi, pos, axis=-1), v,

@@ -45,7 +45,14 @@ import numpy as np
 
 from repro.core.ivf import IVFIndex
 from repro.core.router import FlatRouter, check_query_dim
+from repro import kernels
+from repro.kernels import note_route
 from repro.quant.pq import pq_lut, PQCodebook
+
+
+# The exact stages score in full f32 on every backend: TPU's default matmul
+# precision rounds f32 inputs to bf16, which reorders near-tied neighbours.
+EXACT = jax.lax.Precision.HIGHEST
 
 
 class SearchStats(NamedTuple):
@@ -317,7 +324,10 @@ def window_pq_scores(luts, codes):
     """
     if jax.default_backend() == "tpu":
         from repro.kernels.ops import pq_score_window
+        note_route("pq_score_window",
+                   "interpret" if kernels.interpret_mode() else "mosaic")
         return pq_score_window(luts, codes)
+    note_route("pq_score_window", "xla")
     nq, cand, m = codes.shape
     lutflat = luts.reshape(nq, m * luts.shape[-1])
     idx = codes.astype(jnp.int32) + jnp.arange(m, dtype=jnp.int32) * luts.shape[-1]
@@ -412,7 +422,8 @@ def _search_pass(packed: PackedIVF, Q, router, top_t: int, final_k: int,
         # no PQ stage → exact-score the whole window (search_numpy's
         # rerank_budget=0 semantics); rerank_budget is ignored
         exact = jnp.einsum("qwd,qd->qw",
-                           packed.rerank[jnp.maximum(ids, 0)], Q)
+                           packed.rerank[jnp.maximum(ids, 0)], Q,
+                           precision=EXACT)
         exact = jnp.where(valid, exact, -jnp.inf)
         di, dv = dedup_topk_window(ids, exact, final_k, multiplicity)
         di, dv = _pad_topk(di, dv, final_k)
@@ -425,6 +436,7 @@ def _search_pass(packed: PackedIVF, Q, router, top_t: int, final_k: int,
     luts = jax.vmap(lambda q: pq_lut(packed.pq, q))(Q)         # (nq, m, 16)
     if jax.default_backend() != "tpu" and packed.part_codes2 is not None:
         # CPU: pair-merged LUT gather (half the lookups of per-subspace)
+        note_route("pq_score_window", "xla")
         idx = packed.part_codes2[parts].reshape(nq, -1).astype(jnp.int32)
         g = jnp.take_along_axis(_merged_luts(luts), idx, axis=-1)
         approx = g.reshape(nq, t * pmax, -1).sum(axis=-1)
@@ -441,7 +453,8 @@ def _search_pass(packed: PackedIVF, Q, router, top_t: int, final_k: int,
         # the raw window instead would over-count spilled duplicates and
         # skip escalation the numpy engine's unique count would take
         surviving = jnp.sum(jnp.isfinite(bv), axis=-1)
-    exact = jnp.einsum("qbd,qd->qb", packed.rerank[jnp.maximum(bi, 0)], Q)
+    exact = jnp.einsum("qbd,qd->qb", packed.rerank[jnp.maximum(bi, 0)], Q,
+                       precision=EXACT)
     exact = jnp.where(jnp.isfinite(bv), exact, -jnp.inf)
     fv, fpos = jax.lax.top_k(exact, min(final_k, exact.shape[-1]))
     fi, fv = _pad_topk(jnp.take_along_axis(bi, fpos, axis=-1), fv, final_k)

@@ -34,8 +34,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed TPUCompilerParams -> CompilerParams across jax versions
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+from repro import kernels
+from repro.kernels import note_route
 
 ARGMIN_GROUP = 8
 
@@ -134,18 +134,23 @@ def _lloyd_kernel(x_ref, valid_ref, c_ref, cn_ref,
     valid = valid_ref[...]                          # (bn, 1) f32 0/1
     cm = c_ref[...]                                 # (c, d) full codebook
     cn = cn_ref[...]                                # (1, c)
+    hi = jax.lax.Precision.HIGHEST
     dm = cn - 2.0 * jax.lax.dot_general(
-        x, cm, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-    idx = jnp.argmin(dm, axis=-1)
-    mind = jnp.min(dm, axis=-1) + jnp.sum(x * x, axis=-1)
+        x, cm, (((1,), (1,)), ((), ())), precision=hi,
+        preferred_element_type=jnp.float32)
+    # every reduction keeps its axis: Mosaic refuses a 1-D (bn,) row
+    # reduced to a scalar ("Offset change"), so values stay (bn, 1)/(1, 1)
+    idx = jnp.argmin(dm, axis=-1, keepdims=True)             # (bn, 1)
+    mind = (jnp.min(dm, axis=-1, keepdims=True)
+            + jnp.sum(x * x, axis=-1, keepdims=True))        # (bn, 1)
     onehot = (jax.lax.broadcasted_iota(jnp.int32, dm.shape, 1)
-              == idx[:, None]).astype(jnp.float32) * valid
+              == idx).astype(jnp.float32) * valid
     # MXU contraction: on TPU the one-hot matmul IS the fast accumulate
     acc_sums[...] += jax.lax.dot_general(
-        onehot, x, (((0,), (0,)), ((), ())),
+        onehot, x, (((0,), (0,)), ((), ())), precision=hi,
         preferred_element_type=jnp.float32)
-    acc_counts[...] += jnp.sum(onehot, axis=0)[None, :]
-    acc_loss[...] += jnp.sum(mind * valid[:, 0])[None, None]
+    acc_counts[...] += jnp.sum(onehot, axis=0, keepdims=True)
+    acc_loss[...] += jnp.sum(mind * valid, axis=0, keepdims=True)
 
     @pl.when(i == pl.num_programs(0) - 1)
     def _write():
@@ -192,7 +197,7 @@ def lloyd_sweep_pallas(X, C, c: int, bn: int = 1024, interpret: bool = True):
             pltpu.VMEM((1, c), jnp.float32),
             pltpu.VMEM((1, 1), jnp.float32),
         ],
-        compiler_params=_CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(Xp, valid, C.astype(jnp.float32), cn)
     counts = counts[0]
@@ -258,11 +263,15 @@ def lloyd_sweep_batched(Xb, Cb, c: int, chunk: int = 16384):
 
 def lloyd_sweep_auto(X, C, c: int, chunk: int = 8192,
                      use_pallas: bool = None, interpret: bool = None):
-    """Backend dispatch: Pallas on TPU (codebook fits VMEM), scan elsewhere."""
+    """Backend dispatch: Pallas on TPU (codebook fits VMEM), scan elsewhere.
+    The route taken is logged
+    (`repro.kernels.note_route`)."""
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = kernels.interpret_mode()
     if use_pallas and c * X.shape[1] <= 1 << 20:
+        note_route("lloyd_sweep", "interpret" if interpret else "mosaic")
         return lloyd_sweep_pallas(X, C, c, interpret=interpret)
+    note_route("lloyd_sweep", "xla")
     return lloyd_sweep(X, C, c, chunk=chunk)

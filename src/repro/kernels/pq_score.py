@@ -15,8 +15,9 @@ Two variants:
   ITS OWN gathered candidates (the t·pmax window the IVF search probes), the
   shape the candidate-local `search_jit` pipeline produces (DESIGN.md §3.6).
       score[q, i] = sum_m luts[q, m, codes[q, i, m]]
-  The contraction is a per-query batched matvec on the MXU: each grid cell
-  holds BQ LUT rows and BQ×BN code rows and contracts them batch-wise.
+  Each query has its own codes, so there is no shared operand for the MXU:
+  the lookup is a 16-way select per subspace on the VPU over lane-dense
+  (BQ, BN) code tiles, accumulated over the m subspaces.
 """
 from __future__ import annotations
 
@@ -28,8 +29,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed TPUCompilerParams -> CompilerParams across jax versions
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+from repro import kernels
 
 # Block sizes: BQ queries × BN points per grid cell. m*16 is the contraction
 # dim (m=16 subspaces → 256, MXU-aligned). VMEM footprint per cell:
@@ -38,17 +38,14 @@ _CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerPar
 DEFAULT_BQ = 128
 DEFAULT_BN = 512
 
-# Window variant: the one-hot block is BQ×BN×(m·16), so BQ stays small.
-#   8·512·256·4B ≈ 4 MB one-hot + 8·512·16·4B codes + 8·256·4B luts « 16 MB.
+# Window variant: codes m×BQ×BN·4B (400 KB at m=25) + luts + out « 16 MB.
 DEFAULT_WIN_BQ = 8
 DEFAULT_WIN_BN = 512
 
 
 def _resolve_interpret(interpret: Optional[bool]) -> bool:
     """None → auto-detect: compile to Mosaic on TPU, interpret elsewhere."""
-    if interpret is None:
-        return jax.default_backend() != "tpu"
-    return interpret
+    return kernels.interpret_mode() if interpret is None else interpret
 
 
 def _pq_score_kernel(lut_ref, codes_ref, out_ref, *, n_centers: int):
@@ -92,7 +89,7 @@ def pq_score_pallas(luts, codes, n_centers: int = 16,
         out_specs=pl.BlockSpec((bq, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct(
             (lutmat.shape[0], codes_p.shape[0]), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(lutmat, codes_p)
@@ -100,16 +97,20 @@ def pq_score_pallas(luts, codes, n_centers: int = 16,
 
 
 def _pq_score_window_kernel(lut_ref, codes_ref, out_ref, *, n_centers: int):
-    codes = codes_ref[...]                                   # (BQ, BN, m) int32
-    onehot = (codes[:, :, :, None] == jax.lax.broadcasted_iota(
-        jnp.int32, (1, 1, 1, n_centers), 3))
-    onehot = onehot.astype(jnp.float32).reshape(
-        codes.shape[0], codes.shape[1], -1)                  # (BQ, BN, m*16)
+    # codes arrive subspace-major, (m, BQ, BN): codes_ref[j] is one
+    # lane-dense (BQ, BN) tile, and lut column j·16+k broadcasts along the
+    # lanes of its query row — no 3-D one-hot, no batched dot_general
+    # (Mosaic refuses a batch-dim dot_general)
     lut = lut_ref[...]                                       # (BQ, m*16)
-    # batched matvec: out[b, i] = lut[b, :] · onehot[b, i, :]
-    out_ref[...] = jax.lax.dot_general(
-        lut, onehot, (((1,), (2,)), ((0,), (0,))),
-        preferred_element_type=jnp.float32)                  # (BQ, BN)
+    acc = jnp.zeros(out_ref.shape, jnp.float32)
+    for j in range(codes_ref.shape[0]):
+        cj = codes_ref[j]                                    # (BQ, BN) int32
+        g = jnp.zeros_like(acc)
+        for k in range(n_centers):
+            col = j * n_centers + k
+            g = jnp.where(cj == k, lut[:, col:col + 1], g)
+        acc = acc + g
+    out_ref[...] = acc
 
 
 @functools.partial(jax.jit, static_argnames=("n_centers", "bq", "bn", "interpret"))
@@ -131,19 +132,21 @@ def pq_score_window_pallas(luts, codes, n_centers: int = 16,
     qpad = (-nq) % bq
     npad = (-cand) % bn
     lutmat = jnp.pad(lutmat, ((0, qpad), (0, 0)))
-    codes_p = jnp.pad(codes.astype(jnp.int32), ((0, qpad), (0, npad), (0, 0)))
-    grid = (lutmat.shape[0] // bq, codes_p.shape[1] // bn)
+    # int32 cast, padding and the subspace-major transpose fuse into one copy
+    codes_p = jnp.pad(codes.astype(jnp.int32).transpose(2, 0, 1),
+                      ((0, 0), (0, qpad), (0, npad)))        # (m, nq, cand)
+    grid = (lutmat.shape[0] // bq, codes_p.shape[2] // bn)
     out = pl.pallas_call(
         functools.partial(_pq_score_window_kernel, n_centers=n_centers),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bq, m * k), lambda i, j: (i, 0)),
-            pl.BlockSpec((bq, bn, m), lambda i, j: (i, j, 0)),
+            pl.BlockSpec((m, bq, bn), lambda i, j: (0, i, j)),
         ],
         out_specs=pl.BlockSpec((bq, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct(
-            (lutmat.shape[0], codes_p.shape[1]), jnp.float32),
-        compiler_params=_CompilerParams(
+            (lutmat.shape[0], codes_p.shape[2]), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(lutmat, codes_p)

@@ -16,8 +16,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed TPUCompilerParams -> CompilerParams across jax versions
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+from repro import kernels
+from repro.kernels import note_route
 
 DEFAULT_BN = 512
 DEFAULT_BC = 512
@@ -101,7 +101,7 @@ def soar_assign_pallas(X, rhat, primary, C, lam: float = 1.0,
             pltpu.VMEM((bn, 1), jnp.float32),
             pltpu.VMEM((bn, 1), jnp.int32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(Xp, Rp, rx, prim, Cp, cn)
@@ -184,16 +184,19 @@ def assign_fused(X, C, lam: float = 1.0, n_spills: int = 1,
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = kernels.interpret_mode()
     X = jnp.asarray(X, jnp.float32)
     C = jnp.asarray(C, jnp.float32)
     if n_spills == 0:
         from repro.utils import pairwise_neg_sqdist_argmin
+        note_route("assign_fused", "xla")
         prim, _ = pairwise_neg_sqdist_argmin(X, C, chunk=chunk)
         return prim[:, None]
     if not use_pallas or n_spills > 1:
+        note_route("assign_fused", "xla")
         return _fused_assign_gemm(X, C, lam=lam, n_spills=n_spills,
                                   chunk=chunk)
+    note_route("assign_fused", "interpret" if interpret else "mosaic")
     from repro.kernels.vq_assign import vq_assign_pallas
     prim, _ = vq_assign_pallas(X, C, interpret=interpret)
     r = X - C[prim]

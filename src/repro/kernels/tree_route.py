@@ -40,10 +40,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed TPUCompilerParams -> CompilerParams across jax versions
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+from repro import kernels
+from repro.kernels import note_route
 
-DEFAULT_BQ = 128
+# 64-query tiles keep the gathered (bq, d·cmax) child block, the child
+# table and the outputs inside the default 16 MiB scoped VMEM at d=100,
+# S=50, cmax=128 (128-query tiles need 18.9 MiB)
+DEFAULT_BQ = 64
+LANES = 128
 
 
 @functools.partial(jax.jit, static_argnames=("t_route",))
@@ -67,25 +71,31 @@ def tree_route_ref(Q, SC, CC, CH, t_route: int):
 def _tree_route_kernel(q_ref, sc_ref, ccf_ref, chf_ref,
                        scores_ref, ids_ref, *, t_route: int, cmax: int,
                        d: int):
+    hi = jax.lax.Precision.HIGHEST     # the id gather must stay exact
     q = q_ref[...]                                         # (bq, d)
     ss = jax.lax.dot_general(q, sc_ref[...],
-                             (((1,), (1,)), ((), ())),
+                             (((1,), (1,)), ((), ())), precision=hi,
                              preferred_element_type=jnp.float32)  # (bq, S)
-    ccf = ccf_ref[...]                                     # (S, cmax·d)
+    ccf = ccf_ref[...]                                     # (S, d·cmax)
     chf = chf_ref[...]                                     # (S, cmax) f32
-    bq = q.shape[0]
     for r in range(t_route):
-        idx = jnp.argmax(ss, axis=-1)                      # (bq,)
+        idx = jnp.argmax(ss, axis=-1, keepdims=True)       # (bq, 1)
         onehot = (jax.lax.broadcasted_iota(jnp.int32, ss.shape, 1)
-                  == idx[:, None]).astype(jnp.float32)
+                  == idx).astype(jnp.float32)
         # one-hot MXU gather: selected super's child block / id row
         blk = jax.lax.dot_general(onehot, ccf,
-                                  (((1,), (0,)), ((), ())),
+                                  (((1,), (0,)), ((), ())), precision=hi,
                                   preferred_element_type=jnp.float32)
         cid = jax.lax.dot_general(onehot, chf,
-                                  (((1,), (0,)), ((), ())),
+                                  (((1,), (0,)), ((), ())), precision=hi,
                                   preferred_element_type=jnp.float32)
-        sc = jnp.sum(blk.reshape(bq, cmax, d) * q[:, None, :], axis=-1)
+        # the child block is dimension-major (column k·cmax + j holds
+        # child j's coordinate k) and cmax is lane-aligned, so each
+        # coordinate is one aligned (bq, cmax) slice — no 3-D reshape,
+        # which Mosaic refuses as a shape cast
+        sc = blk[:, 0:cmax] * q[:, 0:1]
+        for k in range(1, d):
+            sc = sc + blk[:, k * cmax:(k + 1) * cmax] * q[:, k:k + 1]
         sc = jnp.where(cid > -0.5, sc, -jnp.inf)
         scores_ref[:, r * cmax:(r + 1) * cmax] = sc
         ids_ref[:, r * cmax:(r + 1) * cmax] = cid.astype(jnp.int32)
@@ -100,19 +110,24 @@ def tree_route_pallas(Q, SC, CC, CH, t_route: int, bq: int = DEFAULT_BQ,
     S, cmax, _ = CC.shape
     npad = (-nq) % bq
     Qp = jnp.pad(Q.astype(jnp.float32), ((0, npad), (0, 0)))
-    ccf = CC.astype(jnp.float32).reshape(S, cmax * d)
-    chf = CH.astype(jnp.float32)
-    w = t_route * cmax
+    # children padded to a lane multiple (pad slots carry id -1 → -inf),
+    # laid out dimension-major; the pad columns are cut after the call
+    cp = cmax + (-cmax) % LANES
+    CCp = jnp.pad(CC.astype(jnp.float32), ((0, 0), (0, cp - cmax), (0, 0)))
+    ccf = CCp.transpose(0, 2, 1).reshape(S, d * cp)
+    chf = jnp.pad(CH.astype(jnp.float32), ((0, 0), (0, cp - cmax)),
+                  constant_values=-1.0)
+    w = t_route * cp
     grid = (Qp.shape[0] // bq,)
     scores, ids = pl.pallas_call(
-        functools.partial(_tree_route_kernel, t_route=t_route, cmax=cmax,
+        functools.partial(_tree_route_kernel, t_route=t_route, cmax=cp,
                           d=d),
         grid=grid,
         in_specs=[
             pl.BlockSpec((bq, d), lambda i: (i, 0)),
             pl.BlockSpec((S, d), lambda i: (0, 0)),
-            pl.BlockSpec((S, cmax * d), lambda i: (0, 0)),
-            pl.BlockSpec((S, cmax), lambda i: (0, 0)),
+            pl.BlockSpec((S, d * cp), lambda i: (0, 0)),
+            pl.BlockSpec((S, cp), lambda i: (0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((bq, w), lambda i: (i, 0)),
@@ -122,22 +137,30 @@ def tree_route_pallas(Q, SC, CC, CH, t_route: int, bq: int = DEFAULT_BQ,
             jax.ShapeDtypeStruct((Qp.shape[0], w), jnp.float32),
             jax.ShapeDtypeStruct((Qp.shape[0], w), jnp.int32),
         ],
-        compiler_params=_CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(Qp, SC.astype(jnp.float32), ccf, chf)
-    return scores[:nq], ids[:nq]
+
+    def cut(a):
+        return a[:nq].reshape(nq, t_route, cp)[:, :, :cmax].reshape(
+            nq, t_route * cmax)
+    return cut(scores), cut(ids)
 
 
 def tree_route(Q, SC, CC, CH, t_route: int, use_pallas: bool = None,
                interpret: bool = None):
     """Backend dispatch, mirroring assign_fused: Pallas on TPU when the
-    child tables fit VMEM, the jit'd reference elsewhere."""
+    child tables fit VMEM, the jit'd reference elsewhere. The route taken
+    is logged (`repro.kernels.note_route`)."""
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = kernels.interpret_mode()
     S, cmax, d = CC.shape
-    if use_pallas and cmax * d <= 1 << 18 and S * d <= 1 << 20:
+    cp = cmax + (-cmax) % LANES
+    if use_pallas and cp * d <= 1 << 18 and S * d <= 1 << 20:
+        note_route("tree_route", "interpret" if interpret else "mosaic")
         return tree_route_pallas(Q, SC, CC, CH, t_route,
                                  interpret=interpret)
+    note_route("tree_route", "xla")
     return tree_route_ref(Q, SC, CC, CH, t_route)

@@ -17,9 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed TPUCompilerParams -> CompilerParams across jax versions
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 DEFAULT_BN = 512
 DEFAULT_BC = 512
 
@@ -85,7 +82,7 @@ def vq_assign_pallas(X, C, bn: int = DEFAULT_BN, bc: int = DEFAULT_BC,
             pltpu.VMEM((bn, 1), jnp.float32),
             pltpu.VMEM((bn, 1), jnp.int32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(Xp, Cp, cn)

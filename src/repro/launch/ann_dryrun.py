@@ -29,8 +29,9 @@ from repro.core.distributed import (abstract_sharded_ivf,  # noqa: E402
 from repro.launch.dryrun import (HBM_BW, ICI_BW, PEAK_FLOPS,  # noqa: E402
                                  fmt_summary)
 from repro.launch.hlo_analysis import analyze  # noqa: E402
-from repro.launch.mesh import (make_production_mesh, set_mesh,  # noqa: E402
+from repro.launch.mesh import (make_production_mesh,  # noqa: E402
                                to_shardings)
+from repro.utils import enable_compile_cache  # noqa: E402
 
 N_LOCAL = 1_000_000
 C_LOCAL = 2_500
@@ -59,7 +60,7 @@ def run(multi_pod: bool, pq: bool = False) -> dict:
                                          final_k=FINAL_K)
         in_sh = (sharded_ivf_pspecs(axes), P())
     t0 = time.time()
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(search, in_shardings=to_shardings(mesh, in_sh),
                           out_shardings=to_shardings(mesh, (P(), P()))
                           ).lower(ivf, q)
@@ -97,6 +98,7 @@ def run(multi_pod: bool, pq: bool = False) -> dict:
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--mesh", default="both",
                     choices=["single", "multi", "both"])

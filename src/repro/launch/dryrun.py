@@ -21,11 +21,12 @@ import numpy as np       # noqa: E402
 
 from repro.configs import ARCH_IDS, get_config, get_rule_overrides  # noqa: E402
 from repro.launch.mesh import (build_rules, make_production_mesh,  # noqa: E402
-                               set_mesh, to_shardings)
+                               to_shardings)
 from repro.launch import specs as S                                 # noqa: E402
 from repro.launch.hlo_analysis import analyze                       # noqa: E402
 from repro.models.config import SHAPES, cell_applicable             # noqa: E402
 from repro.models.layers import set_logical_rules                   # noqa: E402
+from repro.utils import enable_compile_cache  # noqa: E402
 
 # v5e hardware constants (per chip)
 PEAK_FLOPS = 197e12          # bf16
@@ -68,7 +69,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         donate = (2,)           # KV cache updated in place
 
     t0 = time.time()
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(fn, in_shardings=to_shardings(mesh, in_sh),
                           out_shardings=to_shardings(mesh, out_sh),
                           donate_argnums=donate).lower(*args)
@@ -144,6 +145,7 @@ def fmt_summary(r: dict) -> str:
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None, choices=list(ARCH_IDS))
     ap.add_argument("--shape", default=None, choices=list(SHAPES))

@@ -11,21 +11,21 @@ import numpy as np
 import jax
 
 
-def set_mesh(mesh):
-    """Activate `mesh` as the ambient mesh for the following block.
-
-    jax.set_mesh on current jax; on jax<0.5 (no set_mesh) the Mesh object
-    itself is the context manager that installs the global mesh.
-    """
-    return jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh
+def make_mesh(shape, axes, devices=None):
+    """`jax.make_mesh` with Auto axes. The logical rules below are hints
+    that `with_sharding_constraint` hands to the partitioner; under the
+    Explicit axes `jax.make_mesh` now defaults to, the same constraint is
+    an assertion on the argument's sharding, and the model code fails."""
+    from jax.sharding import AxisType
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def to_shardings(mesh, tree):
-    """PartitionSpec pytree → NamedSharding pytree.
-
-    jax<0.5's jit rejects bare PartitionSpecs in in_shardings/out_shardings;
-    NamedSharding works on every version. is_leaf guard: PartitionSpec is a
-    tuple subclass, so tree.map would otherwise flatten into it.
+    """PartitionSpec pytree → NamedSharding pytree, for jit
+    in_shardings/out_shardings without an ambient mesh. is_leaf guard:
+    PartitionSpec is a tuple subclass, so tree.map would otherwise
+    flatten into it.
     """
     from jax.sharding import NamedSharding, PartitionSpec
     return jax.tree.map(
@@ -39,18 +39,18 @@ def make_production_mesh(*, multi_pod: bool = False):
     n = int(np.prod(shape))
     devs = jax.devices()
     if len(devs) == n:
-        return jax.make_mesh(shape, axes)
+        return make_mesh(shape, axes)
     assert len(devs) >= n, (
         f"need {n} devices for mesh {shape}; have {len(devs)} — dryrun.py "
         f"must set XLA_FLAGS=--xla_force_host_platform_device_count=512 "
         f"before any jax import")
-    return jax.make_mesh(shape, axes, devices=devs[:n])
+    return make_mesh(shape, axes, devices=devs[:n])
 
 
 def make_test_mesh(shape=(2, 4), axes=("data", "model")):
     """Small mesh for subprocess-based distribution tests."""
     n = int(np.prod(shape))
-    return jax.make_mesh(shape, axes, devices=jax.devices()[:n])
+    return make_mesh(shape, axes, devices=jax.devices()[:n])
 
 
 # --------------------------------------------------------------------------

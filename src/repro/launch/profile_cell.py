@@ -16,9 +16,10 @@ from repro.launch import specs as S                                 # noqa: E402
 from repro.launch.dryrun import HBM_BW, ICI_BW, PEAK_FLOPS          # noqa: E402
 from repro.launch.hlo_analysis import analyze                       # noqa: E402
 from repro.launch.mesh import (build_rules, make_production_mesh,  # noqa: E402
-                               set_mesh, to_shardings)
+                               to_shardings)
 from repro.models.config import SHAPES                              # noqa: E402
 from repro.models.layers import set_logical_rules                   # noqa: E402
+from repro.utils import enable_compile_cache  # noqa: E402
 
 
 def profile(arch: str, shape: str, multi_pod: bool = False, top_n: int = 12):
@@ -39,7 +40,7 @@ def profile(arch: str, shape: str, multi_pod: bool = False, top_n: int = 12):
     else:
         fn, args, insh, outsh = S.decode_cell_specs(cfg, cell, rules)
         donate = (2,)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         compiled = jax.jit(fn, in_shardings=to_shardings(mesh, insh),
                            out_shardings=to_shardings(mesh, outsh),
                            donate_argnums=donate).lower(*args).compile()
@@ -63,6 +64,7 @@ def profile(arch: str, shape: str, multi_pod: bool = False, top_n: int = 12):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=list(ARCH_IDS))
     ap.add_argument("--shape", required=True, choices=list(SHAPES))

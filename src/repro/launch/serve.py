@@ -15,9 +15,11 @@ from repro.configs import ARCH_IDS, get_config
 from repro.data.pipeline import for_model
 from repro.models import transformer as T
 from repro.serve.engine import ServeEngine
+from repro.utils import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-3-2b", choices=list(ARCH_IDS))
     ap.add_argument("--batch", type=int, default=4)

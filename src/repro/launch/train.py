@@ -21,12 +21,14 @@ import os
 from repro.ckpt.checkpoint import CheckpointManager
 from repro.configs import ARCH_IDS, get_config, get_rule_overrides
 from repro.data.pipeline import for_model
-from repro.launch.mesh import build_rules, make_production_mesh, set_mesh
+from repro.launch.mesh import build_rules, make_production_mesh
 from repro.models.layers import set_logical_rules
 from repro.train.train_loop import train
+from repro.utils import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="granite-3-2b", choices=list(ARCH_IDS))
     ap.add_argument("--mesh", default="cpu", choices=["cpu", "single", "multi"])
@@ -56,7 +58,7 @@ def main():
         if args.no_fsdp:
             rules["embed"] = None
         set_logical_rules(rules)
-        ctx = set_mesh(mesh)
+        ctx = jax.set_mesh(mesh)
 
     # XLA flags a real run would set for collective/compute overlap
     os.environ.setdefault(

@@ -102,8 +102,6 @@ def _moe_dense(p, x, cfg):
 
 
 def _moe_shardmap(p, x, cfg, mesh, rules):
-    from jax.experimental.shard_map import shard_map
-
     dt = x.dtype
     B, S, d = x.shape
     E, k = cfg.n_experts, cfg.experts_per_token
@@ -131,8 +129,8 @@ def _moe_shardmap(p, x, cfg, mesh, rules):
         out = jax.lax.psum(out, exp_ax)
         return out.reshape(Bl, Sl, d)
 
-    fn = shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
     return fn(p, x)
 
 

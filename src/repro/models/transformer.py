@@ -126,19 +126,6 @@ def cache_pspecs(cfg: ModelConfig, batch: int, max_seq: int, rules: dict):
 
 # ---------------------------------------------------------------- forward
 
-@jax.custom_jvp
-def _barrier(tree):
-    return jax.lax.optimization_barrier(tree)
-
-
-@_barrier.defjvp
-def _barrier_jvp(primals, tangents):
-    # identity gradient: optimization_barrier has no differentiation rule on
-    # jax<0.5, and the barrier is a pure scheduling hint
-    (tree,), (dtree,) = primals, tangents
-    return _barrier(tree), dtree
-
-
 def _cast_big_params(groups, cfg: ModelConfig):
     """Cast large stacked weight tensors to the compute dtype BEFORE the
     group scan (§Perf H-cast): otherwise the per-iteration FSDP all-gather /
@@ -155,7 +142,7 @@ def _cast_big_params(groups, cfg: ModelConfig):
     # Without the barrier XLA undoes the optimization: it keeps the fp32
     # buffer and rematerializes the (cheap) convert inside the scan body,
     # re-reading fp32 every iteration (measured: no traffic change).
-    return _barrier(out)
+    return jax.lax.optimization_barrier(out)
 
 
 def _apply_group(gp, x, positions, cfg, mask_mode, states, cache_index):

@@ -114,15 +114,14 @@ def make_pipelined_loss(cfg: ModelConfig, mesh, n_stages: int = 2,
         count = jax.lax.psum(n_loss, stage_axis)
         return total / jnp.maximum(count, 1.0)
 
-    from jax.experimental.shard_map import shard_map
-    return shard_map(
+    return jax.shard_map(
         pipelined, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: P(stage_axis), {"groups": 0,
                                                          "final_norm": 0,
                                                          "head": 0,
                                                          "embed": 0}),
                   P(), P()),
-        out_specs=P(), check_rep=False)
+        out_specs=P(), check_vma=False)
 
 
 def pipelined_loss_and_grad(cfg: ModelConfig, mesh, stage_params, tokens,

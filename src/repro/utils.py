@@ -2,10 +2,32 @@
 from __future__ import annotations
 
 import functools
+import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+
+# checkout root: src/repro/utils.py → src/repro → src → root
+REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    For entry points only — importing `repro` never turns it on, so tests
+    that compile for a described chip stay free of cache warnings. JAX
+    reads JAX_COMPILATION_CACHE_DIR itself when it is set; otherwise the
+    cache lives at <checkout>/.jax_cache, a fixed path, so a later run in
+    the same checkout finds what an earlier one stored.
+    """
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.join(REPO_ROOT, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def pad_to_multiple(x: jax.Array, multiple: int, axis: int = 0, value=0):

@@ -8,7 +8,6 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from repro.core.distributed import build_sharded_ivf, make_distributed_search
-from repro.launch.mesh import set_mesh
 from repro.core import true_neighbors
 from repro.data.vectors import make_manifold
 
@@ -18,7 +17,7 @@ mesh = jax.make_mesh((8,), ("data",))
 sharded = build_sharded_ivf(jax.random.PRNGKey(1), ds.X, n_shards=8,
                             n_partitions=16, spill_mode="soar", train_iters=5)
 search = make_distributed_search(mesh, ("data",), top_t=8, final_k=10)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     ids, scores = jax.jit(search)(sharded, jnp.asarray(ds.Q))
 ids = np.asarray(ids)
 rec = (ids[:, :, None] == tn[:, None, :]).any(-1).mean()
@@ -57,14 +56,14 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs import get_config
 from repro.data.pipeline import for_model
-from repro.launch.mesh import build_rules, set_mesh
+from repro.launch.mesh import build_rules, make_test_mesh
 from repro.models.layers import set_logical_rules
 from repro.models import transformer as T
 from repro.train import optimizer as opt
 from repro.train.train_loop import make_train_step
 
 cfg = get_config("granite-3-2b").smoke_config()
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_test_mesh((2, 4), ("data", "model"))
 rules = build_rules({}, batch_size=8)
 rules["heads"] = None  # 4 smoke heads won't split 4-way AND kv too; keep simple
 set_logical_rules(rules)
@@ -72,7 +71,7 @@ pipe = for_model(cfg, seq_len=32, global_batch=8)
 params = T.init_params(jax.random.PRNGKey(0), cfg)
 lr_fn = opt.warmup_cosine(1e-3, 5, 100)
 step = make_train_step(cfg, lr_fn, accum=2)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     pspec = T.param_pspecs(cfg, rules)
     params = jax.device_put(params, jax.tree.map(
         lambda s: jax.NamedSharding(mesh, s), pspec))
@@ -110,7 +109,6 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from repro.core.distributed import build_sharded_ivf_pq, make_distributed_search_pq
-from repro.launch.mesh import set_mesh
 from repro.core import true_neighbors
 from repro.data.vectors import make_manifold
 
@@ -122,7 +120,7 @@ sharded = build_sharded_ivf_pq(jax.random.PRNGKey(1), ds.X, n_shards=8,
                                spill_mode="soar", train_iters=5)
 search = make_distributed_search_pq(mesh, ("data",), top_t=8, final_k=10,
                                     rerank_k=128, q_chunk=32)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     ids, scores = jax.jit(search)(sharded, jnp.asarray(ds.Q))
 ids = np.asarray(ids)
 rec = (ids[:, :, None] == tn[:, None, :]).any(-1).mean()
@@ -139,7 +137,6 @@ import jax, jax.numpy as jnp, numpy as np
 from repro.core.distributed import (build_sharded_ivf, build_sharded_ivf_pq,
                                     make_distributed_search,
                                     make_distributed_search_pq, shard_filters)
-from repro.launch.mesh import set_mesh
 from repro.data.vectors import make_manifold
 
 ds = make_manifold(jax.random.PRNGKey(0), n=8_000, d=32, nq=32, intrinsic_dim=8)
@@ -153,7 +150,7 @@ sharded = build_sharded_ivf(jax.random.PRNGKey(1), ds.X, n_shards=8,
                             n_partitions=16, spill_mode="soar", train_iters=4)
 search = make_distributed_search(mesh, ("data",), top_t=10, final_k=10,
                                  with_filter=True)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     ids, _ = jax.jit(search)(sharded, jnp.asarray(ds.Q), filt)
 ids = np.asarray(ids)
 rec = (ids[:, :, None] == tn[:, None, :]).any(-1).mean()
@@ -165,7 +162,7 @@ shardedpq = build_sharded_ivf_pq(jax.random.PRNGKey(1), ds.X, n_shards=8,
 searchpq = make_distributed_search_pq(mesh, ("data",), top_t=10, final_k=10,
                                       rerank_k=128, q_chunk=32,
                                       with_filter=True)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     idsp, _ = jax.jit(searchpq)(shardedpq, jnp.asarray(ds.Q), filt)
 idsp = np.asarray(idsp)
 recp = (idsp[:, :, None] == tn[:, None, :]).any(-1).mean()
@@ -205,7 +202,6 @@ from repro.core.distributed import (make_distributed_search,
                                     sharded_from_indexes,
                                     sharded_from_indexes_pq,
                                     stack_tree_routers)
-from repro.launch.mesh import set_mesh
 from repro.core import true_neighbors
 from repro.data.vectors import make_manifold
 
@@ -224,7 +220,7 @@ srt = stack_tree_routers([i.router for i in idxs])
 mesh = jax.make_mesh((8,), ("data",))
 search = make_distributed_search(mesh, ("data",), top_t=8, final_k=10,
                                  with_router=True, t_route=3)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     ids, _ = jax.jit(search)(sharded_from_indexes(idxs), jnp.asarray(ds.Q), srt)
 ids = np.asarray(ids)
 rec = (ids[:, :, None] == tn[:, None, :]).any(-1).mean()
@@ -233,7 +229,7 @@ assert ids.max() < 8_000
 searchpq = make_distributed_search_pq(mesh, ("data",), top_t=8, final_k=10,
                                       rerank_k=128, q_chunk=32,
                                       with_router=True, t_route=3)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     idsp, _ = jax.jit(searchpq)(sharded_from_indexes_pq(idxs),
                                 jnp.asarray(ds.Q), srt)
 idsp = np.asarray(idsp)

@@ -10,7 +10,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from repro.configs import get_config
-from repro.launch.mesh import build_rules, set_mesh, to_shardings
+from repro.launch.mesh import build_rules, make_test_mesh, to_shardings
 from repro.launch import specs as S
 from repro.launch.hlo_analysis import analyze
 from repro.models.config import ShapeCell
@@ -21,7 +21,7 @@ from repro.train import optimizer as opt
 from repro.train.train_loop import make_train_step
 
 cfg = get_config("granite-3-2b").smoke_config()
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_test_mesh((2, 4), ("data", "model"))
 rules = build_rules({"heads": None, "kv_heads": None}, batch_size=8,
                     dp_degree=2)
 set_logical_rules(rules)
@@ -29,7 +29,7 @@ set_logical_rules(rules)
 # --- train step
 cell = ShapeCell("tiny_train", 64, 8, "train")
 fn, args, insh, outsh = S.train_cell_specs(cfg, cell, rules, False)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     compiled = jax.jit(fn, in_shardings=to_shardings(mesh, insh),
                        out_shardings=to_shardings(mesh, outsh),
                        donate_argnums=(0, 1)).lower(*args).compile()
@@ -43,7 +43,7 @@ print("train ok: flops", r["flops"])
 # --- decode step
 cell = ShapeCell("tiny_decode", 64, 8, "decode")
 fn, args, insh, outsh = S.decode_cell_specs(cfg, cell, rules)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     compiled = jax.jit(fn, in_shardings=to_shardings(mesh, insh),
                        out_shardings=to_shardings(mesh, outsh),
                        donate_argnums=(2,)).lower(*args).compile()

@@ -276,7 +276,9 @@ def test_wal_crash_matrix(built, queries, tmp_path):
         if expect == "pre":
             want = r_pre
         else:                          # replay applies the committed add
-            ref = AnnEngine.open(p.replace(f"w{i}", "w0"))
+            # w0, not a string replace on p: the xdist worker's tmp dir
+            # ("popen-gw<i>") would match too
+            ref = AnnEngine.open(str(tmp_path / "w0"))
             ref.add(add)
             want = ref.search(queries, k=K)
         assert np.array_equal(r2[0], want[0]), (spec, expect)

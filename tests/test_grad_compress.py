@@ -13,7 +13,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from repro.train.grad_compress import compressed_psum, compressed_psum_with_feedback
 
 mesh = jax.make_mesh((8,), ("data",))

@@ -11,7 +11,6 @@ from repro.configs import get_config
 from repro.models import transformer as T
 from repro.train.pipeline import (make_pipelined_loss, stack_stage_params,
                                   pipelined_loss_and_grad)
-from repro.launch.mesh import set_mesh
 
 cfg = get_config("granite-3-2b").smoke_config().replace(
     compute_dtype="float32", remat="none")
@@ -30,38 +29,28 @@ ref = float(np.mean(ref_losses))
 
 mesh = jax.make_mesh((2,), ("pod",))
 sp = stack_stage_params(params, cfg, n_stages=2)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     fn = make_pipelined_loss(cfg, mesh, n_stages=2)
     got = float(jax.jit(fn)(sp, tokens, labels))
 assert abs(got - ref) / abs(ref) < 1e-5, (got, ref)
 
-# gradient check: pipelined grads of the group params match sequential grads.
-# jax<0.5's shard_map transpose cannot differentiate this program (spec-check
-# failure on scalar residuals); the forward equality above still holds there,
-# so degrade to a visible skip rather than a false failure.
+# gradient check: pipelined grads of the group params match sequential grads
 def ref_loss_fn(p):
     return sum(T.loss_fn(p, {"tokens": tokens[i], "labels": labels[i]}, cfg)
                for i in range(M)) / M
 ref_grads = jax.grad(ref_loss_fn)(params)
-try:
-    with set_mesh(mesh):
-        _, pipe_grads = pipelined_loss_and_grad(cfg, mesh, sp, tokens, labels)
-except Exception as e:
-    if type(e).__name__ != "_SpecError" or hasattr(jax, "set_mesh"):
-        raise
-    print("OK", got, ref, "(grad check skipped: shard_map transpose "
-          "unsupported on this jax)")
-else:
-    # compare one representative group-leaf: reassemble stage halves
-    pg = np.asarray(pipe_grads["groups"]["pos0_attn"]["wq"])   # (2, G/2, ...)
-    rg = np.asarray(ref_grads["groups"]["pos0_attn"]["wq"])    # (G, ...)
-    pg_full = pg.reshape(rg.shape)
-    np.testing.assert_allclose(pg_full, rg, rtol=2e-4, atol=1e-6)
-    # embed grads live on stage 0
-    pe = np.asarray(pipe_grads["embed"]["table"])[0]
-    re = np.asarray(ref_grads["embed"]["table"])
-    np.testing.assert_allclose(pe, re, rtol=2e-4, atol=1e-6)
-    print("OK", got, ref)
+with jax.set_mesh(mesh):
+    _, pipe_grads = pipelined_loss_and_grad(cfg, mesh, sp, tokens, labels)
+# compare one representative group-leaf: reassemble stage halves
+pg = np.asarray(pipe_grads["groups"]["pos0_attn"]["wq"])   # (2, G/2, ...)
+rg = np.asarray(ref_grads["groups"]["pos0_attn"]["wq"])    # (G, ...)
+pg_full = pg.reshape(rg.shape)
+np.testing.assert_allclose(pg_full, rg, rtol=2e-4, atol=1e-6)
+# embed grads live on stage 0
+pe = np.asarray(pipe_grads["embed"]["table"])[0]
+re = np.asarray(ref_grads["embed"]["table"])
+np.testing.assert_allclose(pe, re, rtol=2e-4, atol=1e-6)
+print("OK", got, ref)
 """
 
 

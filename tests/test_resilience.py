@@ -436,7 +436,6 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from repro.core.distributed import build_sharded_ivf, make_distributed_search
-from repro.launch.mesh import set_mesh
 from repro.data.vectors import make_manifold
 from repro.serve.health import HealthTracker, shards_ok_from_mask
 
@@ -449,7 +448,7 @@ sharded = build_sharded_ivf(jax.random.PRNGKey(1), ds.X, n_shards=8,
 plain = make_distributed_search(mesh, ("data",), top_t=8, final_k=10)
 degr = make_distributed_search(mesh, ("data",), top_t=8, final_k=10,
                                with_health=True)
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     ids0, sc0 = jax.jit(plain)(sharded, jnp.asarray(ds.Q))
     ones = jnp.ones((8,), jnp.uint8)
     ids1, sc1 = jax.jit(degr)(sharded, jnp.asarray(ds.Q), ones)
