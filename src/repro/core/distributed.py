@@ -314,6 +314,23 @@ def _mask_unhealthy(ids, vals, health):
             jnp.where(ok, vals, -jnp.inf))
 
 
+def _global_merge(ids, vals, axes: Tuple[str, ...], final_k: int):
+    """Shard-parallel merge, under the `merge` named scope: gather every
+    shard's (nq, k) candidates over `axes` and re-top-k them."""
+    with jax.named_scope("merge"):
+        ax = axes[0] if len(axes) == 1 else axes
+        all_ids = jax.lax.all_gather(ids, ax, tiled=False)   # (D, nq, k)
+        all_vals = jax.lax.all_gather(vals, ax, tiled=False)
+        if len(axes) > 1:   # gathered over multiple axes → extra lead dims
+            all_ids = all_ids.reshape((-1,) + ids.shape)
+            all_vals = all_vals.reshape((-1,) + vals.shape)
+        nq, D = ids.shape[0], all_ids.shape[0]
+        flat_v = jnp.moveaxis(all_vals, 0, 1).reshape(nq, D * final_k)
+        flat_i = jnp.moveaxis(all_ids, 0, 1).reshape(nq, D * final_k)
+        v, pos = jax.lax.top_k(flat_v, final_k)
+        return jnp.take_along_axis(flat_i, pos, axis=1), v
+
+
 def make_replicated_search(mesh, axes: Tuple[str, ...], *, top_t: int,
                            final_k: int, rerank_budget: int = 256,
                            multiplicity: int = 2, with_filter: bool = False,
@@ -443,18 +460,7 @@ def make_distributed_search(mesh, axes: Tuple[str, ...], *, top_t: int,
         # under-filled window must not alias into the previous shard)
         ids = jnp.where(ids >= 0, ids + base, -1).astype(jnp.int32)
         ids, vals = _mask_unhealthy(ids, vals, health)
-        # global merge: gather every shard's candidates, re-top-k
-        ax = axes[0] if len(axes) == 1 else axes
-        all_ids = jax.lax.all_gather(ids, ax, tiled=False)   # (D, nq, k)
-        all_vals = jax.lax.all_gather(vals, ax, tiled=False)
-        if len(axes) > 1:   # gathered over multiple axes → extra lead dims
-            all_ids = all_ids.reshape((-1,) + ids.shape)
-            all_vals = all_vals.reshape((-1,) + vals.shape)
-        D = all_ids.shape[0]
-        flat_v = jnp.moveaxis(all_vals, 0, 1).reshape(Q.shape[0], D * final_k)
-        flat_i = jnp.moveaxis(all_ids, 0, 1).reshape(Q.shape[0], D * final_k)
-        v, pos = jax.lax.top_k(flat_v, final_k)
-        return jnp.take_along_axis(flat_i, pos, axis=1), v
+        return _global_merge(ids, vals, axes, final_k)
 
     return _shard_map_variants(local_search, mesh, sharded_ivf_pspecs(axes),
                                axes, with_filter, with_router, with_health)
@@ -534,17 +540,7 @@ def make_distributed_search_pq(mesh, axes: Tuple[str, ...], *, top_t: int,
         ids = ids.reshape(nq, final_k)
         vals = vals.reshape(nq, final_k)
         ids, vals = _mask_unhealthy(ids, vals, health)
-        ax = axes[0] if len(axes) == 1 else axes
-        all_ids = jax.lax.all_gather(ids, ax, tiled=False)
-        all_vals = jax.lax.all_gather(vals, ax, tiled=False)
-        if len(axes) > 1:
-            all_ids = all_ids.reshape((-1,) + ids.shape)
-            all_vals = all_vals.reshape((-1,) + vals.shape)
-        D = all_ids.shape[0]
-        flat_v = jnp.moveaxis(all_vals, 0, 1).reshape(nq, D * final_k)
-        flat_i = jnp.moveaxis(all_ids, 0, 1).reshape(nq, D * final_k)
-        v, pos = jax.lax.top_k(flat_v, final_k)
-        return jnp.take_along_axis(flat_i, pos, axis=1), v
+        return _global_merge(ids, vals, axes, final_k)
 
     return _shard_map_variants(local_search, mesh,
                                sharded_ivf_pq_pspecs(axes), axes,

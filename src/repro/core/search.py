@@ -407,57 +407,73 @@ def _search_pass(packed: PackedIVF, Q, router, top_t: int, final_k: int,
     where n_surviving (None unfiltered) counts UNIQUE surviving candidates
     capped at the stage budget — the escalation signal, matching the numpy
     engine's unique-candidate count.
+
+    Each stage runs under a `jax.named_scope` (probe, gather, lut, score,
+    dedup, rerank), which names its operations in a profiler trace
+    (DESIGN.md §3.15); the scopes change HLO metadata only.
     """
-    psc, parts = router.route(Q, top_t)                # (nq, t) probe stage
-    ids = packed.part_ids[parts]                       # (nq, t, pmax)
-    nq, t, pmax = ids.shape
-    ids = ids.reshape(nq, t * pmax)
-    valid = ids >= 0
+    with jax.named_scope("probe"):
+        psc, parts = router.route(Q, top_t)            # (nq, t) probe stage
+    with jax.named_scope("gather"):
+        ids = packed.part_ids[parts]                   # (nq, t, pmax)
+        nq, t, pmax = ids.shape
+        ids = ids.reshape(nq, t * pmax)
+        valid = ids >= 0
+        if filter is not None:
+            fbits = filter[jnp.maximum(ids, 0)]        # (nq, t·pmax) gather
+            valid = valid & (fbits > 0)
+            ids = jnp.where(valid, ids, -1)            # filter-aware dedup
     surviving = None
-    if filter is not None:
-        fbits = filter[jnp.maximum(ids, 0)]            # (nq, t·pmax) gather
-        valid = valid & (fbits > 0)
-        ids = jnp.where(valid, ids, -1)                # filter-aware dedup
     if packed.part_codes is None:
         # no PQ stage → exact-score the whole window (search_numpy's
         # rerank_budget=0 semantics); rerank_budget is ignored
-        exact = jnp.einsum("qwd,qd->qw",
-                           packed.rerank[jnp.maximum(ids, 0)], Q,
-                           precision=EXACT)
-        exact = jnp.where(valid, exact, -jnp.inf)
-        di, dv = dedup_topk_window(ids, exact, final_k, multiplicity)
-        di, dv = _pad_topk(di, dv, final_k)
+        with jax.named_scope("score"):
+            exact = jnp.einsum("qwd,qd->qw",
+                               packed.rerank[jnp.maximum(ids, 0)], Q,
+                               precision=EXACT)
+            exact = jnp.where(valid, exact, -jnp.inf)
+        with jax.named_scope("dedup"):
+            di, dv = dedup_topk_window(ids, exact, final_k, multiplicity)
+            di, dv = _pad_topk(di, dv, final_k)
         if filter is not None:
             # unique survivors, capped at final_k (finite ⟺ a real deduped
             # candidate filled the slot) — matches the numpy engine's
             # unique-count escalation signal
             surviving = jnp.sum(jnp.isfinite(dv), axis=-1)
         return di, dv, surviving
-    luts = jax.vmap(lambda q: pq_lut(packed.pq, q))(Q)         # (nq, m, 16)
+    with jax.named_scope("lut"):
+        luts = jax.vmap(lambda q: pq_lut(packed.pq, q))(Q)     # (nq, m, 16)
     if jax.default_backend() != "tpu" and packed.part_codes2 is not None:
         # CPU: pair-merged LUT gather (half the lookups of per-subspace)
         note_route("pq_score_window", "xla")
-        idx = packed.part_codes2[parts].reshape(nq, -1).astype(jnp.int32)
-        g = jnp.take_along_axis(_merged_luts(luts), idx, axis=-1)
-        approx = g.reshape(nq, t * pmax, -1).sum(axis=-1)
+        with jax.named_scope("gather"):
+            idx = packed.part_codes2[parts].reshape(nq, -1).astype(jnp.int32)
+        with jax.named_scope("score"):
+            g = jnp.take_along_axis(_merged_luts(luts), idx, axis=-1)
+            approx = g.reshape(nq, t * pmax, -1).sum(axis=-1)
     else:
         # TPU one-hot MXU kernel, or raw-code fallback (pair_codes=False)
-        codes = packed.part_codes[parts].reshape(nq, t * pmax, -1)
-        approx = window_pq_scores(luts, codes)
-    approx = approx + jnp.repeat(psc, pmax, axis=-1)           # + <q, centroid>
-    approx = jnp.where(valid, approx, -jnp.inf)
-    bi, bv = dedup_topk_window(ids, approx, rerank_budget, multiplicity)
+        with jax.named_scope("gather"):
+            codes = packed.part_codes[parts].reshape(nq, t * pmax, -1)
+        with jax.named_scope("score"):
+            approx = window_pq_scores(luts, codes)
+    with jax.named_scope("score"):
+        approx = approx + jnp.repeat(psc, pmax, axis=-1)       # + <q, centroid>
+        approx = jnp.where(valid, approx, -jnp.inf)
+    with jax.named_scope("dedup"):
+        bi, bv = dedup_topk_window(ids, approx, rerank_budget, multiplicity)
     if filter is not None:
         # unique survivors, capped at rerank_budget (a -inf slot means the
         # deduped candidate set ran short of the budget) — slot-counting
         # the raw window instead would over-count spilled duplicates and
         # skip escalation the numpy engine's unique count would take
         surviving = jnp.sum(jnp.isfinite(bv), axis=-1)
-    exact = jnp.einsum("qbd,qd->qb", packed.rerank[jnp.maximum(bi, 0)], Q,
-                       precision=EXACT)
-    exact = jnp.where(jnp.isfinite(bv), exact, -jnp.inf)
-    fv, fpos = jax.lax.top_k(exact, min(final_k, exact.shape[-1]))
-    fi, fv = _pad_topk(jnp.take_along_axis(bi, fpos, axis=-1), fv, final_k)
+    with jax.named_scope("rerank"):
+        exact = jnp.einsum("qbd,qd->qb", packed.rerank[jnp.maximum(bi, 0)], Q,
+                           precision=EXACT)
+        exact = jnp.where(jnp.isfinite(bv), exact, -jnp.inf)
+        fv, fpos = jax.lax.top_k(exact, min(final_k, exact.shape[-1]))
+        fi, fv = _pad_topk(jnp.take_along_axis(bi, fpos, axis=-1), fv, final_k)
     return fi, fv, surviving
 
 
@@ -483,14 +499,15 @@ def _search_block(packed: PackedIVF, Q, top_t: int, final_k: int,
         return ids1, vals1
     thresh = rerank_budget if packed.part_codes is not None else final_k
     r2, t2 = router.escalated(top_t)
-    ids2, vals2, _ = _search_pass(packed, Q, r2, t2, final_k,
-                                  rerank_budget, multiplicity, filter)
-    # the escalated probe set is a superset for the flat router (top-2t ⊇
-    # top-t of the same centroid scores) and reaches strictly more
-    # children for the tree router, so taking pass-2 rows never loses
-    # candidates
-    need = (surv1 < thresh)[:, None]
-    return jnp.where(need, ids2, ids1), jnp.where(need, vals2, vals1)
+    with jax.named_scope("escalate"):
+        ids2, vals2, _ = _search_pass(packed, Q, r2, t2, final_k,
+                                      rerank_budget, multiplicity, filter)
+        # the escalated probe set is a superset for the flat router (top-2t ⊇
+        # top-t of the same centroid scores) and reaches strictly more
+        # children for the tree router, so taking pass-2 rows never loses
+        # candidates
+        need = (surv1 < thresh)[:, None]
+        return jnp.where(need, ids2, ids1), jnp.where(need, vals2, vals1)
 
 
 @functools.partial(jax.jit, static_argnames=("top_t", "final_k",

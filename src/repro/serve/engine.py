@@ -15,6 +15,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro import faults
 from repro.models import transformer as T
@@ -159,34 +160,45 @@ class AnnEngine:
         pre-composed DEVICE filter bitmap (tenant ∧ alive, cached by the
         TenantFilterBank) that skips the per-call host composition and
         upload `serving_filter` would pay for a user subset.
+
+        The call runs under four host spans, `engine.prepare` (validation,
+        filter, pack, padding), `engine.upload`, `engine.launch` and
+        `engine.download`, which a profiler trace records on the device
+        trace's clock (DESIGN.md §3.15).
         """
         from repro.core.router import clamp_top_t
         from repro.core.search import pad_queries, search_jit_batched
-        p = (params or SearchParams()).validate(
-            default_top_t=self.top_t, default_rerank=self.rerank_budget)
-        Q = validate_queries(Q, self.index.centroids.shape[1],
-                             sanitize=p.sanitize)
-        epoch = getattr(self.index, "_alive_epoch", -1)
-        if Q.shape[0] == 0:
-            return SearchResult(np.empty((0, p.k), np.int32),
-                                np.empty((0, p.k), np.float32),
-                                epoch=epoch, tenant=p.tenant,
-                                deadline_ms=p.deadline_ms)
-        faults.serve_point("engine:search")
-        if _filter_dev is not None:
-            filt, escalate = _filter_dev, p.escalate
-        else:
-            filt, escalate = self.index.serving_filter(
-                mask=p.filter_mask, ids=p.filter_ids, escalate=p.escalate)
-        t0 = time.perf_counter()
-        Qp, nq, bq = pad_queries(Q, self.bq)
-        ids, vals = search_jit_batched(
-            self.index.pack(), jnp.asarray(Qp),
-            top_t=clamp_top_t(p.top_t, self.index.centroids.shape[0]),
-            final_k=p.k, rerank_budget=max(p.rerank_budget, p.k),
-            bq=bq, multiplicity=1 + max(self.index.n_spills, 1),
-            filter=filt, escalate=escalate)
-        ids, vals = np.asarray(ids)[:nq], np.asarray(vals)[:nq]
+        with TraceAnnotation("engine.prepare"):
+            p = (params or SearchParams()).validate(
+                default_top_t=self.top_t, default_rerank=self.rerank_budget)
+            Q = validate_queries(Q, self.index.centroids.shape[1],
+                                 sanitize=p.sanitize)
+            epoch = getattr(self.index, "_alive_epoch", -1)
+            if Q.shape[0] == 0:
+                return SearchResult(np.empty((0, p.k), np.int32),
+                                    np.empty((0, p.k), np.float32),
+                                    epoch=epoch, tenant=p.tenant,
+                                    deadline_ms=p.deadline_ms)
+            faults.serve_point("engine:search")
+            if _filter_dev is not None:
+                filt, escalate = _filter_dev, p.escalate
+            else:
+                filt, escalate = self.index.serving_filter(
+                    mask=p.filter_mask, ids=p.filter_ids, escalate=p.escalate)
+            t0 = time.perf_counter()
+            packed = self.index.pack()
+            Qp, nq, bq = pad_queries(Q, self.bq)
+        with TraceAnnotation("engine.upload"):
+            Qd = jnp.asarray(Qp)
+        with TraceAnnotation("engine.launch"):
+            ids, vals = search_jit_batched(
+                packed, Qd,
+                top_t=clamp_top_t(p.top_t, self.index.centroids.shape[0]),
+                final_k=p.k, rerank_budget=max(p.rerank_budget, p.k),
+                bq=bq, multiplicity=1 + max(self.index.n_spills, 1),
+                filter=filt, escalate=escalate)
+        with TraceAnnotation("engine.download"):
+            ids, vals = np.asarray(ids)[:nq], np.asarray(vals)[:nq]
         return SearchResult(
             ids, vals, engine_us=(time.perf_counter() - t0) * 1e6,
             batch_size=nq, escalated=bool(escalate and filt is not None),

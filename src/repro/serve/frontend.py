@@ -112,6 +112,7 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro import faults
 from repro.core.mutable import EpochLRU
@@ -692,53 +693,60 @@ class ServingFrontend:
         self._dispatch_search(group)
 
     def _dispatch_search(self, group) -> None:
-        p = group[0].params          # key-equal across the group
-        Qcat = (np.concatenate([r.Q for r in group])
-                if len(group) > 1 else group[0].Q)
-        filt_dev = (self.tenants.get(p.tenant)
-                    if p.tenant is not None else None)
-        t0 = time.perf_counter()
-        degraded = False
-        want_replica = self._use_replica(p)
-        use_replica = want_replica and self.health.allow("replica")
-        if want_replica and not use_replica:
-            degraded = True     # breaker open: full-coverage local serve,
-            #                     but the fan-out capacity is reduced
-        ids = None
-        if use_replica:
-            try:
-                ids, vals, escalated = self._replica_search(Qcat, p,
-                                                            filt_dev)
-                self.health.success("replica")
-                self.stats["replica_dispatches"] += 1
-            except Exception:   # replica target failed: trip + fall back
-                self.health.failure("replica")
-                degraded = True
-        if ids is None:         # local path (policy, breaker, or fallback)
-            r = self.engine.search_request(
-                Qcat, p, **({"_filter_dev": filt_dev}
-                            if filt_dev is not None else {}))
-            ids, vals, escalated = r.ids, r.scores, r.escalated
-        if degraded:
-            self.stats["degraded"] += len(group)
-        engine_us = (time.perf_counter() - t0) * 1e6
-        t_done = time.perf_counter()
-        epoch = getattr(self.engine.index, "_alive_epoch", -1)
-        self.stats["dispatches"] += 1
-        self.stats["requests"] += len(group)
-        self.stats["coalesced"] += len(group) - 1
-        total = int(ids.shape[0])
-        off = 0
-        for r in group:
-            sl = slice(off, off + r.nq)
-            off += r.nq
-            r.future.set_result(SearchResult(
-                ids[sl], vals[sl] if vals is not None else None,
-                engine_us=engine_us,
-                queued_us=(t_done - r.t_admit) * 1e6 - engine_us,
-                batch_size=total, escalated=escalated, epoch=epoch,
-                tenant=p.tenant, deadline_ms=r.params.deadline_ms,
-                degraded=degraded, retries=r.retries))
+        """Serve one coalesced search group under the `frontend.dispatch`
+        host span, which encloses `frontend.coalesce`, the engine's spans
+        and `frontend.resolve` (DESIGN.md §3.15)."""
+        with TraceAnnotation("frontend.dispatch", requests=len(group),
+                             queries=sum(r.nq for r in group)):
+            p = group[0].params          # key-equal across the group
+            with TraceAnnotation("frontend.coalesce"):
+                Qcat = (np.concatenate([r.Q for r in group])
+                        if len(group) > 1 else group[0].Q)
+                filt_dev = (self.tenants.get(p.tenant)
+                            if p.tenant is not None else None)
+            t0 = time.perf_counter()
+            degraded = False
+            want_replica = self._use_replica(p)
+            use_replica = want_replica and self.health.allow("replica")
+            if want_replica and not use_replica:
+                degraded = True     # breaker open: full-coverage local serve,
+                #                     but the fan-out capacity is reduced
+            ids = None
+            if use_replica:
+                try:
+                    ids, vals, escalated = self._replica_search(Qcat, p,
+                                                                filt_dev)
+                    self.health.success("replica")
+                    self.stats["replica_dispatches"] += 1
+                except Exception:   # replica target failed: trip + fall back
+                    self.health.failure("replica")
+                    degraded = True
+            if ids is None:         # local path (policy, breaker, or fallback)
+                r = self.engine.search_request(
+                    Qcat, p, **({"_filter_dev": filt_dev}
+                                if filt_dev is not None else {}))
+                ids, vals, escalated = r.ids, r.scores, r.escalated
+            if degraded:
+                self.stats["degraded"] += len(group)
+            engine_us = (time.perf_counter() - t0) * 1e6
+            t_done = time.perf_counter()
+            epoch = getattr(self.engine.index, "_alive_epoch", -1)
+            self.stats["dispatches"] += 1
+            self.stats["requests"] += len(group)
+            self.stats["coalesced"] += len(group) - 1
+            with TraceAnnotation("frontend.resolve"):
+                total = int(ids.shape[0])
+                off = 0
+                for r in group:
+                    sl = slice(off, off + r.nq)
+                    off += r.nq
+                    r.future.set_result(SearchResult(
+                        ids[sl], vals[sl] if vals is not None else None,
+                        engine_us=engine_us,
+                        queued_us=(t_done - r.t_admit) * 1e6 - engine_us,
+                        batch_size=total, escalated=escalated, epoch=epoch,
+                        tenant=p.tenant, deadline_ms=r.params.deadline_ms,
+                        degraded=degraded, retries=r.retries))
 
     # ------------------------------------------------------ replica fan-out
     def _use_replica(self, p: SearchParams) -> bool:
@@ -784,11 +792,13 @@ class ServingFrontend:
             self._rep_cache[key] = fn
         Qp, nq, _ = pad_queries(Q, self.engine.bq, multiple=R)
         packed = self.engine.index.pack()
-        args = (packed, jnp.asarray(Qp)) + ((filt,) if filt is not None
-                                            else ())
-        ids, vals = fn(*args)
-        return (np.asarray(ids)[:nq], np.asarray(vals)[:nq],
-                bool(escalate and filt is not None))
+        with TraceAnnotation("engine.upload"):
+            Qd = jnp.asarray(Qp)
+        with TraceAnnotation("engine.launch"):
+            ids, vals = fn(packed, Qd, *((filt,) if filt is not None else ()))
+        with TraceAnnotation("engine.download"):
+            ids, vals = np.asarray(ids)[:nq], np.asarray(vals)[:nq]
+        return ids, vals, bool(escalate and filt is not None)
 
     # ---------------------------------------------------------- durability
     def save(self, path: str) -> None:
