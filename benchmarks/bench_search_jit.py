@@ -40,7 +40,8 @@ from repro.quant.pq import pq_lut
 def seed_search_jit(packed: PackedIVF, Q, top_t: int, final_k: int,
                     rerank_budget: int = 256):
     """The seed implementation, kept verbatim as the baseline: per-query
-    closure, dense (n,)-scatter dedup, top_k over the whole database."""
+    closure, dense (n,)-scatter dedup, top_k over the whole database.
+    `packed.rerank` holds the (n, d) rows, as the seed's layout had it."""
     C, ids_all, codes_all = packed.centroids, packed.part_ids, packed.part_codes
     n = packed.rerank.shape[0]
 
@@ -103,11 +104,12 @@ def run(n: int, nq: int, c: int, top_t: int, rerank_budget: int,
     Q = jnp.asarray(ds.Q)
     kw = dict(top_t=top_t, final_k=10, rerank_budget=rerank_budget)
 
+    seed_packed = packed._replace(rerank=jnp.asarray(idx.rerank_f32))
     new_ids, _ = search_jit(packed, Q, **kw)              # compile + warmup
-    seed_ids, _ = seed_search_jit(packed, Q, **kw)
+    seed_ids, _ = seed_search_jit(seed_packed, Q, **kw)
     tiled_ids, _ = search_jit_batched(packed, Q, bq=64, **kw)
     t_new = _time(lambda: search_jit(packed, Q, **kw))
-    t_seed = _time(lambda: seed_search_jit(packed, Q, **kw))
+    t_seed = _time(lambda: seed_search_jit(seed_packed, Q, **kw))
     t_tiled = _time(lambda: search_jit_batched(packed, Q, bq=64, **kw))
 
     r_new = recall_at(np.asarray(new_ids), tn)

@@ -403,9 +403,12 @@ def _mutable_from(meta, arrays):
 
 
 def _packed_state(p):
+    from repro.core.search import rerank_table_rows
     rmeta, rarr = _router_state(p.router)
+    # the rerank table is written as (n, d) rows, the format's storage
+    rows = rerank_table_rows(p.rerank, p.centroids.shape[1])
     arrays = {"centroids": p.centroids, "part_ids": p.part_ids,
-              "sizes": p.sizes, "rerank": p.rerank}
+              "sizes": p.sizes, "rerank": rows}
     if p.part_codes is not None:
         arrays["part_codes"] = p.part_codes
     if p.part_codes2 is not None:
@@ -416,7 +419,7 @@ def _packed_state(p):
 
 
 def _packed_from(meta, arrays):
-    from repro.core.search import PackedIVF
+    from repro.core.search import PackedIVF, rerank_table
     import jax.numpy as jnp
     rt = _router_from_state(meta["router"], arrays)
     j = jnp.asarray
@@ -424,7 +427,8 @@ def _packed_from(meta, arrays):
         j(arrays["centroids"]), j(arrays["part_ids"]),
         j(arrays["part_codes"]) if "part_codes" in arrays else None,
         j(arrays["part_codes2"]) if "part_codes2" in arrays else None,
-        j(arrays["sizes"]), _pq_from_state(arrays), j(arrays["rerank"]),
+        j(arrays["sizes"]), _pq_from_state(arrays),
+        j(rerank_table(arrays["rerank"])),
         rt.device() if rt is not None else None)
 
 

@@ -29,8 +29,9 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core.build import build_ivf_sharded, spill_plan
 from repro.core.router import FlatRouter, TreeRouter
-from repro.core.search import (EXACT, _pad_topk, _search_block,
-                               dedup_topk_window, pack_ivf, window_pq_scores)
+from repro.core.search import (_pad_topk, _search_block, dedup_topk_window,
+                               pack_ivf, rerank_scores, rerank_table_shape,
+                               window_pq_scores)
 from repro.kernels.soar_assign import assign_fused
 
 
@@ -39,7 +40,7 @@ class ShardedIVF(NamedTuple):
     centroids: jax.Array     # (D, c, d)
     part_ids: jax.Array      # (D, c, pmax) int32 GLOBAL point ids, -1 pad
     sizes: jax.Array         # (D, c) int32
-    rerank: jax.Array        # (D, n_local, d) — highest-bitrate local shard
+    rerank: jax.Array        # (D, ...) rerank table per shard, as PackedIVF
     local_base: jax.Array    # (D,) int32 global id offset of each shard
 
 
@@ -53,7 +54,7 @@ class ShardedIVFPQ(NamedTuple):
     part_codes: jax.Array    # (D, c, pmax, m) uint8 PQ codes per assignment
     pq_centers: jax.Array    # (D, m, 16, s) per-shard PQ codebook
     sizes: jax.Array         # (D, c) int32
-    rerank: jax.Array        # (D, n_local, d)
+    rerank: jax.Array        # (D, ...) rerank table per shard, as PackedIVF
     local_base: jax.Array    # (D,) int32
 
 
@@ -102,15 +103,15 @@ def _resolve_shard(idx):
 def _stack_shards(indexes):
     """Shared stacker for the ShardedIVF(PQ) builders/refreshers: resolve
     mutable shards, pack, pad ids to the common pmax envelope (-1
-    sentinel) and rerank to the max local id space (zero rows — padded
-    ids never appear in any partition slot, so they are unreachable), and
-    accumulate cumulative global-id base offsets. Returns
-    (packed, resolved, ids, cents, sizes, reranks, bases)."""
+    sentinel) and each rerank table to the longest one (zero rows — the
+    ids they would hold never appear in any partition slot, so they are
+    unreachable), and accumulate cumulative global-id base offsets.
+    Returns (packed, resolved, ids, cents, sizes, reranks, bases)."""
     resolved = list(map(_resolve_shard, indexes))
     packed = [pack_ivf(idx, pair_codes=False) for idx in resolved]
     n_locals = [idx.n_points for idx in resolved]
     pmax = max(pk.part_ids.shape[1] for pk in packed)
-    nmax = max(n_locals)
+    rmax = max(pk.rerank.shape[0] for pk in packed)
     cents, ids, sizes, reranks, bases = [], [], [], [], []
     base = 0
     for pk, nl in zip(packed, n_locals):
@@ -120,7 +121,7 @@ def _stack_shards(indexes):
         cents.append(np.asarray(pk.centroids))
         sizes.append(np.asarray(pk.sizes))
         reranks.append(np.pad(np.asarray(pk.rerank),
-                              ((0, nmax - nl), (0, 0))))
+                              ((0, rmax - pk.rerank.shape[0]), (0, 0))))
         bases.append(base)
         base += nl
     return packed, resolved, ids, cents, sizes, reranks, bases
@@ -195,7 +196,7 @@ def abstract_sharded_ivf(n_shards: int, n_local: int, n_partitions: int,
         f((n_shards, n_partitions, d), jnp.float32),
         f((n_shards, n_partitions, pmax), jnp.int32),
         f((n_shards, n_partitions), jnp.int32),
-        f((n_shards, n_local, d), jnp.float32),
+        f((n_shards,) + rerank_table_shape(n_local, d), jnp.float32),
         f((n_shards,), jnp.int32))
 
 
@@ -208,7 +209,7 @@ def abstract_sharded_ivf_pq(n_shards: int, n_local: int, n_partitions: int,
         f((n_shards, n_partitions, pmax, m), jnp.uint8),
         f((n_shards, m, 16, d // m), jnp.float32),
         f((n_shards, n_partitions), jnp.int32),
-        f((n_shards, n_local, d), jnp.float32),
+        f((n_shards,) + rerank_table_shape(n_local, d), jnp.float32),
         f((n_shards,), jnp.int32))
 
 
@@ -449,8 +450,7 @@ def make_distributed_search(mesh, axes: Tuple[str, ...], *, top_t: int,
         if filt is not None:
             valid = valid & (filt[0][jnp.maximum(ids, 0)] > 0)
             ids = jnp.where(valid, ids, -1)    # filtered ≡ padding for dedup
-        scores = jnp.einsum("qwd,qd->qw", rerank[jnp.maximum(ids, 0)], Q,
-                            precision=EXACT)
+        scores = rerank_scores(rerank, jnp.maximum(ids, 0), Q)
         scores = jnp.where(valid, scores, -jnp.inf)
         ids, vals = dedup_topk_window(ids, scores, final_k, multiplicity)
         # a tombstone-heavy mutable shard (sharded_from_indexes) can have a
@@ -524,8 +524,7 @@ def make_distributed_search_pq(mesh, axes: Tuple[str, ...], *, top_t: int,
             approx = approx + jnp.repeat(psc, pmax, axis=-1)
             approx = jnp.where(valid, approx, -jnp.inf)
             bi, bv = dedup_topk_window(ids, approx, rerank_k, multiplicity)
-            exact = jnp.einsum("qbd,qd->qb", rerank[jnp.maximum(bi, 0)], Qb,
-                               precision=EXACT)
+            exact = rerank_scores(rerank, jnp.maximum(bi, 0), Qb)
             exact = jnp.where(jnp.isfinite(bv), exact, -jnp.inf)
             v, pos = jax.lax.top_k(exact, min(final_k, exact.shape[-1]))
             gi, v = _pad_topk(jnp.take_along_axis(bi, pos, axis=-1), v,

@@ -49,7 +49,7 @@ import numpy as np
 from repro.ckpt.wal import REC_ADD, REC_COMPACT, REC_HARDEN, REC_REMOVE
 from repro.core.build import build_ivf_sharded, spill_plan
 from repro.core.ivf import IVFIndex
-from repro.core.search import PackedIVF, _paired_codes
+from repro.core.search import LANES, PackedIVF, _paired_codes, rerank_table
 from repro.kernels.soar_assign import assign_fused
 from repro.quant.pq import PQCodebook, pq_encode
 
@@ -561,13 +561,23 @@ class MutableIVF:
                         jnp.asarray(_paired_codes(crows)))
         rerank = p.rerank
         if self._dirty_ids < self.n_total:
-            new_rows = jnp.asarray(self.rerank[self._dirty_ids:self.n_total])
             rerank = jax.lax.dynamic_update_slice_in_dim(
-                rerank, new_rows, self._dirty_ids, 0)
+                rerank, *self._table_rows(self._dirty_ids, self.n_total), 0)
         self._dirty_parts[:] = False
         self._dirty_ids = self.n_total
         return PackedIVF(p.centroids, part_ids, part_codes, part_codes2,
                          sizes, self.pq, rerank, self._serving_router())
+
+    def _table_rows(self, lo: int, hi: int):
+        """(table rows holding vectors [lo, hi), index of the first) —
+        the delta pack's upload into the lane-row table (PackedIVF).
+        Vector lo may start mid-row: the row is rewritten whole, with the
+        values of the vectors before lo that it already holds."""
+        d = self.rerank.shape[1]
+        r0, r1 = lo * d // LANES, -(-hi * d // LANES)
+        flat = self.rerank.reshape(-1)[r0 * LANES:r1 * LANES]
+        flat = np.pad(flat, (0, (r1 - r0) * LANES - flat.shape[0]))
+        return jnp.asarray(flat.reshape(-1, LANES)), r0
 
     def pack(self, pair_codes: Optional[bool] = None) -> PackedIVF:
         """Padded snapshot for the candidate-local jit pipeline (cached;
@@ -597,7 +607,7 @@ class MutableIVF:
             (jnp.asarray(_paired_codes(codes))
              if codes is not None and pair_codes else None),
             jnp.asarray(live_sizes), self.pq,
-            jnp.asarray(self.rerank), self._serving_router())
+            jnp.asarray(rerank_table(self.rerank)), self._serving_router())
         self._packed_pair = pair_codes
         self._dirty_parts = np.zeros(ids.shape[0], bool)
         self._dirty_ids = self.n_total

@@ -37,6 +37,7 @@ hardcoding the doubling.
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple, Optional
 
 import jax
@@ -213,6 +214,87 @@ def _search_numpy_pass(index: IVFIndex, Q: np.ndarray, data: np.ndarray,
 # Fixed-budget jit path (TPU target; used by distributed serving + kernels)
 # --------------------------------------------------------------------------
 
+LANES = 128      # minor (lane) width of a TPU vector register and tile
+
+
+def rerank_span(d: int) -> int:
+    """Lane-rows of the rerank table that one d-vector can touch: its
+    start offset within a row is a multiple of gcd(d, 128), so the span
+    reaches at most 128 - gcd(d, 128) + d lanes (2 rows for d=100, 1 for
+    d=128)."""
+    return -(-(d + LANES - math.gcd(d, LANES)) // LANES)
+
+
+def rerank_table_shape(n: int, d: int) -> tuple:
+    """Shape of the rerank table holding n d-vectors: (R, 128) lane-rows,
+    R = ceil(n·d/128) plus the spare rows the last vector's span reads."""
+    return (-(-n * d // LANES) + rerank_span(d) - 1, LANES)
+
+
+def rerank_table(rows) -> np.ndarray:
+    """(n, d) f32 vectors → the rerank table (see `PackedIVF`): vector i
+    is flat elements [i·d, (i+1)·d) of 128-lane rows, zeros after."""
+    rows = np.asarray(rows, np.float32)
+    n, d = rows.shape
+    flat = np.zeros(math.prod(rerank_table_shape(n, d)), np.float32)
+    flat[:n * d] = rows.reshape(-1)
+    return flat.reshape(-1, LANES)
+
+
+def rerank_table_rows(table, d: int) -> np.ndarray:
+    """Inverse of `rerank_table`: the (n', d) vectors a table holds, n'
+    the most whose table has this shape (n' ≥ n; the extra rows are the
+    zero padding, which no partition slot references), so that
+    rows → table → rows → table is the identity on the table."""
+    table = np.asarray(table)
+    n = (table.shape[0] - rerank_span(d) + 1) * LANES // d
+    return table.reshape(-1)[:n * d].reshape(n, d)
+
+
+def rerank_rows(table, ids, d: int):
+    """Gather vectors `ids` (any shape, values in [0, n)) from the rerank
+    table → (rows (*ids.shape, W), lane (*ids.shape,) int32), where
+    rows[..., lane:lane + d] is the vector, bit for bit.
+
+    Vector i starts at flat element i·d, i.e. row ⌊i·d/128⌋, lane
+    (i·d) mod 128; rows are the `rerank_span(d)` table rows its span
+    covers, joined along lanes (W = 128·span). Row and lane are computed
+    without forming i·d, so they hold for any n·d a table can have."""
+    ids = ids.astype(jnp.int32)
+    g = math.gcd(d, LANES)
+    period, dg = LANES // g, d // g        # i = q·period + r
+    q, r = ids // period, ids % period
+    row = q * dg + (r * dg) // period
+    rows = jnp.concatenate([table[row + j] for j in range(rerank_span(d))],
+                           axis=-1)
+    return rows, (r * dg) % period * g
+
+
+def rerank_scores(table, ids, Q):
+    """Exact f32 scores ⟨Q[q], x_ids[q, j]⟩ → (nq, w), for ids (nq, w)
+    and Q (nq, d): the one read of the rerank table on every search path.
+
+    Instead of moving each candidate's d values to lane 0 (a lane shift
+    that differs per candidate, which has no cheap TPU form), the query
+    moves. Qs[q, u] is Q[q] placed at lane u·g, one placement per lane a
+    vector can start at (g = gcd(d, 128); one placement, the identity,
+    when d is a multiple of 128); one batched matmul scores each
+    candidate's rows against every placement, and the candidate keeps
+    the placement at its own start lane. The other lanes meet zeros, so
+    each score sums the same d products as a gather of (n, d) rows
+    would, in another order: equal to float32 rounding."""
+    d = Q.shape[-1]
+    rows, lane = rerank_rows(table, ids, d)
+    g = math.gcd(d, LANES)
+    starts = g * jnp.arange(LANES // g, dtype=jnp.int32)          # (P,)
+    place = (jnp.arange(rows.shape[-1])[None, None, :]
+             == jnp.arange(d)[None, :, None] + starts[:, None, None])
+    Qs = jnp.einsum("qd,udl->qul", Q, place.astype(Q.dtype),
+                    precision=EXACT)                              # (nq, P, W)
+    s = jnp.einsum("qwl,qul->qwu", rows, Qs, precision=EXACT)    # (nq, w, P)
+    return jnp.sum(jnp.where(lane[..., None] == starts, s, 0.0), axis=-1)
+
+
 class PackedIVF(NamedTuple):
     """Dense, padded IVF layout for the jit path.
 
@@ -224,6 +306,16 @@ class PackedIVF(NamedTuple):
                  tail when m is odd), directly indexable into the merged
                  per-query LUT — halves the gather count of CPU scoring
     sizes:       (c,) int32
+    rerank:      f32 rerank table, read only through `rerank_rows`:
+                 (R, 128) lane-rows, vector i at flat elements
+                 [i·d, (i+1)·d), R = ceil(n·d/128) + spare rows (see
+                 `rerank_table_shape`). Why (DESIGN.md §3.6): the TPU
+                 runtime lays an (n, 100) f32 array out column-major
+                 (d on sublanes, padded to 104; n on lanes), and a row
+                 gather then made every search program copy the whole
+                 table into row-major first (473 MB per call at
+                 1.18M × 100). A (R, 128) table has no padding in either
+                 order, is committed row-major, and is gathered in place.
     router:      optional probe-stage Router (core/router.py) attached at
                  pack time; None → flat probe over `centroids` (the
                  historical trace, bitwise)
@@ -234,7 +326,7 @@ class PackedIVF(NamedTuple):
     part_codes2: Optional[jax.Array]
     sizes: jax.Array
     pq: Optional[PQCodebook]
-    rerank: jax.Array           # (n, d) f32
+    rerank: jax.Array           # rerank table, see above
     router: Optional[object] = None
 
 
@@ -309,7 +401,7 @@ def pack_ivf(index: IVFIndex, pmax: Optional[int] = None,
         (jnp.asarray(_paired_codes(codes))
          if codes is not None and pair_codes else None),
         jnp.asarray(np.minimum(sizes, pmax).astype(np.int32)),
-        index.pq, jnp.asarray(data),
+        index.pq, jnp.asarray(rerank_table(data)),
         rt.device() if rt is not None else None)
 
 
@@ -428,9 +520,7 @@ def _search_pass(packed: PackedIVF, Q, router, top_t: int, final_k: int,
         # no PQ stage → exact-score the whole window (search_numpy's
         # rerank_budget=0 semantics); rerank_budget is ignored
         with jax.named_scope("score"):
-            exact = jnp.einsum("qwd,qd->qw",
-                               packed.rerank[jnp.maximum(ids, 0)], Q,
-                               precision=EXACT)
+            exact = rerank_scores(packed.rerank, jnp.maximum(ids, 0), Q)
             exact = jnp.where(valid, exact, -jnp.inf)
         with jax.named_scope("dedup"):
             di, dv = dedup_topk_window(ids, exact, final_k, multiplicity)
@@ -469,8 +559,7 @@ def _search_pass(packed: PackedIVF, Q, router, top_t: int, final_k: int,
         # skip escalation the numpy engine's unique count would take
         surviving = jnp.sum(jnp.isfinite(bv), axis=-1)
     with jax.named_scope("rerank"):
-        exact = jnp.einsum("qbd,qd->qb", packed.rerank[jnp.maximum(bi, 0)], Q,
-                           precision=EXACT)
+        exact = rerank_scores(packed.rerank, jnp.maximum(bi, 0), Q)
         exact = jnp.where(jnp.isfinite(bv), exact, -jnp.inf)
         fv, fpos = jax.lax.top_k(exact, min(final_k, exact.shape[-1]))
         fi, fv = _pad_topk(jnp.take_along_axis(bi, fpos, axis=-1), fv, final_k)

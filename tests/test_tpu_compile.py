@@ -8,7 +8,9 @@ Mosaic lowering refusals, unaligned slices, scoped-VMEM overflows — at no
 chip time. The topology is described inside a fixture, never at import.
 """
 import functools
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -94,9 +96,67 @@ def test_serving_step_compiles_for_v5e(one_chip, monkeypatch):
         sds((C, D), jnp.float32), sds((C, PMAX), jnp.int32),
         sds((C, PMAX, M), jnp.uint8), None, sds((C,), jnp.int32),
         PQCodebook(sds((M, 16, D // M), jnp.float32)),
-        sds((N, D), jnp.float32))
+        sds(search.rerank_table_shape(N, D), jnp.float32))
     txt = search.search_jit_batched.lower(
         packed, sds((1024, D), jnp.float32), top_t=32, final_k=10,
         rerank_budget=256, bq=BQ, multiplicity=2).compile().as_text()
     assert "tpu_custom_call" in txt
     assert "pq_score_window_pallas" in txt
+
+
+# glove-100-angular as one index on one chip (benchmarks/chip/configs):
+# vectors, width, partitions, partition capacity, PQ subspaces.
+GN, GD, GC, GCAP, GM = 1_183_514, 100, 2_959, 1_678, 25
+
+
+@pytest.fixture(scope="module", params=[32, 128], ids=lambda bq: f"bq{bq}")
+def glove_step(request, one_chip):
+    """The compiled entry computation of one `search_jit_batched` call
+    at glove100's shapes for a described v5e, one compile per bq."""
+    from repro.core import search
+    from repro.quant.pq import PQCodebook
+    mp = pytest.MonkeyPatch()
+    mp.setattr(jax, "default_backend", lambda: "tpu")
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    table = search.rerank_table_shape(GN, GD)
+    packed = search.PackedIVF(
+        sds((GC, GD), jnp.float32), sds((GC, GCAP), jnp.int32),
+        sds((GC, GCAP, GM), jnp.uint8), None, sds((GC,), jnp.int32),
+        PQCodebook(sds((GM, 16, GD // GM), jnp.float32)),
+        sds(table, jnp.float32))
+    try:
+        txt = search.search_jit_batched.lower(
+            packed, sds((request.param, GD), jnp.float32), top_t=16,
+            final_k=10, rerank_budget=256, bq=request.param,
+            multiplicity=2).compile().as_text()
+    finally:
+        mp.undo()
+    return table, txt[txt.index("\nENTRY"):].split("\n}")[0]
+
+
+def test_rerank_table_is_read_in_place_at_glove100(glove_step):
+    """The rerank table is committed row-major, (8, 128) tiles, and no
+    operation of the search program copies it whole: each call gathers
+    its candidates' lane-rows from the table as it lies in HBM."""
+    table, entry = glove_step
+    shape = ",".join(map(str, table))
+    param = re.search(
+        r"%(packed_rerank[\w.]*) = f32\[" + shape + r"\]\{([^}]*)\} "
+        r"parameter\(", entry)
+    assert param, "rerank table is not a parameter of the entry computation"
+    name, layout = param.groups()
+    assert layout == "1,0:T(8,128)"
+    size = math.prod(table)
+    operand = re.compile(r"[(, ]%" + re.escape(name) + r"[,)]")
+    copies = []
+    for line in entry.splitlines():
+        if not operand.search(line) or "parameter(" in line:
+            continue
+        result = re.search(r" = (.+?) [\w.-]+\(%", line).group(1)
+        if any(math.prod(map(int, s.split(","))) >= size
+               for s in re.findall(r"\[([\d,]+)\]", result)):
+            copies.append(line.strip()[:160])
+    assert not copies, copies
