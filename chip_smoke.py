@@ -424,6 +424,7 @@ def four_chips(size: Size, seed: int, n_dev: int = 4) -> dict:
     st = fe.stats
     log("frontend_stats " + json.dumps(st, sort_keys=True))
     check(st["replica_dispatches"] > 0, "frontend fanned out over replicas")
+    check(st["replica_placements"] == 1, "index placed on the replicas once")
     for key in ("failures", "retries", "degraded"):
         check(st[key] == 0, f"frontend {key} == 0")
     rep_ids = np.concatenate([r.ids for r in rep])

@@ -389,6 +389,26 @@ def make_replicated_search(mesh, axes: Tuple[str, ...], *, top_t: int,
                          out_specs=(P(a), P(a)), check_vma=False)
 
 
+def replicate_on_mesh(tree, mesh):
+    """`tree` (a PackedIVF, a filter bitmap) laid out as
+    NamedSharding(mesh, P()) — the layout make_replicated_search's index
+    and filter arguments expect, so its calls move no index bytes.
+
+    Each leaf, a device array on one device of the mesh, keeps that
+    buffer as its shard there (that device holds it once) and is copied
+    once to each other device. Passing the single-device tree instead
+    makes JAX broadcast all of it on every call (DESIGN.md §3.12)."""
+    from jax.sharding import NamedSharding
+    rep = NamedSharding(mesh, P())
+
+    def one(x):
+        shards = [x if d in x.devices() else jax.device_put(x, d)
+                  for d in mesh.devices.flat]
+        return jax.make_array_from_single_device_arrays(x.shape, rep, shards)
+
+    return jax.tree_util.tree_map(one, tree)
+
+
 def _apply_params(params, top_t, final_k):
     """Resolve a serve/api.SearchParams against a distributed maker's
     kwargs — the unified request API's seam into this layer."""

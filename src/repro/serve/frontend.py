@@ -52,7 +52,9 @@ module adds the missing production layer:
   make_replicated_search (index replicated, queries split): the
   data-parallel dual of the shard-parallel distributed layer, bitwise
   identical to local execution because replicas run the same query-local
-  pipeline with no collectives.
+  pipeline with no collectives. The packed index and each filter bitmap
+  are placed on the replicas once per snapshot (device 0 keeps the
+  engine's own buffers), and queries go straight to their replica.
 
 Durability rides the engine snapshot: `save` stores the front-end config
 and every tenant bitmap as `extra`/`extra_arrays` alongside the index, and
@@ -340,6 +342,7 @@ class ServingFrontend:
                                         capacity=tenant_capacity)
         self.stats = {"dispatches": 0, "coalesced": 0, "requests": 0,
                       "mutations": 0, "replica_dispatches": 0,
+                      "replica_placements": 0, "replica_placed_bytes": 0,
                       "rejected": 0, "shed": 0, "expired": 0,
                       "retries": 0, "failures": 0, "degraded": 0}
         self._q: deque = deque()
@@ -350,6 +353,11 @@ class ServingFrontend:
         self._draining = False
         self._rep_cache: dict = {}      # static-shape key -> jitted replica fn
         self._mesh = None
+        self._rows = None               # queries split row-wise on _mesh
+        # replicated placements by source identity: the current pack, and
+        # the standing tombstone filter plus every cached tenant bitmap
+        self._rep_index = EpochLRU(capacity=1)
+        self._rep_filters = EpochLRU(capacity=tenant_capacity + 1)
         self._thread = threading.Thread(target=self._loop,
                                         name="serve-frontend", daemon=True)
         self._thread.start()
@@ -775,30 +783,52 @@ class ServingFrontend:
             filt, escalate = filt_dev, p.escalate
         devs = jax.devices()
         R = len(devs)
+        if self._mesh is None:
+            from jax.sharding import Mesh, NamedSharding
+            from jax.sharding import PartitionSpec as P
+            self._mesh = Mesh(np.array(devs), ("r",))
+            self._rows = NamedSharding(self._mesh, P("r"))
         top_t = clamp_top_t(p.top_t, self.engine.index.centroids.shape[0])
         mult = 1 + max(self.engine.index.n_spills, 1)
         key = (top_t, p.k, max(p.rerank_budget, p.k), mult,
                bool(escalate), filt is not None, R)
         fn = self._rep_cache.get(key)
         if fn is None:
-            from jax.sharding import Mesh
             from repro.core.distributed import make_replicated_search
-            if self._mesh is None:
-                self._mesh = Mesh(np.array(devs), ("r",))
             fn = jax.jit(make_replicated_search(
                 self._mesh, ("r",), top_t=top_t, final_k=p.k,
                 rerank_budget=max(p.rerank_budget, p.k), multiplicity=mult,
                 with_filter=filt is not None, escalate=bool(escalate)))
             self._rep_cache[key] = fn
-        Qp, nq, _ = pad_queries(Q, self.engine.bq, multiple=R)
-        packed = self.engine.index.pack()
-        with TraceAnnotation("engine.upload"):
-            Qd = jnp.asarray(Qp)
-        with TraceAnnotation("engine.launch"):
-            ids, vals = fn(packed, Qd, *((filt,) if filt is not None else ()))
-        with TraceAnnotation("engine.download"):
+        args = [self._placed(self._rep_index, self.engine.index.pack())]
+        if filt is not None:
+            args.append(self._placed(self._rep_filters, filt))
+        with TraceAnnotation("replica.scatter"):
+            Qp, nq, _ = pad_queries(Q, self.engine.bq, multiple=R)
+            Qd = jax.device_put(Qp, self._rows)
+        with TraceAnnotation("replica.launch"):
+            ids, vals = fn(args[0], Qd, *args[1:])
+        with TraceAnnotation("replica.gather"):
             ids, vals = np.asarray(ids)[:nq], np.asarray(vals)[:nq]
         return ids, vals, bool(escalate and filt is not None)
+
+    def _placed(self, cache: EpochLRU, src):
+        """`src` (the packed index, a filter bitmap) replicated on the
+        replica mesh, placed once per source object: pack() and the filter
+        caches return the same object until a mutation, so a new object is
+        exactly what needs placing again. The entry holds `src`, so its
+        id is not reused while cached (DESIGN.md §3.12)."""
+        def place():
+            from repro.core.distributed import replicate_on_mesh
+            nbytes = (sum(x.nbytes for x in jax.tree_util.tree_leaves(src))
+                      * (self._mesh.devices.size - 1))
+            with TraceAnnotation("replica.place", bytes=nbytes):
+                placed = jax.block_until_ready(
+                    replicate_on_mesh(src, self._mesh))
+            self.stats["replica_placements"] += 1
+            self.stats["replica_placed_bytes"] += nbytes
+            return src, placed
+        return cache.get(id(src), None, place)[1]
 
     # ---------------------------------------------------------- durability
     def save(self, path: str) -> None:

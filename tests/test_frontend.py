@@ -332,3 +332,100 @@ def test_replica_policy_multidevice():
                         "JAX_PLATFORMS": "cpu"})
     assert r.returncode == 0, (r.stdout[-1000:], r.stderr[-3000:])
     assert "OK" in r.stdout
+
+
+# The index is placed on the replicas once per packed snapshot (DESIGN.md
+# §3.12). After the warm-up dispatch every further one runs with implicit
+# device-to-device transfers disallowed for the whole process (the guard
+# is set globally: the dispatcher is another thread), so a dispatch that
+# broadcast the index or a filter again would raise.
+SCRIPT_PLACEMENT = r"""
+import os, sys
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import jax, numpy as np
+from chip_smoke import exact_topk, recall_at_k
+from repro.data.vectors import make_manifold
+from repro.serve.api import SearchParams
+from repro.serve.engine import AnnEngine
+from repro.serve.frontend import ServingFrontend
+
+case = sys.argv[1]
+assert len(jax.devices()) == 4
+ds = make_manifold(jax.random.PRNGKey(0), n=3_000, d=24, nq=32,
+                   intrinsic_dim=8)
+eng = AnnEngine.build(jax.random.PRNGKey(1), ds.X, 16, train_iters=5)
+fe = ServingFrontend(eng, policy="replica", default_deadline_ms=200.0)
+fe.search(ds.Q, SearchParams(k=10))              # compiles and places
+st = fe.stats
+assert st["replica_placements"] == 1, st
+jax.config.update("jax_transfer_guard_device_to_device", "disallow")
+
+
+def same_as_local(**kw):
+    r = fe.search(ds.Q, SearchParams(k=10, **kw))
+    ids, sc = eng.search(ds.Q, k=10, **({"filter_ids": TENANT_IDS}
+                                        if kw else {}))
+    assert not r.degraded, "the replica dispatch fell back"
+    assert np.array_equal(r.ids, ids), "replica ids != local"
+    assert np.array_equal(r.scores, sc), "replica scores != local"
+    return r.ids
+
+
+def exact_recall(ids):
+    # the exact brute force over the live set; 0.9 is the floor the chip
+    # smoke holds this pipeline to, and the replica answers equal the
+    # local ones bitwise, so recall only catches a stale replica (a
+    # removed id served, an added one missed)
+    keep = eng.index.alive[:eng.index.n_total].astype(bool)
+    X = eng.index.rerank[:eng.index.n_total]
+    rec = recall_at_k(ids, exact_topk(X, ds.Q, keep=keep))
+    assert rec >= 0.9, rec
+    assert keep[ids[ids >= 0]].all(), "a removed id was served"
+
+
+TENANT_IDS = np.arange(0, 3_000, 2)
+if case == "steady":
+    for _ in range(3):
+        same_as_local()
+    assert st["replica_placements"] == 1 and st["replica_dispatches"] == 4
+    packed = eng.index.pack()
+    placed = fe._placed(fe._rep_index, packed)
+    (shard0,) = [s for s in placed.rerank.addressable_shards
+                 if s.device == jax.devices()[0]]
+    assert (shard0.data.unsafe_buffer_pointer()
+            == packed.rerank.unsafe_buffer_pointer()), "index held twice"
+    size = sum(x.nbytes for x in jax.tree_util.tree_leaves(packed))
+    assert st["replica_placed_bytes"] == 3 * size, st
+elif case == "mutation":
+    new = make_manifold(jax.random.PRNGKey(5), n=200, d=24, nq=1,
+                        intrinsic_dim=8).X
+    added = fe.add(new)
+    exact_recall(same_as_local())
+    assert st["replica_placements"] == 2, st
+    fe.remove(np.concatenate([added[:50], np.arange(0, 3_000, 7)]))
+    exact_recall(same_as_local())
+    assert st["replica_placements"] == 3, st
+elif case == "tenant":
+    fe.register_tenant("t", ids=TENANT_IDS)
+    for _ in range(3):
+        ids = same_as_local(tenant="t")
+        assert np.isin(ids[ids >= 0], TENANT_IDS).all()
+    assert st["replica_placements"] == 2 and fe.tenants.fills == 1, st
+fe.close()
+assert st["failures"] == 0 and st["degraded"] == 0, st
+print("OK")
+"""
+
+
+@pytest.mark.parametrize("case", ["steady", "mutation", "tenant"])
+def test_replica_placement_multidevice(case):
+    """steady: no implicit device-to-device copy after the warm-up, one
+    placement, device 0's shard is the engine's own buffer; mutation: an
+    add and a remove each place the new pack again; tenant: a tenant
+    bitmap is placed once. Every answer is bitwise the local path's."""
+    r = subprocess.run(
+        [sys.executable, "-c", SCRIPT_PLACEMENT, case], capture_output=True,
+        text=True, env={"PYTHONPATH": "src:.", "PATH": "/usr/bin:/bin",
+                        "JAX_PLATFORMS": "cpu"}, timeout=600)
+    assert r.returncode == 0, (r.stdout[-1000:], r.stderr[-3000:])
+    assert "OK" in r.stdout

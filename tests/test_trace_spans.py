@@ -4,13 +4,18 @@
    trace, read back with the benchmark's own reduction
    (benchmarks/chip/tracefile.py), holds one `frontend.dispatch` span per
    dispatch the frontend counted, each enclosing the six step spans once,
-   in the order the dispatcher runs them.
+   in the order the dispatcher runs them. A replica dispatch on four
+   devices encloses `replica.scatter`, `replica.launch` and
+   `replica.gather` instead of the engine's steps, and `replica.place`
+   where it places a new pack, with the bytes it copied.
 2. Device stages: every stage of the search program carries its
    `jax.named_scope` into the compiled HLO's `op_name` metadata, which is
    what a device trace reports as each operation's `tf_op`.
 """
+import json
 import os
 import re
+import subprocess
 import sys
 import threading
 
@@ -89,6 +94,71 @@ def test_host_spans_nest_in_each_dispatch_in_order(ds, engine, tmp_path):
         assert d.span.start <= seq[0].start
         assert all(a.end <= b.start for a, b in zip(seq, seq[1:]))
         assert seq[-1].end <= d.span.end
+
+
+SCRIPT_REPLICA_TRACE = r"""
+import json, os, sys
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import jax
+from benchmarks.chip import tracefile
+from repro.data.vectors import make_manifold
+from repro.serve.engine import AnnEngine
+from repro.serve.frontend import ServingFrontend
+
+ds = make_manifold(jax.random.PRNGKey(0), n=3_000, d=24, nq=32,
+                   intrinsic_dim=8)
+eng = AnnEngine.build(jax.random.PRNGKey(1), ds.X, 16, train_iters=5)
+fe = ServingFrontend(eng, policy="replica", default_deadline_ms=200.0)
+fe.search(ds.Q)                         # compiles and places, untraced
+before = dict(fe.stats)
+opts = jax.profiler.ProfileOptions()
+opts.python_tracer_level = 0
+jax.profiler.start_trace(sys.argv[1], profiler_options=opts)
+with jax.profiler.TraceAnnotation(tracefile.WINDOW):
+    fe.add(ds.X[:64] * 0.5)             # a new pack: placed again
+    fe.search(ds.Q)
+    fe.search(ds.Q)
+    fe.close()
+jax.profiler.stop_trace()
+size = sum(x.nbytes for x in jax.tree_util.tree_leaves(eng.index.pack()))
+print(json.dumps({"before": before, "stats": fe.stats, "pack_bytes": size}))
+"""
+
+
+def test_replica_spans_nest_in_each_dispatch(tmp_path):
+    from jax.profiler import ProfileData
+    r = subprocess.run(
+        [sys.executable, "-c", SCRIPT_REPLICA_TRACE, str(tmp_path)],
+        capture_output=True, text=True, cwd=ROOT, timeout=600,
+        env={"PYTHONPATH": "src:.", "PATH": "/usr/bin:/bin",
+             "JAX_PLATFORMS": "cpu"})
+    assert r.returncode == 0, (r.stdout[-1000:], r.stderr[-3000:])
+    out = json.loads(r.stdout.splitlines()[-1])
+    assert out["stats"]["replica_dispatches"] == 3
+    assert out["stats"]["replica_placements"] == 2
+    tr = tracefile.load(str(tmp_path))
+    got = spans.dispatches(tr)
+    assert len(got) == 2
+    steps = ("replica.place", "replica.scatter", "replica.launch",
+             "replica.gather")
+    for d, placed in zip(got, (True, False)):
+        assert not d.steps.keys() & {"engine.prepare", "engine.upload",
+                                     "engine.launch", "engine.download"}
+        inner = sorted((e for e in tr.host if e.name in steps
+                        and d.span.start <= e.start
+                        and e.end <= d.span.end), key=lambda e: e.start)
+        names = tuple(e.name for e in inner)
+        assert names == steps[0 if placed else 1:], names
+        assert all(a.end <= b.start for a, b in zip(inner, inner[1:]))
+    (path,) = [os.path.join(dp, f) for dp, _, fs in os.walk(tmp_path)
+               for f in fs if f.endswith(".xplane.pb")]
+    placed_bytes = [dict(e.stats).get("bytes")
+                    for p in ProfileData.from_file(path).planes
+                    for line in p.lines for e in line.events
+                    if e.name == "replica.place"]
+    assert placed_bytes == [3 * out["pack_bytes"]]
+    assert placed_bytes[0] == (out["stats"]["replica_placed_bytes"]
+                               - out["before"]["replica_placed_bytes"])
 
 
 # ---------------------------------------------------------- device stages
