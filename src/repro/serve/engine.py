@@ -71,6 +71,59 @@ class ServeEngine:
         return jnp.concatenate(out, axis=1)
 
 
+class PendingSearch:
+    """A search launched on the device: the program is queued, and its
+    padded ids and scores are already being copied back
+    (`copy_to_host_async`). `wait()` blocks until the device has computed
+    them and fetches nothing. The first read of `ids` or `scores` collects
+    both, in one `jax.device_get` under the `span` host span, and slices
+    off the padding; a caller may replace them, as on a SearchResult.
+    `result()` is the SearchResult. The serve point named like the span
+    (`engine:download`, `replica:gather`) fires at collection, where an
+    error of the device surfaces (DESIGN.md §3.12–3.13)."""
+
+    def __init__(self, arrays, nq: int, *, t0: Optional[float] = None,
+                 span: str = "engine.download", **meta):
+        self.arrays = arrays            # (ids, scores), padded
+        self.nq, self.t0, self.span, self.meta = nq, t0, span, meta
+        self._host = None
+        for a in arrays:
+            if isinstance(a, jax.Array):
+                a.copy_to_host_async()
+
+    def wait(self) -> None:
+        jax.block_until_ready(self.arrays)
+
+    def _collect(self) -> list:
+        if self._host is None:
+            with TraceAnnotation(self.span):
+                faults.serve_point(self.span.replace(".", ":"))
+                ids, scores = jax.device_get(self.arrays)
+            self._host = [ids[:self.nq], scores[:self.nq]]
+        return self._host
+
+    @property
+    def ids(self) -> np.ndarray:
+        return self._collect()[0]
+
+    @ids.setter
+    def ids(self, v) -> None:
+        self._collect()[0] = v
+
+    @property
+    def scores(self) -> np.ndarray:
+        return self._collect()[1]
+
+    @scores.setter
+    def scores(self, v) -> None:
+        self._collect()[1] = v
+
+    def result(self) -> SearchResult:
+        ids, scores = self._collect()
+        us = 0.0 if self.t0 is None else (time.perf_counter() - self.t0) * 1e6
+        return SearchResult(ids, scores, engine_us=us, **self.meta)
+
+
 class AnnEngine:
     """Online ANN serving engine over a mutable SOAR index.
 
@@ -150,7 +203,7 @@ class AnnEngine:
         return r.ids, r.scores
 
     def search_request(self, Q, params: Optional[SearchParams] = None, *,
-                       _filter_dev=None) -> SearchResult:
+                       _filter_dev=None, wait: bool = True):
         """Structured serving entry point: (nq, d) queries + SearchParams
         → SearchResult (DESIGN.md §3.12).
 
@@ -161,10 +214,15 @@ class AnnEngine:
         TenantFilterBank) that skips the per-call host composition and
         upload `serving_filter` would pay for a user subset.
 
+        `wait=False` returns as soon as the search program is launched,
+        with a PendingSearch whose `result()` collects it: the front-end
+        launches the next batch before it collects this one.
+
         The call runs under four host spans, `engine.prepare` (validation,
-        filter, pack, padding), `engine.upload`, `engine.launch` and
-        `engine.download`, which a profiler trace records on the device
-        trace's clock (DESIGN.md §3.15).
+        filter, pack, padding), `engine.upload`, `engine.launch` (the call
+        and the start of the copy back) and `engine.download` (at
+        collection), which a profiler trace records on the device trace's
+        clock (DESIGN.md §3.15).
         """
         from repro.core.router import clamp_top_t
         from repro.core.search import pad_queries, search_jit_batched
@@ -173,12 +231,13 @@ class AnnEngine:
                 default_top_t=self.top_t, default_rerank=self.rerank_budget)
             Q = validate_queries(Q, self.index.centroids.shape[1],
                                  sanitize=p.sanitize)
-            epoch = getattr(self.index, "_alive_epoch", -1)
+            meta = dict(epoch=getattr(self.index, "_alive_epoch", -1),
+                        tenant=p.tenant, deadline_ms=p.deadline_ms)
             if Q.shape[0] == 0:
-                return SearchResult(np.empty((0, p.k), np.int32),
-                                    np.empty((0, p.k), np.float32),
-                                    epoch=epoch, tenant=p.tenant,
-                                    deadline_ms=p.deadline_ms)
+                empty = PendingSearch((np.empty((0, p.k), np.int32),
+                                       np.empty((0, p.k), np.float32)),
+                                      0, **meta)
+                return empty.result() if wait else empty
             faults.serve_point("engine:search")
             if _filter_dev is not None:
                 filt, escalate = _filter_dev, p.escalate
@@ -191,18 +250,16 @@ class AnnEngine:
         with TraceAnnotation("engine.upload"):
             Qd = jnp.asarray(Qp)
         with TraceAnnotation("engine.launch"):
-            ids, vals = search_jit_batched(
-                packed, Qd,
-                top_t=clamp_top_t(p.top_t, self.index.centroids.shape[0]),
-                final_k=p.k, rerank_budget=max(p.rerank_budget, p.k),
-                bq=bq, multiplicity=1 + max(self.index.n_spills, 1),
-                filter=filt, escalate=escalate)
-        with TraceAnnotation("engine.download"):
-            ids, vals = np.asarray(ids)[:nq], np.asarray(vals)[:nq]
-        return SearchResult(
-            ids, vals, engine_us=(time.perf_counter() - t0) * 1e6,
-            batch_size=nq, escalated=bool(escalate and filt is not None),
-            epoch=epoch, tenant=p.tenant, deadline_ms=p.deadline_ms)
+            pending = PendingSearch(
+                search_jit_batched(
+                    packed, Qd,
+                    top_t=clamp_top_t(p.top_t, self.index.centroids.shape[0]),
+                    final_k=p.k, rerank_budget=max(p.rerank_budget, p.k),
+                    bq=bq, multiplicity=1 + max(self.index.n_spills, 1),
+                    filter=filt, escalate=escalate),
+                nq, t0=t0, batch_size=nq,
+                escalated=bool(escalate and filt is not None), **meta)
+        return pending.result() if wait else pending
 
     # ---------------------------------------------------------- durability
     def save(self, path: str, *, extra: Optional[dict] = None,

@@ -17,6 +17,12 @@ module adds the missing production layer:
   compile, so it NEVER adds a compile (pinned by
   tests/test_frontend.py::test_no_recompilation).
 
+- **Two batches in flight** — the dispatcher launches a ripe group while
+  fewer than `DEPTH` (two) are in flight and collects each once a waiter
+  thread has seen its results computed, so the host's preparation and
+  copy back overlap the device's work on the other batch. Futures are
+  still resolved on the dispatcher thread, in launch order.
+
 - **Deadline-aware flushing** — a batch dispatches when it reaches
   `max_batch` queries OR when the oldest compatible request has spent half
   its `deadline_ms` budget waiting (clamped by `max_delay_ms`, so
@@ -103,7 +109,9 @@ Resilience layer (ISSUE 9, DESIGN.md §3.13) — the front-end learns to say
 """
 from __future__ import annotations
 
+import contextlib
 import os
+import queue
 import threading
 import time
 from collections import deque
@@ -122,7 +130,7 @@ from repro.serve.api import (DEFAULT_DEADLINE_MS, DeadlineExceededError,
                              FrontendClosedError, OverloadedError,
                              SearchParams, SearchResult, _positive_int,
                              is_retryable)
-from repro.serve.engine import AnnEngine
+from repro.serve.engine import AnnEngine, PendingSearch
 from repro.serve.health import HealthTracker
 
 
@@ -271,6 +279,23 @@ class _Request:
                 else self.deadline_at - time.perf_counter())
 
 
+DEPTH = 2   # coalesced search groups in flight at once (DESIGN.md §3.12)
+
+
+@dataclass
+class _Flight:
+    """A launched search group whose results are on their way back."""
+    group: list
+    pending: PendingSearch
+    Q: np.ndarray                 # the coalesced queries and filter, kept
+    filt_dev: Optional[jax.Array]  # for a replica group's local fallback
+    t0: float                     # perf_counter at launch
+    degraded: bool
+    replica: bool                 # launched on the replicas
+    ahead: bool                   # launched with another group in flight
+    ready: bool = False           # computed on the device (the waiter)
+
+
 class ServingFrontend:
     """Async serving loop in front of AnnEngine (DESIGN.md §3.12).
 
@@ -344,8 +369,12 @@ class ServingFrontend:
                       "mutations": 0, "replica_dispatches": 0,
                       "replica_placements": 0, "replica_placed_bytes": 0,
                       "rejected": 0, "shed": 0, "expired": 0,
-                      "retries": 0, "failures": 0, "degraded": 0}
+                      "retries": 0, "failures": 0, "degraded": 0,
+                      "launched_ahead": 0}
         self._q: deque = deque()
+        self._inflight: deque = deque()   # launched groups, oldest first
+        self._busy = False              # the dispatcher holds a group
+        self._launched: queue.SimpleQueue = queue.SimpleQueue()
         self._cost = 0                  # admission units currently queued
         self._fatal: Optional[BaseException] = None
         self._cond = threading.Condition()
@@ -360,7 +389,11 @@ class ServingFrontend:
         self._rep_filters = EpochLRU(capacity=tenant_capacity + 1)
         self._thread = threading.Thread(target=self._loop,
                                         name="serve-frontend", daemon=True)
+        self._waiter = threading.Thread(target=self._wait_ready,
+                                        name="serve-frontend-waiter",
+                                        daemon=True)
         self._thread.start()
+        self._waiter.start()
 
     # ------------------------------------------------------------- clients
     def submit(self, Q, params: Optional[SearchParams] = None) -> Future:
@@ -433,13 +466,14 @@ class ServingFrontend:
         self.tenants.register(tenant, ids=ids, mask=mask)
 
     def flush(self) -> None:
-        """Block until every currently queued request has dispatched
-        (pending deadline timers are overridden — the queue drains now)."""
+        """Block until every currently queued request, and every launched
+        batch, has been served (pending deadline timers are overridden —
+        the queue drains now)."""
         with self._cond:
             self._draining = True
             self._cond.notify_all()
             self._cond.wait_for(
-                lambda: not self._q or self._closed
+                lambda: self._idle_locked() or self._closed
                 or self._fatal is not None)
             self._draining = False
 
@@ -447,21 +481,24 @@ class ServingFrontend:
         """Stop the dispatcher. Idempotent, and deterministic about every
         pending Future: `drain=True` (default) serves the queue first;
         `drain=False` fails queued Futures with FrontendClosedError
-        immediately. If the dispatcher already died, pending Futures were
-        failed at death — close() just reaps the thread."""
+        immediately; batches already launched are still served. If the
+        dispatcher already died, pending Futures were failed at death —
+        close() just reaps the threads."""
         with self._cond:
             if not self._closed:
                 if drain and self._fatal is None:
                     self._draining = True
                     self._cond.notify_all()
                     self._cond.wait_for(
-                        lambda: not self._q or self._fatal is not None)
+                        lambda: self._idle_locked()
+                        or self._fatal is not None)
                     self._draining = False
                 self._fail_pending_locked(FrontendClosedError(
                     "front-end is closed (closed before dispatch)"))
                 self._closed = True
                 self._cond.notify_all()
         self._thread.join(timeout=10.0)
+        self._waiter.join(timeout=10.0)
 
     def _fail_pending_locked(self, exc: BaseException) -> None:
         """Lock held: fail every queued Future with `exc` and empty the
@@ -472,6 +509,11 @@ class ServingFrontend:
                 r.future.set_exception(exc)
         self._q.clear()
         self._cost = 0
+
+    def _idle_locked(self) -> bool:
+        """Lock held: nothing queued, in flight or in the dispatcher's
+        hands."""
+        return not self._q and not self._inflight and not self._busy
 
     def __enter__(self):
         return self
@@ -531,19 +573,24 @@ class ServingFrontend:
                 f"nothing sheddable)")
 
     def _loop(self) -> None:
+        """The dispatcher: launch a ripe group while fewer than DEPTH are
+        in flight, collect the oldest once the waiter has seen its results
+        computed, and otherwise sleep on the one condition that both the
+        queue and the waiter signal (DESIGN.md §3.12)."""
         try:
             while True:
                 with self._cond:
-                    group, timeout = self._collect_locked()
-                    if group is None:
-                        if self._closed and not self._q:
-                            return
-                        self._cond.wait(timeout=timeout)
-                        continue
-                    if not self._q:
-                        self._cond.notify_all()   # wake flush()/close()
+                    flight, group = self._next_locked()
+                    if flight is None and group is None:
+                        return
+                    self._busy = True
+                if flight is not None:
+                    group = flight.group
                 try:
-                    self._dispatch(group)
+                    if flight is not None:
+                        self._complete(flight)
+                    else:
+                        self._dispatch(group)
                 except Exception as e:       # contained: group-local
                     self._contain(group, e)
                 except BaseException as e:   # fatal: crash the dispatcher
@@ -552,30 +599,69 @@ class ServingFrontend:
                             r.future.set_exception(e)
                     raise
                 with self._cond:
-                    if not self._q:
-                        self._cond.notify_all()
+                    self._busy = False
+                    if self._idle_locked():
+                        self._cond.notify_all()   # wake flush()/close()
         except BaseException as e:  # noqa: BLE001 — recorded as _fatal
             self._dispatcher_died(e)
+        finally:
+            self._launched.put(None)             # stops the waiter
+
+    def _next_locked(self):
+        """Lock held: wait for the dispatcher's next step — (flight, None)
+        to collect the oldest launched group, whose results are computed;
+        (None, group) to dispatch a group from the queue; (None, None) to
+        exit. Collecting first resolves finished requests without waiting
+        for another launch."""
+        while True:
+            if self._inflight and self._inflight[0].ready:
+                return self._inflight.popleft(), None
+            timeout = None
+            if len(self._inflight) < DEPTH:
+                group, timeout = self._next_group_locked()
+                if group is not None:
+                    return None, group
+            if self._closed and not self._q and not self._inflight:
+                return None, None
+            self._cond.wait(timeout=timeout)
+
+    def _wait_ready(self) -> None:
+        """The waiter thread: blocks on each launched group's results in
+        launch order and marks it ready under the condition. It resolves
+        nothing and opens no span; an error of the device surfaces again
+        where the dispatcher collects the group."""
+        while (flight := self._launched.get()) is not None:
+            with contextlib.suppress(Exception):
+                flight.pending.wait()
+            with self._cond:
+                flight.ready = True
+                self._cond.notify_all()
 
     def _dispatcher_died(self, exc: BaseException) -> None:
         """The dispatcher thread is exiting on a fatal error. Fail every
-        queued Future (nobody should block forever on a dead loop) and
-        poison `submit` — pinned by the stranded-Future regression test."""
+        queued and in-flight Future (nobody should block forever on a dead
+        loop) and poison `submit` — pinned by the stranded-Future
+        regression tests."""
         with self._cond:
             self._fatal = exc
             err = FrontendClosedError(
                 f"dispatcher thread died: {exc!r}")
             err.__cause__ = exc
             self._fail_pending_locked(err)
+            for flight in self._inflight:
+                for r in flight.group:
+                    if not r.future.done():
+                        r.future.set_exception(err)
+            self._inflight.clear()
             self._cond.notify_all()
 
     def _contain(self, group, exc: Exception) -> None:
-        """An engine Exception during dispatch: fail THIS group only; the
-        dispatcher keeps serving. Retryable search failures get a bounded
-        exponential backoff and re-queue at the head (still before any
-        queued mutation — searches at one epoch commute, so head re-entry
-        preserves the barrier order). Mutations never retry: the engine
-        may have partially applied the write, and replaying it could
+        """An engine Exception at launch or at collection: fail THIS group
+        only; the dispatcher keeps serving. Retryable search failures get a
+        bounded exponential backoff and re-queue at the head (still before
+        any queued mutation — searches at one epoch commute, so head
+        re-entry preserves the barrier order). Mutations never retry: the
+        engine may have partially applied the write, and replaying it could
         double-apply."""
         r0 = group[0]
         if (r0.kind == "search" and is_retryable(exc)
@@ -602,10 +688,11 @@ class ServingFrontend:
             if not r.future.done():
                 r.future.set_exception(exc)
 
-    def _collect_locked(self):
+    def _next_group_locked(self):
         """With the lock held: pick the next dispatch group, or
         (None, timeout) to sleep. Mutations dispatch only from the queue
-        head (strict barrier); searches group by coalescing key across the
+        head (strict barrier), and only once every launched search has been
+        collected and resolved; searches group by coalescing key across the
         pre-mutation prefix (searches at one epoch commute, so grouping
         past a different-keyed search is safe — past a mutation is not).
 
@@ -619,6 +706,8 @@ class ServingFrontend:
             return None, None
         head = q[0]
         if head.kind != "search":
+            if self._inflight:
+                return None, None   # the waiter wakes us as they finish
             q.popleft()
             self._cost -= head.cost
             return [head], None
@@ -698,12 +787,13 @@ class ServingFrontend:
             self.stats["mutations"] += 1
             req.future.set_result(n)
             return
-        self._dispatch_search(group)
+        self._launch(group)
 
-    def _dispatch_search(self, group) -> None:
-        """Serve one coalesced search group under the `frontend.dispatch`
-        host span, which encloses `frontend.coalesce`, the engine's spans
-        and `frontend.resolve` (DESIGN.md §3.15)."""
+    def _launch(self, group) -> None:
+        """Launch one coalesced search group and put it in flight, under
+        the `frontend.dispatch` host span, which encloses
+        `frontend.coalesce` and the engine's (or the replicas') launch-side
+        spans (DESIGN.md §3.15)."""
         with TraceAnnotation("frontend.dispatch", requests=len(group),
                              queries=sum(r.nq for r in group)):
             p = group[0].params          # key-equal across the group
@@ -713,35 +803,61 @@ class ServingFrontend:
                 filt_dev = (self.tenants.get(p.tenant)
                             if p.tenant is not None else None)
             t0 = time.perf_counter()
-            degraded = False
             want_replica = self._use_replica(p)
             use_replica = want_replica and self.health.allow("replica")
-            if want_replica and not use_replica:
-                degraded = True     # breaker open: full-coverage local serve,
-                #                     but the fan-out capacity is reduced
-            ids = None
+            # breaker open: full-coverage local serve, but the fan-out
+            # capacity is reduced
+            degraded = want_replica and not use_replica
+            pending = None
             if use_replica:
                 try:
-                    ids, vals, escalated = self._replica_search(Qcat, p,
-                                                                filt_dev)
-                    self.health.success("replica")
-                    self.stats["replica_dispatches"] += 1
+                    pending = self._replica_launch(Qcat, p, filt_dev)
                 except Exception:   # replica target failed: trip + fall back
                     self.health.failure("replica")
                     degraded = True
-            if ids is None:         # local path (policy, breaker, or fallback)
-                r = self.engine.search_request(
-                    Qcat, p, **({"_filter_dev": filt_dev}
-                                if filt_dev is not None else {}))
-                ids, vals, escalated = r.ids, r.scores, r.escalated
+            replica = pending is not None
+            if not replica:     # local path (policy, breaker, or fallback)
+                pending = self.engine.search_request(
+                    Qcat, p, _filter_dev=filt_dev, wait=False)
+        flight = _Flight(group, pending, Qcat, filt_dev, t0, degraded,
+                         replica, ahead=bool(self._inflight))
+        with self._cond:
+            self._inflight.append(flight)
+        self._launched.put(flight)
+
+    def _complete(self, flight: "_Flight") -> None:
+        """Collect a launched group and resolve its Futures, under the
+        `frontend.complete` host span, which encloses the engine's
+        `engine.download` (or `replica.gather`) and `frontend.resolve`. A
+        replica failure that surfaces here trips the breaker and serves the
+        group on the local path."""
+        group = flight.group
+        with TraceAnnotation("frontend.complete", requests=len(group)):
+            p = group[0].params
+            degraded = flight.degraded
+            try:
+                r = flight.pending.result()
+            except Exception:
+                if not flight.replica:
+                    raise
+                self.health.failure("replica")
+                degraded = True
+                r = self.engine.search_request(flight.Q, p,
+                                               _filter_dev=flight.filt_dev)
+            else:
+                if flight.replica:
+                    self.health.success("replica")
+                    self.stats["replica_dispatches"] += 1
+            ids, vals, escalated = r.ids, r.scores, r.escalated
             if degraded:
                 self.stats["degraded"] += len(group)
-            engine_us = (time.perf_counter() - t0) * 1e6
+            engine_us = (time.perf_counter() - flight.t0) * 1e6
             t_done = time.perf_counter()
             epoch = getattr(self.engine.index, "_alive_epoch", -1)
             self.stats["dispatches"] += 1
             self.stats["requests"] += len(group)
             self.stats["coalesced"] += len(group) - 1
+            self.stats["launched_ahead"] += flight.ahead
             with TraceAnnotation("frontend.resolve"):
                 total = int(ids.shape[0])
                 off = 0
@@ -769,10 +885,12 @@ class ServingFrontend:
         # compose-and-upload); tenant filters are already device-resident
         return not p.has_inline_filter
 
-    def _replica_search(self, Q: np.ndarray, p: SearchParams, filt_dev):
-        """Shard a coalesced batch row-wise over all devices. Mirrors the
-        engine path's filter/escalation plan exactly (serving_filter) so
-        results stay bitwise identical to local execution."""
+    def _replica_launch(self, Q: np.ndarray, p: SearchParams,
+                        filt_dev) -> PendingSearch:
+        """Launch a coalesced batch sharded row-wise over all devices; its
+        `replica.gather` is at collection. Mirrors the engine path's
+        filter/escalation plan exactly (serving_filter) so results stay
+        bitwise identical to local execution."""
         from repro.core.router import clamp_top_t
         from repro.core.search import pad_queries
         faults.serve_point("replica:dispatch")
@@ -807,10 +925,9 @@ class ServingFrontend:
             Qp, nq, _ = pad_queries(Q, self.engine.bq, multiple=R)
             Qd = jax.device_put(Qp, self._rows)
         with TraceAnnotation("replica.launch"):
-            ids, vals = fn(args[0], Qd, *args[1:])
-        with TraceAnnotation("replica.gather"):
-            ids, vals = np.asarray(ids)[:nq], np.asarray(vals)[:nq]
-        return ids, vals, bool(escalate and filt is not None)
+            return PendingSearch(fn(args[0], Qd, *args[1:]), nq,
+                                 span="replica.gather",
+                                 escalated=bool(escalate and filt is not None))
 
     def _placed(self, cache: EpochLRU, src):
         """`src` (the packed index, a filter bitmap) replicated on the

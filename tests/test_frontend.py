@@ -21,7 +21,12 @@ Pins, per the acceptance criteria:
    registry alongside the engine snapshot.
 7. Replica fan-out (subprocess, 8 virtual devices): policy="replica"
    shards coalesced batches across devices with bitwise-local results.
+8. Two batches in flight: the dispatcher launches a ripe group while the
+   last is still on the device, never holds more than two, answers
+   bitwise as solo, and lets a mutation or `flush()`/`close()` through
+   only once every launched batch has been resolved.
 """
+import os
 import subprocess
 import sys
 import threading
@@ -285,6 +290,133 @@ def test_close_rejects_new_work(ds, engine):
     fe.close()                                    # idempotent
     with pytest.raises(RuntimeError, match="closed"):
         fe.submit(ds.Q[:1], SearchParams(k=3))
+
+
+# --------------------------------------------------- two batches in flight
+def _until(cond, seconds=30.0):
+    t0 = time.perf_counter()
+    while not cond():
+        assert time.perf_counter() - t0 < seconds, "timed out"
+        time.sleep(0.001)
+
+
+def test_launch_ahead_keeps_at_most_two_in_flight(ds, engine, held):
+    """Three groups ripe together (three coalescing keys): the second
+    launches while the first is still on the device, the third waits for
+    a slot and launches once the first is collected, still ahead of the
+    second. Every answer is bitwise the solo one."""
+    solo = [engine.search(ds.Q[2 * i:2 * i + 2], k=4 + i) for i in range(3)]
+    with ServingFrontend(engine, policy="local", max_delay_ms=1.0) as fe:
+        futs = [fe.submit(ds.Q[2 * i:2 * i + 2], SearchParams(k=4 + i))
+                for i in range(3)]
+        _until(lambda: len(fe._inflight) == 2)
+        time.sleep(0.05)
+        assert len(fe._inflight) == 2 and len(fe._q) == 1   # no third slot
+        held.release()                       # the first batch finishes
+        _until(lambda: futs[0].done() and not fe._q
+               and len(fe._inflight) == 2)
+        assert not futs[1].done()
+        held.release(2)
+        res = [f.result(timeout=30) for f in futs]
+        stats = dict(fe.stats)
+    assert stats["dispatches"] == 3 and stats["launched_ahead"] == 2
+    for r, (ids, sc) in zip(res, solo):
+        assert np.array_equal(r.ids, ids) and np.array_equal(r.scores, sc)
+
+
+@pytest.mark.parametrize("n_pre", [1, 2])
+def test_mutation_waits_for_the_batches_in_flight(ds, engine, held, n_pre):
+    """A mutation queued behind launched batches (one, with a slot free,
+    or two) runs only after they are resolved at the old epoch; a search
+    queued after it serves the new epoch."""
+    from concurrent.futures import Future
+    from repro.serve.frontend import _Request
+    with ServingFrontend(engine, policy="local", max_delay_ms=1.0) as fe:
+        e0 = engine.index._alive_epoch
+        pre = [fe.submit(ds.Q[i:i + 1],
+                         SearchParams(k=4 + i, deadline_ms=10_000.0))
+               for i in range(n_pre)]
+        _until(lambda: len(fe._inflight) == n_pre)
+        mfut: Future = Future()
+        fe._enqueue(_Request("remove", mfut,
+                             payload=(np.arange(N), False)))
+        post = fe.submit(ds.Q[:1], SearchParams(k=4, deadline_ms=10_000.0))
+        time.sleep(0.05)
+        assert not mfut.done() and not post.done() and len(fe._q) == 2
+        held.release(n_pre + 1)
+        pre_r = [f.result(timeout=30) for f in pre]
+        assert mfut.result(timeout=30) == N
+        post_r = post.result(timeout=30)
+    for r in pre_r:
+        assert r.epoch == e0 and (r.ids >= 0).any()
+    assert post_r.epoch > e0 and (post_r.ids == -1).all()
+
+
+@pytest.mark.parametrize("how", ["flush", "close"])
+def test_flush_and_close_wait_for_the_batches_in_flight(ds, engine, held,
+                                                        how):
+    fe = ServingFrontend(engine, policy="local", max_delay_ms=1.0)
+    try:
+        fut = fe.submit(ds.Q[:1], SearchParams(k=4))
+        _until(lambda: len(fe._inflight) == 1)
+        t = threading.Thread(target=getattr(fe, how))
+        t.start()
+        time.sleep(0.05)
+        assert t.is_alive() and not fut.done()
+        held.release()
+        t.join(timeout=30)
+        assert not t.is_alive()
+        assert fut.done() and fut.result().ids.shape == (1, 4)
+    finally:
+        fe.close()
+
+
+def test_pipeline_under_thread_stress(ds, engine):
+    """More clients than cores, three coalescing keys and a short switch
+    interval: every request resolves exactly once, bitwise as solo, the
+    counters add up, and nothing is left in flight after close()."""
+    keys = (4, 5, 6)
+    solo = {(i, k): engine.search(ds.Q[i:i + 1], k=k)
+            for i in range(NQ) for k in keys}
+    for k in keys:                     # every bucket the clients can hit
+        for nq in (2, 4):
+            engine.search(ds.Q[:nq], k=k)
+    n_clients, per_client = os.cpu_count() + 2, 8
+    got, errors = [], []
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        fe = ServingFrontend(engine, policy="local", max_delay_ms=1.0,
+                             max_batch=4)
+
+        def client(c):
+            try:
+                for j in range(per_client):
+                    i, k = (c * per_client + j) % NQ, keys[(c + j) % 3]
+                    got.append((i, k, fe.search(ds.Q[i:i + 1],
+                                                SearchParams(k=k))))
+            except Exception as e:     # surfaced below
+                errors.append(repr(e))
+
+        threads = [threading.Thread(target=client, args=(c,))
+                   for c in range(n_clients)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+        fe.close()
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in threads) and not errors, errors
+    assert len(got) == n_clients * per_client
+    st = fe.stats
+    assert st["requests"] == len(got)
+    assert st["requests"] - st["coalesced"] == st["dispatches"]
+    assert st["launched_ahead"] <= st["dispatches"]
+    assert not fe._inflight and not fe._q and fe._cost == 0
+    for i, k, r in got:
+        assert np.array_equal(r.ids, solo[i, k][0])
+        assert np.array_equal(r.scores, solo[i, k][1])
 
 
 # ----------------------------------------------------------- replica policy

@@ -20,7 +20,9 @@ Pins, per the acceptance criteria:
    retry + backoff (SearchResult.retries); mutations never retry.
 7. Stranded-Future regression: a BaseException out of the engine fails
    every pending/in-flight Future, poisons submit with
-   FrontendClosedError, and close() still returns — zero hung Futures.
+   FrontendClosedError, and close() still returns — zero hung Futures,
+   also with two batches in flight. A retryable fault where a launched
+   batch is collected re-queues it at the head, and it resolves.
 8. Shutdown ordering: close() during an in-flight mutation, submits
    racing close(), close(drain=False) failing queued work
    deterministically.
@@ -30,7 +32,8 @@ Pins, per the acceptance criteria:
     all-healthy is bitwise the plain path; a dead shard's ids vanish
     while healthy shards' answers survive; the replica breaker falls
     back to bitwise-identical local serving flagged degraded, then
-    heals through the half-open probe.
+    heals through the half-open probe; a replica batch that fails where
+    it is collected is served on the local path too.
 """
 import subprocess
 import sys
@@ -350,6 +353,60 @@ def test_dispatcher_death_strands_no_futures(ds, engine):
     assert fe._cost == 0
 
 
+def _until(cond, seconds=30.0):
+    t0 = time.perf_counter()
+    while not cond():
+        assert time.perf_counter() - t0 < seconds, "timed out"
+        time.sleep(0.001)
+
+
+def test_retryable_fault_at_collection_requeues_and_resolves(ds, engine,
+                                                             held):
+    """Two batches in flight; the first one's collection fails with a
+    retryable fault. It goes back to the head of the queue, launches again
+    while the second is still in flight, and both answer bitwise."""
+    want = [engine.search_request(ds.Q[:2], SearchParams(k=4 + i))
+            for i in range(2)]
+    faults.install("engine:download@1x1", mode="transient")
+    fe = ServingFrontend(engine, policy="local", max_delay_ms=1.0,
+                         retry_backoff_ms=0.5)
+    try:
+        futs = [fe.submit(ds.Q[:2], SearchParams(k=4 + i)) for i in range(2)]
+        _until(lambda: len(fe._inflight) == 2)
+        held.release(3)
+        res = [f.result(timeout=30) for f in futs]
+        assert [r.retries for r in res] == [1, 0]
+        assert fe.stats["retries"] == 1 and fe.stats["failures"] == 0
+        assert fe.stats["dispatches"] == 2
+        for r, w in zip(res, want):
+            assert np.array_equal(r.ids, w.ids)
+            assert np.array_equal(r.scores, w.scores)
+    finally:
+        fe.close()
+
+
+def test_fatal_fault_with_two_batches_in_flight_strands_no_futures(
+        ds, engine, held):
+    fe = ServingFrontend(engine, policy="local", max_delay_ms=1.0)
+    s1 = fe.submit(ds.Q[:1], SearchParams(k=3))
+    s2 = fe.submit(ds.Q[:1], SearchParams(k=4))
+    _until(lambda: len(fe._inflight) == 2)
+    s3 = fe.submit(ds.Q[:1], SearchParams(k=5))      # queued behind both
+    faults.inject("engine:download@1", mode="raise")  # BaseException
+    held.release(2)
+    with pytest.raises(InjectedCrash):
+        s1.result(timeout=30)                        # collected: the cause
+    for f in (s2, s3):                               # in flight, queued
+        with pytest.raises(FrontendClosedError):
+            f.result(timeout=30)
+    faults.uninstall()
+    with pytest.raises(FrontendClosedError, match="closed"):
+        fe.submit(ds.Q[:1], SearchParams(k=3))
+    fe.close()
+    assert not fe._thread.is_alive() and not fe._waiter.is_alive()
+    assert fe._cost == 0 and not fe._inflight
+
+
 # ---------------------------------------------------- shutdown ordering
 def test_close_during_inflight_mutation(ds, engine):
     fe = ServingFrontend(engine, policy="local", max_delay_ms=1.0)
@@ -507,6 +564,13 @@ assert not r4.degraded
 assert fe.health.state("replica") == "closed"
 assert fe.stats["replica_dispatches"] == 1
 assert np.array_equal(r4.ids, solo_ids)        # replica path stays bitwise
+faults.install("replica:gather@1x1", mode="error")   # fails at collection
+r5 = fe.search(ds.Q, SearchParams(k=6))
+assert r5.degraded, "a failed gather falls back to the local path"
+assert np.array_equal(r5.ids, solo_ids)
+assert np.array_equal(r5.scores, solo_sc)
+assert fe.stats["degraded"] == 4 and fe.stats["replica_dispatches"] == 1
+faults.uninstall()
 fe.close()
 print("OK")
 """

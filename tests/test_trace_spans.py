@@ -2,12 +2,17 @@
 
 1. Host spans: a tiny ServingFrontend runs under the JAX profiler; its
    trace, read back with the benchmark's own reduction
-   (benchmarks/chip/tracefile.py), holds one `frontend.dispatch` span per
-   dispatch the frontend counted, each enclosing the six step spans once,
-   in the order the dispatcher runs them. A replica dispatch on four
-   devices encloses `replica.scatter`, `replica.launch` and
-   `replica.gather` instead of the engine's steps, and `replica.place`
-   where it places a new pack, with the bytes it copied.
+   (benchmarks/chip/tracefile.py), holds one `frontend.dispatch` span
+   (the launch side) and one `frontend.complete` span (the collect side)
+   per dispatch the frontend counted. Each dispatch span encloses its
+   four launch steps once, in order, and each complete span its two
+   collect steps; a batch is collected after its launch, and the two
+   kinds of span never overlap. With two batches in flight the second
+   dispatch span opens before the first batch's complete span. A replica
+   dispatch on four devices encloses `replica.scatter` and
+   `replica.launch` instead of the engine's steps, and `replica.place`
+   where it places a new pack, with the bytes it copied; its complete
+   span encloses `replica.gather`.
 2. Device stages: every stage of the search program carries its
    `jax.named_scope` into the compiled HLO's `op_name` metadata, which is
    what a device trace reports as each operation's `tf_op`.
@@ -18,6 +23,7 @@ import re
 import subprocess
 import sys
 import threading
+import time
 
 import jax
 import jax.numpy as jnp
@@ -52,48 +58,117 @@ def engine(ds):
 
 
 # ------------------------------------------------------------ host spans
-def test_host_spans_nest_in_each_dispatch_in_order(ds, engine, tmp_path):
-    """Concurrent single-query clients (coalesced dispatches) and one
-    request larger than a batch, all inside the traced window; the
-    frontend is closed inside it, so every dispatch span has ended."""
-    fe = ServingFrontend(engine, policy="local", default_deadline_ms=20.0)
-    for nq in (1, 2, 4, 8, 16):            # every bucket compiles before
-        fe.search(ds.Q[:nq])               # the trace
+COMPLETE = "frontend.complete"
+LAUNCH_STEPS = ("frontend.coalesce", "engine.prepare", "engine.upload",
+                "engine.launch")
+COLLECT_STEPS = ("engine.download", "frontend.resolve")
+
+
+def _enclosed(tr, outer: str, names) -> list:
+    """Each `outer` span of the trace, in time order, with the spans named
+    in `names` that it encloses, in time order."""
+    def by_start(evs):
+        return sorted(evs, key=lambda e: e.start)
+    return [(o, by_start(e for e in tr.host if e.name in names
+                         and o.start <= e.start and e.end <= o.end))
+            for o in by_start(e for e in tr.host if e.name == outer)]
+
+
+def _check_launch_and_collect(tr, n, launch_steps, collect_steps):
+    """n dispatch and n complete spans; each holds its steps once, in
+    order; the i-th batch is collected after its launch; no two of these
+    spans overlap (one dispatcher thread opens them all)."""
+    launches = _enclosed(tr, spans.DISPATCH, launch_steps + collect_steps)
+    collects = _enclosed(tr, COMPLETE, launch_steps + collect_steps)
+    assert len(launches) == len(collects) == n
+    for outer, inner in launches + collects:
+        assert all(a.end <= b.start for a, b in zip(inner, inner[1:]))
+    assert all(tuple(e.name for e in inner) == launch_steps
+               for _, inner in launches)
+    assert all(tuple(e.name for e in inner) == collect_steps
+               for _, inner in collects)
+    for (d, _), (c, _) in zip(launches, collects):
+        assert d.end <= c.start
+    both = sorted([o for o, _ in launches + collects], key=lambda e: e.start)
+    assert all(a.end <= b.start for a, b in zip(both, both[1:]))
+    return [d for d, _ in launches], [c for c, _ in collects]
+
+
+def _traced(tmp_path, body):
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
     jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
     try:
         with jax.profiler.TraceAnnotation(tracefile.WINDOW):
-            stats0 = dict(fe.stats)
-            threads = [threading.Thread(
-                target=fe.search, args=(ds.Q[i:i + 1], SearchParams(k=5)))
-                for i in range(NQ)]
-            for t in threads:
-                t.start()
-            fe.search(ds.Q)
-            for t in threads:
-                t.join(timeout=60)
-            assert not any(t.is_alive() for t in threads)
-            fe.close()
-            stats1 = dict(fe.stats)
+            body()
+    finally:
+        jax.profiler.stop_trace()
+    return tracefile.load(str(tmp_path))
+
+
+def test_host_spans_nest_in_each_dispatch_in_order(ds, engine, tmp_path):
+    """Concurrent single-query clients (coalesced dispatches) and one
+    request larger than a batch, all inside the traced window; the
+    frontend is closed inside it, so every span has ended."""
+    fe = ServingFrontend(engine, policy="local", default_deadline_ms=20.0)
+    for nq in (1, 2, 4, 8, 16):            # every bucket compiles before
+        fe.search(ds.Q[:nq])               # the trace
+    stats = []
+
+    def body():
+        stats.append(dict(fe.stats))
+        threads = [threading.Thread(
+            target=fe.search, args=(ds.Q[i:i + 1], SearchParams(k=5)))
+            for i in range(NQ)]
+        for t in threads:
+            t.start()
+        fe.search(ds.Q)
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+        fe.close()
+        stats.append(dict(fe.stats))
+    try:
+        tr = _traced(tmp_path, body)
     finally:
         fe.close()
-        jax.profiler.stop_trace()
-    tr = tracefile.load(str(tmp_path))
 
-    n = stats1["dispatches"] - stats0["dispatches"]
+    n = stats[1]["dispatches"] - stats[0]["dispatches"]
     assert n >= 2
-    assert sum(e.name == spans.DISPATCH for e in tr.host) == n
-    got = spans.dispatches(tr)
+    _check_launch_and_collect(tr, n, LAUNCH_STEPS, COLLECT_STEPS)
+    got = spans.dispatches(tr)             # the benchmark's reduction
     assert len(got) == n
     for d in got:
-        assert set(d.steps) == set(spans.STEPS)
-        assert all(len(v) == 1 for v in d.steps.values())
-        seq = sorted((v[0] for v in d.steps.values()), key=lambda e: e.start)
-        assert tuple(e.name for e in seq) == spans.STEPS
-        assert d.span.start <= seq[0].start
-        assert all(a.end <= b.start for a, b in zip(seq, seq[1:]))
-        assert seq[-1].end <= d.span.end
+        assert set(d.steps) == set(LAUNCH_STEPS)
+
+
+def test_a_second_dispatch_opens_before_the_first_completes(
+        ds, engine, held, tmp_path):
+    """Two groups of different keys, the first held on the device until
+    the second is launched: the trace shows the launch ahead."""
+    fe = ServingFrontend(engine, policy="local", max_delay_ms=1.0)
+    held.release(2)                        # the two warm-up searches
+    for k in (5, 6):
+        fe.search(ds.Q[:2], SearchParams(k=k))
+
+    def body():
+        futs = [fe.submit(ds.Q[:2], SearchParams(k=k)) for k in (5, 6)]
+        t0 = time.perf_counter()
+        while len(fe._inflight) < 2:
+            assert time.perf_counter() - t0 < 30
+            time.sleep(0.001)
+        held.release(2)
+        for f in futs:
+            f.result(timeout=30)
+        fe.close()
+    try:
+        tr = _traced(tmp_path, body)
+    finally:
+        fe.close()
+    assert fe.stats["launched_ahead"] == 1
+    (d1, d2), (c1, c2) = _check_launch_and_collect(
+        tr, 2, LAUNCH_STEPS, COLLECT_STEPS)
+    assert d2.start < c1.start
 
 
 SCRIPT_REPLICA_TRACE = r"""
@@ -139,16 +214,16 @@ def test_replica_spans_nest_in_each_dispatch(tmp_path):
     tr = tracefile.load(str(tmp_path))
     got = spans.dispatches(tr)
     assert len(got) == 2
-    steps = ("replica.place", "replica.scatter", "replica.launch",
-             "replica.gather")
-    for d, placed in zip(got, (True, False)):
+    for d in got:
         assert not d.steps.keys() & {"engine.prepare", "engine.upload",
                                      "engine.launch", "engine.download"}
-        inner = sorted((e for e in tr.host if e.name in steps
-                        and d.span.start <= e.start
-                        and e.end <= d.span.end), key=lambda e: e.start)
-        names = tuple(e.name for e in inner)
-        assert names == steps[0 if placed else 1:], names
+    steps = ("replica.place", "replica.scatter", "replica.launch")
+    collect = ("replica.gather", "frontend.resolve")
+    _check_launch_and_collect(tr, 2, steps[1:], collect)
+    launches = _enclosed(tr, spans.DISPATCH, steps)
+    assert [tuple(e.name for e in inner) for _, inner in launches] \
+        == [steps, steps[1:]]
+    for _, inner in launches:
         assert all(a.end <= b.start for a, b in zip(inner, inner[1:]))
     (path,) = [os.path.join(dp, f) for dp, _, fs in os.walk(tmp_path)
                for f in fs if f.endswith(".xplane.pb")]
