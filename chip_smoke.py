@@ -397,10 +397,9 @@ def four_chips(size: Size, seed: int, n_dev: int = 4) -> dict:
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding
-    from repro.core.distributed import (build_sharded_ivf_pq,
-                                        make_distributed_search_pq,
-                                        sharded_ivf_pq_pspecs)
-    from repro.launch.mesh import make_mesh, to_shardings
+    from repro.core.distributed import (build_sharded_ivf,
+                                        make_distributed_search, shard_spec)
+    from repro.launch.mesh import make_mesh
     from repro.serve.api import SearchParams
     from repro.serve.frontend import ServingFrontend
     from jax.sharding import PartitionSpec as P
@@ -445,17 +444,17 @@ def four_chips(size: Size, seed: int, n_dev: int = 4) -> dict:
 
     # --- 4-shard PQ search, one shard per chip
     t0 = time.perf_counter()
-    shards = build_sharded_ivf_pq(jax.random.PRNGKey(seed), X, n_dev,
-                                  size.c // n_dev, size.m)
+    shards = build_sharded_ivf(jax.random.PRNGKey(seed), X, n_dev,
+                               size.c // n_dev, pq_subspaces=size.m)
     log(f"shard_build_seconds {time.perf_counter() - t0:.3f} "
         f"(shards={n_dev} c_per_shard={size.c // n_dev} "
-        f"pmax={shards.part_ids.shape[2]})")
+        f"pmax={shards.packed.part_ids.shape[2]})")
     mesh = make_mesh((n_dev,), ("data",), devices=devs[:n_dev])
-    spec = sharded_ivf_pq_pspecs(("data",))
-    shards = jax.device_put(shards, to_shardings(mesh, spec))
-    search = jax.jit(make_distributed_search_pq(
-        mesh, ("data",), top_t=size.top_t, final_k=K, rerank_k=256,
-        q_chunk=min(128, Q.shape[0])))
+    shards = jax.device_put(shards,
+                            NamedSharding(mesh, shard_spec(("data",))))
+    search = jax.jit(make_distributed_search(
+        mesh, ("data",), top_t=size.top_t, final_k=K, rerank_budget=256,
+        bq=min(128, Q.shape[0])))
     Qd = jax.device_put(jnp.asarray(Q), NamedSharding(mesh, P()))
     t0 = time.perf_counter()
     ids, _ = search(shards, Qd)

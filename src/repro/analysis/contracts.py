@@ -346,35 +346,29 @@ def _spec_pq_encode():
 
 # -------------------------------------------------------- distributed makers
 
-@jaxpr_contract("distributed_search", no_dims={"n"})
-def _spec_distributed_search():
+def _distributed_search_spec(pq_subspaces: int):
     import jax
     import jax.numpy as jnp
     from repro.core.distributed import build_sharded_ivf, \
         make_distributed_search
     X, Q = _tiny_dataset()
     sivf = build_sharded_ivf(jax.random.PRNGKey(2), X, 1, C_TRACE,
-                             train_iters=3)
+                             train_iters=3, pq_subspaces=pq_subspaces)
     fn = make_distributed_search(_tiny_mesh(), ("a",), top_t=TOP_T,
-                                 final_k=FINAL_K, multiplicity=2)
+                                 final_k=FINAL_K, rerank_budget=32,
+                                 bq=NQ_TRACE, multiplicity=2)
     return TraceSpec(fn=fn, args=(sivf, jnp.asarray(Q)),
                      dims={"n": N_TRACE})
+
+
+@jaxpr_contract("distributed_search", no_dims={"n"})
+def _spec_distributed_search():
+    return _distributed_search_spec(0)
 
 
 @jaxpr_contract("distributed_search_pq", no_dims={"n"})
 def _spec_distributed_search_pq():
-    import jax
-    import jax.numpy as jnp
-    from repro.core.distributed import build_sharded_ivf_pq, \
-        make_distributed_search_pq
-    X, Q = _tiny_dataset()
-    sivf = build_sharded_ivf_pq(jax.random.PRNGKey(2), X, 1, C_TRACE, 8,
-                                train_iters=3)
-    fn = make_distributed_search_pq(_tiny_mesh(), ("a",), top_t=TOP_T,
-                                    final_k=FINAL_K, rerank_k=32,
-                                    q_chunk=NQ_TRACE, multiplicity=2)
-    return TraceSpec(fn=fn, args=(sivf, jnp.asarray(Q)),
-                     dims={"n": N_TRACE})
+    return _distributed_search_spec(8)
 
 
 @jaxpr_contract("replicated_search", no_dims={"n"})

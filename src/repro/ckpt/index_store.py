@@ -542,7 +542,7 @@ def save_shards(path: str, indexes, *, extra: Optional[dict] = None):
 
 def load_shards(path: str):
     """Load a shard envelope → (list of per-shard indexes, extra). Feed
-    the list to distributed.sharded_from_indexes(_pq) to re-stack the
+    the list to distributed.sharded_from_indexes to re-stack the
     serving envelope."""
     path = resolve_snapshot_dir(path)
     manifest = read_manifest(path)

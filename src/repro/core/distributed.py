@@ -8,8 +8,9 @@ scale: 1B+ vectors don't fit one host). Design (DESIGN.md §3.5):
   (optionally spilled) IVF over them — building is embarrassingly parallel
   and shard-local, exactly how ScaNN serving shards;
 - a query batch is replicated to all shards (its bytes are tiny vs the DB);
-- each shard runs the fixed-budget jit search (search_jit semantics) over
-  its local partitions and emits its local top-k with GLOBAL ids;
+- each shard runs the single-host search pass (`_search_block`, in the
+  bq-query tiles of search_jit_batched) over its own PackedIVF and emits
+  its local top-k with GLOBAL ids;
 - one `all_gather` of (D × nq × k) ids/scores + a replicated top-k merge.
   The collective moves O(nq·k·D) bytes — independent of database size, so
   SOAR's bandwidth frugality survives cluster scale.
@@ -29,33 +30,22 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core.build import build_ivf_sharded, spill_plan
 from repro.core.router import FlatRouter, TreeRouter
-from repro.core.search import (_pad_topk, _search_block, dedup_topk_window,
-                               pack_ivf, rerank_scores, rerank_table_shape,
-                               window_pq_scores)
+from repro.core.search import (PackedIVF, _search_block, _search_tiles,
+                               pack_ivf, rerank_table_shape)
 from repro.kernels.soar_assign import assign_fused
+from repro.quant.pq import PQCodebook
 
 
 class ShardedIVF(NamedTuple):
-    """Per-shard IVF arrays, stacked over a leading shard dim D."""
-    centroids: jax.Array     # (D, c, d)
-    part_ids: jax.Array      # (D, c, pmax) int32 GLOBAL point ids, -1 pad
-    sizes: jax.Array         # (D, c) int32
-    rerank: jax.Array        # (D, ...) rerank table per shard, as PackedIVF
+    """One `PackedIVF` per shard, stacked: every leaf of `packed` carries
+    a leading shard dim D (centroids (D, c, d), part_ids (D, c, pmax)
+    shard-LOCAL ids with -1 padding, the rerank table (D, R, 128), and,
+    on a PQ-scored index, part_codes (D, c, pmax, m) and pq.centers
+    (D, m, 16, s)). An exact-score index has part_codes and pq None, as a
+    single-host PackedIVF does; part_codes2 and router are always None
+    (each shard's router travels as its own argument)."""
+    packed: PackedIVF
     local_base: jax.Array    # (D,) int32 global id offset of each shard
-
-
-class ShardedIVFPQ(NamedTuple):
-    """PQ-scored variant (§Perf H3 — the paper's actual pipeline): per
-    ASSIGNMENT codes in partition order; candidates are scored from uint8
-    codes (d/(2s)·2 bytes each at uint8 layout) instead of gathering the
-    float32 vectors (4d bytes) — 16× less candidate traffic at m=d/4."""
-    centroids: jax.Array     # (D, c, d)
-    part_ids: jax.Array      # (D, c, pmax) int32 GLOBAL ids, -1 pad
-    part_codes: jax.Array    # (D, c, pmax, m) uint8 PQ codes per assignment
-    pq_centers: jax.Array    # (D, m, 16, s) per-shard PQ codebook
-    sizes: jax.Array         # (D, c) int32
-    rerank: jax.Array        # (D, ...) rerank table per shard, as PackedIVF
-    local_base: jax.Array    # (D,) int32
 
 
 class ShardedTreeRouter(NamedTuple):
@@ -72,8 +62,8 @@ class ShardedTreeRouter(NamedTuple):
 
 def stack_tree_routers(routers) -> ShardedTreeRouter:
     """Stack per-shard TreeRouters (e.g. `idx.router` of each shard built
-    with router="tree") into the sharded envelope for the
-    `with_router=True` distributed search paths."""
+    with router="tree") into the sharded envelope that
+    make_distributed_search's `router` argument takes."""
     S = max(r.n_super for r in routers)
     cmax = max(r.cmax for r in routers)
     d = routers[0].d
@@ -89,9 +79,12 @@ def stack_tree_routers(routers) -> ShardedTreeRouter:
                              jnp.asarray(CC))
 
 
-def tree_router_pspecs(axes: Tuple[str, ...]) -> ShardedTreeRouter:
-    a = axes if len(axes) > 1 else axes[0]
-    return ShardedTreeRouter(P(a), P(a), P(a))
+def shard_spec(axes: Tuple[str, ...]) -> P:
+    """The PartitionSpec of every sharded argument (index, filter, router
+    tables, health mask): the leading shard dim split over `axes`. A spec
+    is a pytree prefix, so this one spec lays out every leaf of a
+    ShardedIVF or ShardedTreeRouter."""
+    return P(axes if len(axes) > 1 else axes[0])
 
 
 def _resolve_shard(idx):
@@ -100,52 +93,53 @@ def _resolve_shard(idx):
     return idx.to_ivf_index() if isinstance(idx, MutableIVF) else idx
 
 
-def _stack_shards(indexes):
-    """Shared stacker for the ShardedIVF(PQ) builders/refreshers: resolve
-    mutable shards, pack, pad ids to the common pmax envelope (-1
-    sentinel) and each rerank table to the longest one (zero rows — the
-    ids they would hold never appear in any partition slot, so they are
-    unreachable), and accumulate cumulative global-id base offsets.
-    Returns (packed, resolved, ids, cents, sizes, reranks, bases)."""
-    resolved = list(map(_resolve_shard, indexes))
-    packed = [pack_ivf(idx, pair_codes=False) for idx in resolved]
-    n_locals = [idx.n_points for idx in resolved]
-    pmax = max(pk.part_ids.shape[1] for pk in packed)
-    rmax = max(pk.rerank.shape[0] for pk in packed)
-    cents, ids, sizes, reranks, bases = [], [], [], [], []
-    base = 0
-    for pk, nl in zip(packed, n_locals):
-        pad = pmax - pk.part_ids.shape[1]
-        ids.append(np.pad(np.asarray(pk.part_ids), ((0, 0), (0, pad)),
-                          constant_values=-1))
-        cents.append(np.asarray(pk.centroids))
-        sizes.append(np.asarray(pk.sizes))
-        reranks.append(np.pad(np.asarray(pk.rerank),
-                              ((0, rmax - pk.rerank.shape[0]), (0, 0))))
-        bases.append(base)
-        base += nl
-    return packed, resolved, ids, cents, sizes, reranks, bases
+def _padded(a, axis: int, width: int, fill=0) -> np.ndarray:
+    a = np.asarray(a)
+    pads = [(0, 0)] * a.ndim
+    pads[axis] = (0, width - a.shape[axis])
+    return np.pad(a, pads, constant_values=fill)
 
 
 def sharded_from_indexes(indexes) -> ShardedIVF:
     """Stack per-shard indexes (IVFIndex or MutableIVF) into a ShardedIVF.
 
-    The refresh path after online mutation: each shard's live snapshot is
-    packed and padded to the common (pmax, n_local) envelope; local ids
-    keep their shard-stable values and globalize via the cumulative base
-    offsets.
+    Each shard's live snapshot is packed and padded to the common
+    envelope: partitions to the longest one (-1 ids, zero codes), the
+    rerank table to the longest one (zero rows — the ids they would hold
+    never appear in any partition slot, so they are unreachable). The PQ
+    codes and codebooks are stacked iff the shards have them. Local ids
+    keep their shard-stable values and globalize through the cumulative
+    base offsets, so this is also the refresh path after online mutation.
     """
-    _, _, ids, cents, sizes, reranks, bases = _stack_shards(indexes)
-    return ShardedIVF(
-        jnp.asarray(np.stack(cents)), jnp.asarray(np.stack(ids)),
-        jnp.asarray(np.stack(sizes)), jnp.asarray(np.stack(reranks)),
-        jnp.asarray(np.array(bases, np.int32)))
+    shards = list(map(_resolve_shard, indexes))
+    packed = [pack_ivf(idx, pair_codes=False) for idx in shards]
+    pmax = max(pk.part_ids.shape[1] for pk in packed)
+    rmax = max(pk.rerank.shape[0] for pk in packed)
+    has_pq = packed[0].part_codes is not None
+
+    def stack(arrays):
+        return jnp.asarray(np.stack([np.asarray(a) for a in arrays]))
+
+    bases = np.cumsum([0] + [idx.n_points for idx in shards[:-1]])
+    return ShardedIVF(PackedIVF(
+        centroids=stack(pk.centroids for pk in packed),
+        part_ids=stack(_padded(pk.part_ids, 1, pmax, -1) for pk in packed),
+        part_codes=(stack(_padded(pk.part_codes, 1, pmax) for pk in packed)
+                    if has_pq else None),
+        part_codes2=None,
+        sizes=stack(pk.sizes for pk in packed),
+        pq=(PQCodebook(stack(idx.pq.centers for idx in shards))
+            if has_pq else None),
+        rerank=stack(_padded(pk.rerank, 0, rmax) for pk in packed)),
+        jnp.asarray(bases.astype(np.int32)))
 
 
 def build_sharded_ivf(key, X: np.ndarray, n_shards: int, n_partitions: int,
                       spill_mode: str = "soar", lam: float = 1.0,
-                      train_iters: int = 8) -> ShardedIVF:
-    """Host-side build: split X row-wise, build one spilled IVF per shard.
+                      train_iters: int = 8,
+                      pq_subspaces: int = 0) -> ShardedIVF:
+    """Host-side build: split X row-wise, build one spilled IVF per shard
+    (PQ-scored with `pq_subspaces` > 0, else exact-scored).
 
     Each per-shard build runs the streamed driver (core/build.py), so peak
     accelerator memory is O(shard tile) rather than O(n/D).
@@ -157,7 +151,7 @@ def build_sharded_ivf(key, X: np.ndarray, n_shards: int, n_partitions: int,
         build_ivf_sharded(jax.random.fold_in(key, s),
                           X[s * nl:(s + 1) * nl], n_partitions,
                           spill_mode=spill_mode, lam=lam,
-                          train_iters=train_iters)
+                          pq_subspaces=pq_subspaces, train_iters=train_iters)
         for s in range(n_shards)
     ]
     return sharded_from_indexes(indexes)
@@ -189,38 +183,20 @@ def make_sharded_assign(mesh, axes: Tuple[str, ...], *,
 
 
 def abstract_sharded_ivf(n_shards: int, n_local: int, n_partitions: int,
-                         pmax: int, d: int) -> ShardedIVF:
-    """ShapeDtypeStruct stand-in for the production-scale dry run."""
-    f = jax.ShapeDtypeStruct
-    return ShardedIVF(
-        f((n_shards, n_partitions, d), jnp.float32),
-        f((n_shards, n_partitions, pmax), jnp.int32),
-        f((n_shards, n_partitions), jnp.int32),
-        f((n_shards,) + rerank_table_shape(n_local, d), jnp.float32),
-        f((n_shards,), jnp.int32))
-
-
-def abstract_sharded_ivf_pq(n_shards: int, n_local: int, n_partitions: int,
-                            pmax: int, d: int, m: int) -> ShardedIVFPQ:
-    f = jax.ShapeDtypeStruct
-    return ShardedIVFPQ(
-        f((n_shards, n_partitions, d), jnp.float32),
-        f((n_shards, n_partitions, pmax), jnp.int32),
-        f((n_shards, n_partitions, pmax, m), jnp.uint8),
-        f((n_shards, m, 16, d // m), jnp.float32),
-        f((n_shards, n_partitions), jnp.int32),
-        f((n_shards,) + rerank_table_shape(n_local, d), jnp.float32),
-        f((n_shards,), jnp.int32))
-
-
-def sharded_ivf_pspecs(axes: Tuple[str, ...]) -> ShardedIVF:
-    a = axes if len(axes) > 1 else axes[0]
-    return ShardedIVF(P(a), P(a), P(a), P(a), P(a))
-
-
-def sharded_ivf_pq_pspecs(axes: Tuple[str, ...]) -> ShardedIVFPQ:
-    a = axes if len(axes) > 1 else axes[0]
-    return ShardedIVFPQ(P(a), P(a), P(a), P(a), P(a), P(a), P(a))
+                         pmax: int, d: int, m: int = 0) -> ShardedIVF:
+    """ShapeDtypeStruct stand-in for the production-scale dry run; m > 0
+    makes it PQ-scored with m subspaces."""
+    def f(shape, dtype):
+        return jax.ShapeDtypeStruct((n_shards,) + shape, dtype)
+    return ShardedIVF(PackedIVF(
+        f((n_partitions, d), jnp.float32),
+        f((n_partitions, pmax), jnp.int32),
+        f((n_partitions, pmax, m), jnp.uint8) if m else None,
+        None,
+        f((n_partitions,), jnp.int32),
+        PQCodebook(f((m, 16, d // m), jnp.float32)) if m else None,
+        f(rerank_table_shape(n_local, d), jnp.float32)),
+        f((), jnp.int32))
 
 
 def stack_filters(masks, n_local_max: Optional[int] = None) -> jax.Array:
@@ -270,31 +246,6 @@ def _local_router(C, srt, t_route):
                       t_route=(max(1, -(-S // 8)) if t_route is None
                                else t_route),
                       n_partitions=C.shape[0])
-
-
-def _shard_map_variants(local_search, mesh, spec, axes, with_filter,
-                        with_router, with_health=False):
-    """shard_map wiring shared by both distributed search makers: the
-    optional filter bitmap, router-table, and health-mask args extend
-    in_specs in a fixed order (ivf, Q[, filt][, router][, health])."""
-    a = axes if len(axes) > 1 else axes[0]
-    specs = [spec, P()]
-    if with_filter:
-        specs.append(P(a))
-    if with_router:
-        specs.append(tree_router_pspecs(axes))
-    if with_health:
-        specs.append(P(a))
-
-    def fn(ivf, Q, *rest):
-        it = iter(rest)
-        filt = next(it) if with_filter else None
-        srt = next(it) if with_router else None
-        health = next(it) if with_health else None
-        return local_search(ivf, Q, filt, srt, health)
-
-    return jax.shard_map(fn, mesh=mesh, in_specs=tuple(specs),
-                         out_specs=(P(), P()), check_vma=False)
 
 
 def _mask_unhealthy(ids, vals, health):
@@ -362,7 +313,7 @@ def make_replicated_search(mesh, axes: Tuple[str, ...], *, top_t: int,
     the front-end's TenantFilterBank.
 
     Degraded mode (§3.13) is intentionally NOT a mask here, unlike the
-    shard-parallel makers: replicas hold disjoint QUERY slices of one
+    shard-parallel maker: replicas hold disjoint QUERY slices of one
     batch, so masking a dead replica would lose its queries' answers
     rather than narrow their coverage. The degraded path for replica
     fan-out lives at the front-end: a failed replica dispatch trips the
@@ -409,180 +360,67 @@ def replicate_on_mesh(tree, mesh):
     return jax.tree_util.tree_map(one, tree)
 
 
-def _apply_params(params, top_t, final_k):
-    """Resolve a serve/api.SearchParams against a distributed maker's
-    kwargs — the unified request API's seam into this layer."""
-    if params is None:
-        return top_t, final_k, True
-    p = params.validate(default_top_t=top_t)
-    return p.top_t, p.k, p.escalate
-
-
 def make_distributed_search(mesh, axes: Tuple[str, ...], *, top_t: int,
-                            final_k: int = 10, multiplicity: int = 2,
-                            with_filter: bool = False,
-                            with_router: bool = False,
-                            t_route: Optional[int] = None,
-                            with_health: bool = False,
-                            params=None):
-    """Returns jit-able fn(ShardedIVF, Q (nq, d)) → (ids, scores) global.
+                            final_k: int = 10, rerank_budget: int = 256,
+                            bq: int = 128, multiplicity: int = 2,
+                            t_route: Optional[int] = None, params=None):
+    """Shard-parallel search: returns a jit-able fn(index: ShardedIVF,
+    Q (nq, d), filter=None, router=None, health=None) → (ids, scores),
+    each (nq, final_k), ids global.
 
-    Pass multiplicity ≥ 1 + n_spills when serving multi-spill shards
-    (dedup_topk_window's correctness bound); default 2 covers the
-    single-spill "naive"/"soar" builds.
+    Each shard runs the single-host pass (`_search_block`, the same named
+    stages) over its own PackedIVF, in bq-query tiles as
+    search_jit_batched does, globalizes its local top-k and masks it by
+    `health`; one all_gather and top-k then merge the shards. The sharded
+    path has no escalation pass: `escalate` is always off. `rerank_budget`
+    is the PQ stage's budget (an exact-score index ignores it); pass
+    multiplicity ≥ 1 + n_spills when serving multi-spill shards
+    (dedup_topk_window's correctness bound).
 
-    with_filter=True: the returned fn takes an extra argument — a (D, n_local)
-    uint8 LOCAL-id bitmap (stack_filters / shard_filters), sharded like the
-    index — and masks candidates per gathered window before dedup, exactly
-    the §3.9 subset semantics of the single-host engines.
+    The optional arguments, each sharded like the index:
 
-    with_router=True: the fn takes a trailing ShardedTreeRouter argument
-    (stack_tree_routers over the shards' build-time routers) and probes
-    through each shard's two-level router at the given `t_route` (default
-    ceil(S/8)) instead of the flat local GEMM — the per-shard O(c)→O(√c)
-    probe reduction, shard-local like everything else.
+    - filter: (D, n_local) uint8 LOCAL-id bitmap (stack_filters /
+      shard_filters), masking candidates per gathered window before
+      dedup — the §3.9 subset semantics of the single-host engines;
+    - router: a ShardedTreeRouter (stack_tree_routers over the shards'
+      build-time routers): each shard probes through its own two-level
+      router at `t_route` (default ceil(S/8)) instead of the flat GEMM
+      over its centroids;
+    - health: (D,) uint8 (HealthTracker.mask): a down shard's candidates
+      become (-1, -inf) padding before the merge, so the top-k comes from
+      the healthy shards (partial results, DESIGN.md §3.13). All ones is
+      bitwise the health=None result.
 
-    with_health=True: the fn takes a FINAL (D,) uint8 health bitmap
-    (HealthTracker.mask, sharded like the index) and serves top-k from
-    the HEALTHY shards only — a down shard's candidates become (-1,
-    -inf) padding before the global merge (partial results, DESIGN.md
-    §3.13). An all-ones mask is bitwise-identical to the
-    with_health=False results.
-
-    params: optional serve/api.SearchParams whose k/top_t override the
-    kwargs (the unified request API, DESIGN.md §3.12).
+    params: optional serve/api.SearchParams whose k/top_t/rerank_budget
+    override the kwargs (the unified request API, DESIGN.md §3.12).
     """
-    top_t, final_k, _ = _apply_params(params, top_t, final_k)
+    if params is not None:
+        p = params.validate(default_top_t=top_t, default_rerank=rerank_budget)
+        top_t, final_k, rerank_budget = p.top_t, p.k, p.rerank_budget
+    spec = shard_spec(axes)
 
-    def local_search(ivf: ShardedIVF, Q, filt=None, srt=None, health=None):
-        # leading shard dim is size 1 inside shard_map — squeeze it
-        C = ivf.centroids[0]
-        part_ids = ivf.part_ids[0]
-        rerank = ivf.rerank[0]
-        base = ivf.local_base[0]
-
-        # batched: one router probe, then candidate-local dedup — no
-        # intermediate scales with the shard size (DESIGN.md §3.6)
-        router = _local_router(C, srt, t_route)
-        _, parts = router.route(Q, router.clamp(top_t))
-        ids = part_ids[parts].reshape(Q.shape[0], -1)      # (nq, t·pmax) local
-        valid = ids >= 0
-        if filt is not None:
-            valid = valid & (filt[0][jnp.maximum(ids, 0)] > 0)
-            ids = jnp.where(valid, ids, -1)    # filtered ≡ padding for dedup
-        scores = rerank_scores(rerank, jnp.maximum(ids, 0), Q)
-        scores = jnp.where(valid, scores, -jnp.inf)
-        ids, vals = dedup_topk_window(ids, scores, final_k, multiplicity)
-        # a tombstone-heavy mutable shard (sharded_from_indexes) can have a
-        # window narrower than final_k — pad to keep the merge shapes fixed
-        ids, vals = _pad_topk(ids, vals, final_k)
+    def local(index: ShardedIVF, Q, filt, srt, health):
+        # the leading shard dim is size 1 inside shard_map — squeeze it
+        packed = jax.tree_util.tree_map(lambda x: x[0], index.packed)
+        ids, vals = _search_tiles(
+            packed, Q, top_t, final_k, rerank_budget, bq, multiplicity,
+            None if filt is None else filt[0], False,
+            _local_router(packed.centroids, srt, t_route))
         # globalize local ids, preserving the -1 padding sentinel (an
         # under-filled window must not alias into the previous shard)
-        ids = jnp.where(ids >= 0, ids + base, -1).astype(jnp.int32)
+        ids = jnp.where(ids >= 0, ids + index.local_base[0],
+                        -1).astype(jnp.int32)
         ids, vals = _mask_unhealthy(ids, vals, health)
         return _global_merge(ids, vals, axes, final_k)
 
-    return _shard_map_variants(local_search, mesh, sharded_ivf_pspecs(axes),
-                               axes, with_filter, with_router, with_health)
+    search = jax.shard_map(local, mesh=mesh,
+                           in_specs=(spec, P(), spec, spec, spec),
+                           out_specs=(P(), P()), check_vma=False)
 
+    def fn(index: ShardedIVF, Q, filter=None, router=None, health=None):
+        return search(index, Q, filter, router, health)
 
-def make_distributed_search_pq(mesh, axes: Tuple[str, ...], *, top_t: int,
-                               final_k: int = 10, rerank_k: int = 256,
-                               q_chunk: int = 128, multiplicity: int = 2,
-                               with_filter: bool = False,
-                               with_router: bool = False,
-                               t_route: Optional[int] = None,
-                               with_health: bool = False,
-                               params=None):
-    """PQ-scored distributed search (§Perf H3 — the paper's own pipeline).
-
-    Per shard per q_chunk tile: batched centroid top-t → PQ-score the
-    gathered t·pmax candidate windows from their uint8 codes (Pallas one-hot
-    MXU kernel on TPU, + the router's coarse score) → candidate-local
-    dedup-by-max + top rerank_k over the window → exact rerank of only
-    those from the float data → local top-k → global all_gather merge.
-    Tiles stream through lax.map to bound the live candidate buffers
-    (baseline peaked at 16 GiB gathering f32 candidates).
-
-    with_filter as in make_distributed_search: fn gains a (D, n_local)
-    uint8 local-id bitmap argument masking candidates pre-dedup.
-    with_router/t_route as in make_distributed_search: a trailing
-    ShardedTreeRouter argument replaces the flat local probe.
-    with_health as in make_distributed_search: a final (D,) uint8 health
-    bitmap masks down shards out of the merge (§3.13 partial results).
-    params: optional serve/api.SearchParams overriding k/top_t (§3.12).
-    """
-    top_t, final_k, _ = _apply_params(params, top_t, final_k)
-
-    def local_search(ivf: ShardedIVFPQ, Q, filt=None, srt=None,
-                     health=None):
-        C = ivf.centroids[0]
-        part_ids = ivf.part_ids[0]
-        part_codes = ivf.part_codes[0]
-        pqc = ivf.pq_centers[0]                   # (m, 16, s)
-        rerank = ivf.rerank[0]
-        base = ivf.local_base[0]
-        fbits = None if filt is None else filt[0]
-        m = pqc.shape[0]
-        s = pqc.shape[2]
-        pmax = part_ids.shape[1]
-        router = _local_router(C, srt, t_route)
-        tt = router.clamp(top_t)
-
-        def tile(Qb):                                      # (bq, d)
-            psc, parts = router.route(Qb, tt)
-            bq = Qb.shape[0]
-            tw = parts.shape[-1]         # router may return fewer than tt
-            ids = part_ids[parts].reshape(bq, -1)          # (bq, t·pmax)
-            valid = ids >= 0
-            if fbits is not None:
-                valid = valid & (fbits[jnp.maximum(ids, 0)] > 0)
-                ids = jnp.where(valid, ids, -1)
-            codes = part_codes[parts].reshape(bq, tw * pmax, m)
-            luts = jnp.einsum("qms,mks->qmk", Qb.reshape(bq, m, s), pqc)
-            approx = window_pq_scores(luts, codes)
-            approx = approx + jnp.repeat(psc, pmax, axis=-1)
-            approx = jnp.where(valid, approx, -jnp.inf)
-            bi, bv = dedup_topk_window(ids, approx, rerank_k, multiplicity)
-            exact = rerank_scores(rerank, jnp.maximum(bi, 0), Qb)
-            exact = jnp.where(jnp.isfinite(bv), exact, -jnp.inf)
-            v, pos = jax.lax.top_k(exact, min(final_k, exact.shape[-1]))
-            gi, v = _pad_topk(jnp.take_along_axis(bi, pos, axis=-1), v,
-                              final_k)
-            # keep the -1 sentinel out of the global id space: an
-            # under-filled tombstone-heavy shard must not alias elsewhere
-            return jnp.where(gi >= 0, gi + base, -1).astype(jnp.int32), v
-
-        nq = Q.shape[0]
-        Qc = Q.reshape(nq // q_chunk, q_chunk, -1)
-        ids, vals = jax.lax.map(tile, Qc)
-        ids = ids.reshape(nq, final_k)
-        vals = vals.reshape(nq, final_k)
-        ids, vals = _mask_unhealthy(ids, vals, health)
-        return _global_merge(ids, vals, axes, final_k)
-
-    return _shard_map_variants(local_search, mesh,
-                               sharded_ivf_pq_pspecs(axes), axes,
-                               with_filter, with_router, with_health)
-
-
-def sharded_from_indexes_pq(indexes) -> ShardedIVFPQ:
-    """Stack per-shard PQ indexes (IVFIndex or MutableIVF) — the refresh
-    path that re-serves per-shard indexes after online mutation."""
-    packed, resolved, ids, cents, sizes, reranks, bases = (
-        _stack_shards(indexes))
-    pmax = ids[0].shape[1]
-    codes, pqcs = [], []
-    for pk, idx in zip(packed, resolved):
-        pad = pmax - pk.part_ids.shape[1]
-        codes.append(np.pad(np.asarray(pk.part_codes),
-                            ((0, 0), (0, pad), (0, 0))))
-        pqcs.append(np.asarray(idx.pq.centers))
-    return ShardedIVFPQ(
-        jnp.asarray(np.stack(cents)), jnp.asarray(np.stack(ids)),
-        jnp.asarray(np.stack(codes)), jnp.asarray(np.stack(pqcs)),
-        jnp.asarray(np.stack(sizes)), jnp.asarray(np.stack(reranks)),
-        jnp.asarray(np.array(bases, np.int32)))
+    return fn
 
 
 # ------------------------------------------------------------- durability
@@ -592,32 +430,14 @@ def save_sharded(path: str, indexes, *, extra=None):
     mutation state survives) plus an envelope manifest, committed with a
     single atomic directory swap. Keep the PER-SHARD indexes around for
     saving rather than the stacked device arrays: the envelope restores
-    them, and `sharded_from_indexes(_pq)` restacks bitwise."""
+    them, and `sharded_from_indexes` restacks bitwise."""
     from repro.ckpt.index_store import save_shards
     save_shards(path, indexes, extra=extra)
 
 
 def load_sharded(path: str):
     """→ (per-shard index objects, extra). Restack with
-    `sharded_from_indexes` / `sharded_from_indexes_pq`; any torn or
+    `sharded_from_indexes`; any torn or
     bit-flipped shard raises CorruptSnapshotError at load."""
     from repro.ckpt.index_store import load_shards
     return load_shards(path)
-
-
-def build_sharded_ivf_pq(key, X: np.ndarray, n_shards: int, n_partitions: int,
-                         pq_subspaces: int, spill_mode: str = "soar",
-                         lam: float = 1.0, train_iters: int = 8
-                         ) -> ShardedIVFPQ:
-    """Host-side build of the PQ-scored sharded index (streamed per shard)."""
-    n = X.shape[0]
-    assert n % n_shards == 0
-    nl = n // n_shards
-    indexes = [
-        build_ivf_sharded(jax.random.fold_in(key, sh),
-                          X[sh * nl:(sh + 1) * nl], n_partitions,
-                          spill_mode=spill_mode, lam=lam,
-                          pq_subspaces=pq_subspaces, train_iters=train_iters)
-        for sh in range(n_shards)
-    ]
-    return sharded_from_indexes_pq(indexes)

@@ -672,6 +672,16 @@ def search_jit_batched(packed: PackedIVF, Q, top_t: int, final_k: int,
     search_jit (bitmap and router tables are closed over, shared across
     tiles).
     """
+    return _search_tiles(packed, Q, top_t, final_k, rerank_budget, bq,
+                         multiplicity, filter, escalate, router)
+
+
+def _search_tiles(packed: PackedIVF, Q, top_t: int, final_k: int,
+                  rerank_budget: int, bq: int, multiplicity: int, filter,
+                  escalate: bool, router):
+    """`_search_block` over bq-query tiles (lax.map), the last tile
+    zero-padded and sliced off: the body of search_jit_batched and of each
+    shard of core/distributed.make_distributed_search."""
     nq, d = Q.shape
     if nq == 0:          # static at trace time: empty batch, no tiles
         return (jnp.zeros((0, final_k), jnp.int32),

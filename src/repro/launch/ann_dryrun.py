@@ -21,11 +21,7 @@ import jax           # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 from repro.core.distributed import (abstract_sharded_ivf,  # noqa: E402
-                                    abstract_sharded_ivf_pq,
-                                    make_distributed_search,
-                                    make_distributed_search_pq,
-                                    sharded_ivf_pq_pspecs,
-                                    sharded_ivf_pspecs)
+                                    make_distributed_search, shard_spec)
 from repro.launch.dryrun import (HBM_BW, ICI_BW, PEAK_FLOPS,  # noqa: E402
                                  fmt_summary)
 from repro.launch.hlo_analysis import analyze  # noqa: E402
@@ -48,17 +44,11 @@ def run(multi_pod: bool, pq: bool = False) -> dict:
     n_shards = 512 if multi_pod else 256
     q = jax.ShapeDtypeStruct((NQ, D), jnp.float32)
     from jax.sharding import PartitionSpec as P
-    if pq:
-        m = D // 4   # s=4 dims/subspace
-        ivf = abstract_sharded_ivf_pq(n_shards, N_LOCAL, C_LOCAL, PMAX, D, m)
-        search = make_distributed_search_pq(mesh, axes, top_t=TOP_T,
-                                            final_k=FINAL_K)
-        in_sh = (sharded_ivf_pq_pspecs(axes), P())
-    else:
-        ivf = abstract_sharded_ivf(n_shards, N_LOCAL, C_LOCAL, PMAX, D)
-        search = make_distributed_search(mesh, axes, top_t=TOP_T,
-                                         final_k=FINAL_K)
-        in_sh = (sharded_ivf_pspecs(axes), P())
+    m = D // 4 if pq else 0   # s=4 dims/subspace
+    ivf = abstract_sharded_ivf(n_shards, N_LOCAL, C_LOCAL, PMAX, D, m)
+    search = make_distributed_search(mesh, axes, top_t=TOP_T,
+                                     final_k=FINAL_K)
+    in_sh = (shard_spec(axes), P())
     t0 = time.time()
     with jax.set_mesh(mesh):
         lowered = jax.jit(search, in_shardings=to_shardings(mesh, in_sh),

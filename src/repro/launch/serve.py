@@ -1,4 +1,6 @@
-"""Serving launcher: batched prefill + greedy decode, reporting tokens/s.
+"""LM decode serving: `ServeEngine` (batched prefill + greedy decode over
+the model zoo), the `make_serve_step` the decode dry-run cells lower, and
+a launcher reporting tokens/s:
 
     python -m repro.launch.serve --arch granite-3-2b --batch 4 --new 32
 (CPU container → smoke config; on TPU pods the full config + production mesh.)
@@ -9,13 +11,56 @@ import argparse
 import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ARCH_IDS, get_config
 from repro.data.pipeline import for_model
 from repro.models import transformer as T
-from repro.serve.engine import ServeEngine
+from repro.models.config import ModelConfig
 from repro.utils import enable_compile_cache
+
+
+def make_serve_step(cfg: ModelConfig):
+    """fn(params, token (B,1), caches, index) → (next_token (B,1), caches)."""
+
+    def serve_step(params, token, caches, index):
+        logits, caches = T.decode_step(params, token, caches, index, cfg)
+        nxt = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
+        return nxt[:, None], caches
+
+    return serve_step
+
+
+def make_prefill_step(cfg: ModelConfig, max_seq: int):
+    def prefill_step(params, inputs):
+        return T.prefill(params, inputs, cfg, max_seq=max_seq)
+    return prefill_step
+
+
+class ServeEngine:
+    """Minimal batched greedy-decoding engine (CPU-runnable at smoke scale)."""
+
+    def __init__(self, cfg: ModelConfig, params, max_seq: int = 256):
+        self.cfg = cfg
+        self.params = params
+        self.max_seq = max_seq
+        self._prefill = jax.jit(make_prefill_step(cfg, max_seq))
+        self._step = jax.jit(make_serve_step(cfg), donate_argnums=(2,))
+
+    def generate(self, inputs: dict, n_new: int):
+        """inputs: {"tokens": (B, S)} (+ patches for vlm). Greedy decode."""
+        logits, caches = self._prefill(self.params, inputs)
+        tok = jnp.argmax(logits[:, -1, :], axis=-1)[:, None].astype(jnp.int32)
+        prefix = (self.cfg.n_prefix_embeds
+                  if self.cfg.frontend == "vision" else 0)
+        start = inputs["tokens"].shape[1] + prefix
+        out = [tok]
+        for i in range(n_new - 1):
+            tok, caches = self._step(self.params, tok, caches,
+                                     jnp.asarray(start + i, jnp.int32))
+            out.append(tok)
+        return jnp.concatenate(out, axis=1)
 
 
 def main():

@@ -124,7 +124,7 @@ def prefill_cell_specs(cfg: ModelConfig, cell: ShapeCell, rules: dict):
 
 
 def decode_cell_specs(cfg: ModelConfig, cell: ShapeCell, rules: dict):
-    from repro.serve.engine import make_serve_step
+    from repro.launch.serve import make_serve_step
 
     params_abs = _serve_params_abs(cfg)
     pspec = T.param_pspecs(cfg, rules)

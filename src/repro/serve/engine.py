@@ -1,16 +1,13 @@
-"""Serving engines: LM decode (prefill + greedy decode over the model zoo)
-and online ANN serving over a mutable SOAR index.
-
-`serve_step` (single decode step over a full KV cache) is the function the
-decode_32k / long_500k dry-run cells lower; `generate` is the CPU-runnable
-driver used by examples and tests. `AnnEngine` is the vector-search
-counterpart: add/remove/search against a live index (DESIGN.md §3.7).
+"""Online ANN serving over a mutable SOAR index: `AnnEngine`
+add/remove/search against a live index (DESIGN.md §3.7), and the search
+plan that both its own launch and the front-end's replica fan-out run
+(§3.12).
 """
 from __future__ import annotations
 
 import os
 import time
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -18,57 +15,26 @@ import numpy as np
 from jax.profiler import TraceAnnotation
 
 from repro import faults
-from repro.models import transformer as T
-from repro.models.config import ModelConfig
-# validation + defaults live on the unified request API (serve/api.py,
-# DESIGN.md §3.12); re-exported here because this module was their
-# historical home and external callers import them from the engine edge
-from repro.serve.api import (_positive_int, validate_queries,  # noqa: F401
-                             SearchParams, SearchResult,
-                             DEFAULT_TOP_T, DEFAULT_RERANK_BUDGET,
-                             DEFAULT_BQ)
+from repro.serve.api import (DEFAULT_BQ, DEFAULT_RERANK_BUDGET,
+                             DEFAULT_TOP_T, SearchParams, SearchResult,
+                             _positive_int, validate_queries)
 
 
-def make_serve_step(cfg: ModelConfig):
-    """fn(params, token (B,1), caches, index) → (next_token (B,1), caches)."""
+class SearchPlan(NamedTuple):
+    """What one search launch runs (AnnEngine.plan): the packed index,
+    the padded queries, the filter, and the static arguments every launch
+    of the search pass takes (`static`: top_t, final_k, rerank_budget,
+    multiplicity, escalate)."""
+    packed: object              # the index's PackedIVF snapshot
+    Q: np.ndarray               # (padded rows, d) float32
+    nq: int                     # rows before padding
+    bq: int                     # the compile-cache bucket (pad_queries)
+    filter: Optional[jax.Array]
+    static: dict
 
-    def serve_step(params, token, caches, index):
-        logits, caches = T.decode_step(params, token, caches, index, cfg)
-        nxt = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
-        return nxt[:, None], caches
-
-    return serve_step
-
-
-def make_prefill_step(cfg: ModelConfig, max_seq: int):
-    def prefill_step(params, inputs):
-        return T.prefill(params, inputs, cfg, max_seq=max_seq)
-    return prefill_step
-
-
-class ServeEngine:
-    """Minimal batched greedy-decoding engine (CPU-runnable at smoke scale)."""
-
-    def __init__(self, cfg: ModelConfig, params, max_seq: int = 256):
-        self.cfg = cfg
-        self.params = params
-        self.max_seq = max_seq
-        self._prefill = jax.jit(make_prefill_step(cfg, max_seq))
-        self._step = jax.jit(make_serve_step(cfg), donate_argnums=(2,))
-
-    def generate(self, inputs: dict, n_new: int):
-        """inputs: {"tokens": (B, S)} (+ patches for vlm). Greedy decode."""
-        logits, caches = self._prefill(self.params, inputs)
-        tok = jnp.argmax(logits[:, -1, :], axis=-1)[:, None].astype(jnp.int32)
-        prefix = (self.cfg.n_prefix_embeds
-                  if self.cfg.frontend == "vision" else 0)
-        start = inputs["tokens"].shape[1] + prefix
-        out = [tok]
-        for i in range(n_new - 1):
-            tok, caches = self._step(self.params, tok, caches,
-                                     jnp.asarray(start + i, jnp.int32))
-            out.append(tok)
-        return jnp.concatenate(out, axis=1)
+    @property
+    def escalated(self) -> bool:
+        return bool(self.static["escalate"] and self.filter is not None)
 
 
 class PendingSearch:
@@ -224,8 +190,7 @@ class AnnEngine:
         collection), which a profiler trace records on the device trace's
         clock (DESIGN.md §3.15).
         """
-        from repro.core.router import clamp_top_t
-        from repro.core.search import pad_queries, search_jit_batched
+        from repro.core.search import search_jit_batched
         with TraceAnnotation("engine.prepare"):
             p = (params or SearchParams()).validate(
                 default_top_t=self.top_t, default_rerank=self.rerank_budget)
@@ -239,27 +204,44 @@ class AnnEngine:
                                       0, **meta)
                 return empty.result() if wait else empty
             faults.serve_point("engine:search")
-            if _filter_dev is not None:
-                filt, escalate = _filter_dev, p.escalate
-            else:
-                filt, escalate = self.index.serving_filter(
-                    mask=p.filter_mask, ids=p.filter_ids, escalate=p.escalate)
             t0 = time.perf_counter()
-            packed = self.index.pack()
-            Qp, nq, bq = pad_queries(Q, self.bq)
+            plan = self.plan(Q, p, _filter_dev)
         with TraceAnnotation("engine.upload"):
-            Qd = jnp.asarray(Qp)
+            Qd = jnp.asarray(plan.Q)
         with TraceAnnotation("engine.launch"):
             pending = PendingSearch(
-                search_jit_batched(
-                    packed, Qd,
-                    top_t=clamp_top_t(p.top_t, self.index.centroids.shape[0]),
-                    final_k=p.k, rerank_budget=max(p.rerank_budget, p.k),
-                    bq=bq, multiplicity=1 + max(self.index.n_spills, 1),
-                    filter=filt, escalate=escalate),
-                nq, t0=t0, batch_size=nq,
-                escalated=bool(escalate and filt is not None), **meta)
+                search_jit_batched(plan.packed, Qd, bq=plan.bq,
+                                   filter=plan.filter, **plan.static),
+                plan.nq, t0=t0, batch_size=plan.nq,
+                escalated=plan.escalated, **meta)
         return pending.result() if wait else pending
+
+    def plan(self, Q: np.ndarray, p: SearchParams, filt_dev=None,
+             rows: int = 1) -> SearchPlan:
+        """The search plan for validated queries `Q` and resolved params
+        `p` — the one source of what a launch runs, for this engine's own
+        launch and the front-end's replica fan-out alike (DESIGN.md
+        §3.12): the filter and escalation (`filt_dev`, a device bitmap
+        such as a tenant's, else the index's `serving_filter`), the probe
+        width clamped to the partitions, the rerank floor k, the dedup
+        multiplicity of the index's spills, and the queries padded to a
+        compile-cache bucket and to a multiple of `rows`. It validates
+        nothing and opens no span."""
+        from repro.core.router import clamp_top_t
+        from repro.core.search import pad_queries
+        if filt_dev is not None:
+            filt, escalate = filt_dev, p.escalate
+        else:
+            filt, escalate = self.index.serving_filter(
+                mask=p.filter_mask, ids=p.filter_ids, escalate=p.escalate)
+        packed = self.index.pack()
+        Qp, nq, bq = pad_queries(Q, self.bq, multiple=rows)
+        static = dict(
+            top_t=clamp_top_t(p.top_t, self.index.centroids.shape[0]),
+            final_k=p.k, rerank_budget=max(p.rerank_budget, p.k),
+            multiplicity=1 + max(self.index.n_spills, 1),
+            escalate=bool(escalate))
+        return SearchPlan(packed, Qp, nq, bq, filt, static)
 
     # ---------------------------------------------------------- durability
     def save(self, path: str, *, extra: Optional[dict] = None,

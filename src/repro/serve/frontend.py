@@ -104,8 +104,8 @@ Resilience layer (ISSUE 9, DESIGN.md §3.13) — the front-end learns to say
   data, full coverage) with `SearchResult.degraded=True`; while the
   breaker is open, traffic stays local (still flagged degraded) until the
   half-open probe heals it. The shard-parallel degraded path (partial
-  top-k from healthy shards) lives in core/distributed.py
-  `with_health=True`.
+  top-k from healthy shards) is the `health=` argument of
+  core/distributed.make_distributed_search.
 """
 from __future__ import annotations
 
@@ -888,17 +888,11 @@ class ServingFrontend:
     def _replica_launch(self, Q: np.ndarray, p: SearchParams,
                         filt_dev) -> PendingSearch:
         """Launch a coalesced batch sharded row-wise over all devices; its
-        `replica.gather` is at collection. Mirrors the engine path's
-        filter/escalation plan exactly (serving_filter) so results stay
-        bitwise identical to local execution."""
-        from repro.core.router import clamp_top_t
-        from repro.core.search import pad_queries
+        `replica.gather` is at collection. The engine's plan decides what
+        runs (filter, escalation, static arguments, padding to a multiple
+        of the replicas), as for a local launch, so results stay bitwise
+        identical to local execution."""
         faults.serve_point("replica:dispatch")
-        if filt_dev is None:
-            filt, escalate = self.engine.index.serving_filter(
-                escalate=p.escalate)
-        else:
-            filt, escalate = filt_dev, p.escalate
         devs = jax.devices()
         R = len(devs)
         if self._mesh is None:
@@ -906,28 +900,24 @@ class ServingFrontend:
             from jax.sharding import PartitionSpec as P
             self._mesh = Mesh(np.array(devs), ("r",))
             self._rows = NamedSharding(self._mesh, P("r"))
-        top_t = clamp_top_t(p.top_t, self.engine.index.centroids.shape[0])
-        mult = 1 + max(self.engine.index.n_spills, 1)
-        key = (top_t, p.k, max(p.rerank_budget, p.k), mult,
-               bool(escalate), filt is not None, R)
+        plan = self.engine.plan(Q, p, filt_dev, rows=R)
+        key = (*plan.static.items(), plan.filter is not None, R)
         fn = self._rep_cache.get(key)
         if fn is None:
             from repro.core.distributed import make_replicated_search
             fn = jax.jit(make_replicated_search(
-                self._mesh, ("r",), top_t=top_t, final_k=p.k,
-                rerank_budget=max(p.rerank_budget, p.k), multiplicity=mult,
-                with_filter=filt is not None, escalate=bool(escalate)))
+                self._mesh, ("r",), with_filter=plan.filter is not None,
+                **plan.static))
             self._rep_cache[key] = fn
-        args = [self._placed(self._rep_index, self.engine.index.pack())]
-        if filt is not None:
-            args.append(self._placed(self._rep_filters, filt))
+        args = [self._placed(self._rep_index, plan.packed)]
+        if plan.filter is not None:
+            args.append(self._placed(self._rep_filters, plan.filter))
         with TraceAnnotation("replica.scatter"):
-            Qp, nq, _ = pad_queries(Q, self.engine.bq, multiple=R)
-            Qd = jax.device_put(Qp, self._rows)
+            Qd = jax.device_put(plan.Q, self._rows)
         with TraceAnnotation("replica.launch"):
-            return PendingSearch(fn(args[0], Qd, *args[1:]), nq,
+            return PendingSearch(fn(args[0], Qd, *args[1:]), plan.nq,
                                  span="replica.gather",
-                                 escalated=bool(escalate and filt is not None))
+                                 escalated=plan.escalated)
 
     def _placed(self, cache: EpochLRU, src):
         """`src` (the packed index, a filter bitmap) replicated on the

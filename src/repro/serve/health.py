@@ -14,8 +14,8 @@ on one blip (capacity never comes back). The classic answer is a
 
 `CircuitBreaker` is the single-target state machine; `HealthTracker`
 holds one per named target and renders the healthy set as the `(D,)`
-uint8 mask the degraded distributed search paths consume
-(`make_distributed_search(..., with_health=True)`) and as the
+uint8 mask the degraded shard-parallel search consumes
+(`make_distributed_search`'s `health=` argument) and as the
 allow/deny gate the front-end's replica fan-out consults before
 dispatching.
 
@@ -153,7 +153,7 @@ class HealthTracker:
     def mask(self, n_targets: int,
              ok: Optional[Iterable[int]] = None) -> np.ndarray:
         """(n_targets,) uint8 health bitmap over integer targets 0..n-1
-        for the `with_health=True` distributed search paths. `ok`
+        for `make_distributed_search`'s `health=` argument. `ok`
         overrides the breaker query (e.g. a precomputed healthy set, so
         one mask serves a whole batch without consuming extra half-open
         probe slots)."""

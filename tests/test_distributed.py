@@ -1,7 +1,11 @@
 """Multi-device semantics tests (8 virtual CPU devices via subprocess, so
-the main pytest process keeps its single-device view)."""
+the main pytest process keeps its single-device view), and the one-shard
+search on the main process's one device."""
+import functools
 import subprocess
 import sys
+
+import pytest
 
 SCRIPT_ANN = r"""
 import os
@@ -108,18 +112,18 @@ SCRIPT_ANN_PQ = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
-from repro.core.distributed import build_sharded_ivf_pq, make_distributed_search_pq
+from repro.core.distributed import build_sharded_ivf, make_distributed_search
 from repro.core import true_neighbors
 from repro.data.vectors import make_manifold
 
 ds = make_manifold(jax.random.PRNGKey(0), n=16_000, d=32, nq=64, intrinsic_dim=8)
 tn = true_neighbors(ds.X, ds.Q, k=10)
 mesh = jax.make_mesh((8,), ("data",))
-sharded = build_sharded_ivf_pq(jax.random.PRNGKey(1), ds.X, n_shards=8,
-                               n_partitions=16, pq_subspaces=8,
-                               spill_mode="soar", train_iters=5)
-search = make_distributed_search_pq(mesh, ("data",), top_t=8, final_k=10,
-                                    rerank_k=128, q_chunk=32)
+sharded = build_sharded_ivf(jax.random.PRNGKey(1), ds.X, n_shards=8,
+                            n_partitions=16, pq_subspaces=8,
+                            spill_mode="soar", train_iters=5)
+search = make_distributed_search(mesh, ("data",), top_t=8, final_k=10,
+                                 rerank_budget=128, bq=32)
 with jax.set_mesh(mesh):
     ids, scores = jax.jit(search)(sharded, jnp.asarray(ds.Q))
 ids = np.asarray(ids)
@@ -134,9 +138,8 @@ SCRIPT_ANN_FILTERED = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
-from repro.core.distributed import (build_sharded_ivf, build_sharded_ivf_pq,
-                                    make_distributed_search,
-                                    make_distributed_search_pq, shard_filters)
+from repro.core.distributed import (build_sharded_ivf,
+                                    make_distributed_search, shard_filters)
 from repro.data.vectors import make_manifold
 
 ds = make_manifold(jax.random.PRNGKey(0), n=8_000, d=32, nq=32, intrinsic_dim=8)
@@ -149,19 +152,18 @@ filt = shard_filters(mask, [1000] * 8)
 sharded = build_sharded_ivf(jax.random.PRNGKey(1), ds.X, n_shards=8,
                             n_partitions=16, spill_mode="soar", train_iters=4)
 search = make_distributed_search(mesh, ("data",), top_t=10, final_k=10,
-                                 with_filter=True)
+                                 bq=32)
 with jax.set_mesh(mesh):
     ids, _ = jax.jit(search)(sharded, jnp.asarray(ds.Q), filt)
 ids = np.asarray(ids)
 rec = (ids[:, :, None] == tn[:, None, :]).any(-1).mean()
 assert rec > 0.9, f"filtered distributed recall {rec}"
 assert mask[ids[ids >= 0]].all(), "result violated the subset filter"
-shardedpq = build_sharded_ivf_pq(jax.random.PRNGKey(1), ds.X, n_shards=8,
-                                 n_partitions=16, pq_subspaces=8,
-                                 spill_mode="soar", train_iters=4)
-searchpq = make_distributed_search_pq(mesh, ("data",), top_t=10, final_k=10,
-                                      rerank_k=128, q_chunk=32,
-                                      with_filter=True)
+shardedpq = build_sharded_ivf(jax.random.PRNGKey(1), ds.X, n_shards=8,
+                              n_partitions=16, pq_subspaces=8,
+                              spill_mode="soar", train_iters=4)
+searchpq = make_distributed_search(mesh, ("data",), top_t=10, final_k=10,
+                                   rerank_budget=128, bq=32)
 with jax.set_mesh(mesh):
     idsp, _ = jax.jit(searchpq)(shardedpq, jnp.asarray(ds.Q), filt)
 idsp = np.asarray(idsp)
@@ -198,16 +200,15 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from repro.core.build import build_ivf_sharded
 from repro.core.distributed import (make_distributed_search,
-                                    make_distributed_search_pq,
                                     sharded_from_indexes,
-                                    sharded_from_indexes_pq,
                                     stack_tree_routers)
 from repro.core import true_neighbors
 from repro.data.vectors import make_manifold
 
 # each shard builds its own index AND its own two-level router (like its
-# own codebook); the with_router=True search paths take the stacked tables
-# as a trailing argument and probe through them shard-locally
+# own codebook); the search takes the stacked tables as its `router`
+# argument and probes through them shard-locally. The shards are PQ-built;
+# the exact case drops their codes.
 ds = make_manifold(jax.random.PRNGKey(0), n=8_000, d=32, nq=32, intrinsic_dim=8)
 tn = true_neighbors(ds.X, ds.Q, k=10)
 nl = 1_000
@@ -218,20 +219,19 @@ idxs = [build_ivf_sharded(jax.random.fold_in(jax.random.PRNGKey(1), s),
         for s in range(8)]
 srt = stack_tree_routers([i.router for i in idxs])
 mesh = jax.make_mesh((8,), ("data",))
+shardedpq = sharded_from_indexes(idxs)
+sharded = shardedpq._replace(packed=shardedpq.packed._replace(
+    part_codes=None, pq=None))
 search = make_distributed_search(mesh, ("data",), top_t=8, final_k=10,
-                                 with_router=True, t_route=3)
+                                 rerank_budget=128, bq=32, t_route=3)
 with jax.set_mesh(mesh):
-    ids, _ = jax.jit(search)(sharded_from_indexes(idxs), jnp.asarray(ds.Q), srt)
+    ids, _ = jax.jit(search)(sharded, jnp.asarray(ds.Q), router=srt)
 ids = np.asarray(ids)
 rec = (ids[:, :, None] == tn[:, None, :]).any(-1).mean()
 assert rec > 0.70, f"tree-routed distributed recall {rec}"
 assert ids.max() < 8_000
-searchpq = make_distributed_search_pq(mesh, ("data",), top_t=8, final_k=10,
-                                      rerank_k=128, q_chunk=32,
-                                      with_router=True, t_route=3)
 with jax.set_mesh(mesh):
-    idsp, _ = jax.jit(searchpq)(sharded_from_indexes_pq(idxs),
-                                jnp.asarray(ds.Q), srt)
+    idsp, _ = jax.jit(search)(shardedpq, jnp.asarray(ds.Q), router=srt)
 idsp = np.asarray(idsp)
 recp = (idsp[:, :, None] == tn[:, None, :]).any(-1).mean()
 assert recp > 0.65, f"tree-routed distributed PQ recall {recp}"
@@ -241,3 +241,48 @@ print("OK recall", rec, recp)
 
 def test_distributed_ann_search_tree_routed():
     _run(SCRIPT_ANN_ROUTER)
+
+
+# ------------------------------------------------------------ one pass
+@functools.lru_cache(maxsize=None)
+def _one_shard(pq_subspaces):
+    import jax
+    from repro.core.distributed import build_sharded_ivf
+    from repro.data.vectors import make_manifold
+    ds = make_manifold(jax.random.PRNGKey(0), n=2_000, d=16, nq=40,
+                       intrinsic_dim=8)
+    return ds, build_sharded_ivf(jax.random.PRNGKey(1), ds.X, n_shards=1,
+                                 n_partitions=16, train_iters=3,
+                                 pq_subspaces=pq_subspaces)
+
+
+@pytest.mark.parametrize("filtered", [False, True],
+                         ids=["unfiltered", "filtered"])
+@pytest.mark.parametrize("pq_subspaces", [0, 8], ids=["exact", "pq"])
+def test_one_shard_runs_the_single_host_pass(pq_subspaces, filtered):
+    """A one-shard make_distributed_search on a one-device mesh answers
+    bitwise as search_jit_batched (no escalation) on that shard's
+    PackedIVF: the sharded path runs the single-host pass, tiles and all
+    (40 queries in tiles of 16, the last one padded)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from repro.core.distributed import make_distributed_search
+    from repro.core.search import search_jit_batched
+    ds, sharded = _one_shard(pq_subspaces)
+    packed = jax.tree_util.tree_map(lambda x: x[0], sharded.packed)
+    mask = (np.random.default_rng(0).random(2_000) < 0.3).astype(np.uint8)
+    kw = dict(top_t=4, final_k=10, rerank_budget=32, bq=16, multiplicity=2)
+    mesh = jax.make_mesh((1,), ("data",))
+    Q = jnp.asarray(ds.Q)
+    with jax.set_mesh(mesh):
+        got = jax.jit(make_distributed_search(mesh, ("data",), **kw))(
+            sharded, Q, filter=jnp.asarray(mask[None]) if filtered else None)
+    want = search_jit_batched(packed, Q, escalate=False,
+                              filter=jnp.asarray(mask) if filtered else None,
+                              **kw)
+    for a, b in zip(got, want):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    if filtered:
+        ids = np.asarray(got[0])
+        assert mask[ids[ids >= 0]].all()

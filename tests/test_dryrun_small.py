@@ -16,7 +16,7 @@ from repro.launch.hlo_analysis import analyze
 from repro.models.config import ShapeCell
 from repro.models.layers import set_logical_rules
 from repro.models import transformer as T
-from repro.serve.engine import make_serve_step
+from repro.launch.serve import make_serve_step
 from repro.train import optimizer as opt
 from repro.train.train_loop import make_train_step
 

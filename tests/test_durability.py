@@ -113,7 +113,7 @@ def test_knn_memory_roundtrip_with_filters(rng, tmp_path):
 def test_sharded_envelope_roundtrip(rng, tmp_path):
     from repro.core.build import build_ivf_sharded
     from repro.core.distributed import (load_sharded, save_sharded,
-                                        sharded_from_indexes_pq)
+                                        sharded_from_indexes)
     from repro.core.mutable import MutableIVF
     X = rng.normal(size=(512, 8)).astype(np.float32)
     shards = [build_ivf_sharded(jax.random.PRNGKey(s),
@@ -121,12 +121,12 @@ def test_sharded_envelope_roundtrip(rng, tmp_path):
                                 pq_subspaces=2) for s in range(2)]
     shards[0] = MutableIVF.from_index(shards[0])
     shards[0].add(rng.normal(size=(10, 8)).astype(np.float32))
-    s0 = sharded_from_indexes_pq(shards)
+    s0 = sharded_from_indexes(shards)
     p = str(tmp_path / "shards")
     save_sharded(p, shards, extra={"note": 1})
     loaded, extra = load_sharded(p)
     assert extra == {"note": 1}
-    s1 = sharded_from_indexes_pq(loaded)
+    s1 = sharded_from_indexes(loaded)
     for a, b in zip(jax.tree_util.tree_leaves(s0),
                     jax.tree_util.tree_leaves(s1)):
         assert np.array_equal(np.asarray(a), np.asarray(b))

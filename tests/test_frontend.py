@@ -466,6 +466,40 @@ def test_replica_policy_multidevice():
     assert "OK" in r.stdout
 
 
+# One plan (DESIGN.md §3.12): the replica launch runs the engine's plan, so
+# for the same request its jit-cache key holds exactly the static arguments
+# of the engine's own launch — unfiltered, behind the standing soft-delete
+# filter, and for a tenant. A one-device mesh is enough to launch it.
+@pytest.mark.parametrize("case", ["plain", "soft_deleted", "tenant"])
+def test_replica_launch_takes_the_engine_plan(ds, engine, monkeypatch, case):
+    from repro.core import search
+    seen = []
+    real = search.search_jit_batched
+
+    def spy(*a, **kw):
+        seen.append({k: v for k, v in kw.items()
+                     if k not in ("bq", "filter")})
+        return real(*a, **kw)
+
+    monkeypatch.setattr(search, "search_jit_batched", spy)
+    if case == "soft_deleted":
+        engine.remove(np.arange(0, N, 3), hard=False)
+    with ServingFrontend(engine, policy="local") as fe:
+        fe.register_tenant("t", ids=np.arange(0, N, 2))
+        p = SearchParams(k=6, tenant="t" if case == "tenant" else None,
+                         escalate=True).validate(
+            default_top_t=engine.top_t, default_rerank=engine.rerank_budget)
+        filt = fe.tenants.get("t") if case == "tenant" else None
+        local = engine.search_request(ds.Q, p, _filter_dev=filt)
+        rep = fe._replica_launch(ds.Q, p, filt).result()
+        (key,) = fe._rep_cache
+    assert dict(key[:-2]) == seen[-1]
+    assert key[-2:] == (case != "plain", len(jax.devices()))
+    assert rep.escalated == local.escalated
+    assert np.array_equal(rep.ids, local.ids)
+    assert np.array_equal(rep.scores, local.scores)
+
+
 # The index is placed on the replicas once per packed snapshot (DESIGN.md
 # §3.12). After the warm-up dispatch every further one runs with implicit
 # device-to-device transfers disallowed for the whole process (the guard

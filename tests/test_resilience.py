@@ -28,7 +28,7 @@ Pins, per the acceptance criteria:
    deterministically.
 9. Durability composition: a WAL crash mid-mutation BEHIND the front-end
    recovers bitwise per the PR 7 contract.
-10. Degraded fan-out (subprocess, 8 virtual devices): with_health
+10. Degraded fan-out (subprocess, 8 virtual devices): a health mask
     all-healthy is bitwise the plain path; a dead shard's ids vanish
     while healthy shards' answers survive; the replica breaker falls
     back to bitwise-identical local serving flagged degraded, then
@@ -502,20 +502,19 @@ mesh = jax.make_mesh((8,), ("data",))
 sharded = build_sharded_ivf(jax.random.PRNGKey(1), ds.X, n_shards=8,
                             n_partitions=16, spill_mode="soar",
                             train_iters=3)
-plain = make_distributed_search(mesh, ("data",), top_t=8, final_k=10)
-degr = make_distributed_search(mesh, ("data",), top_t=8, final_k=10,
-                               with_health=True)
+search = jax.jit(make_distributed_search(mesh, ("data",), top_t=8,
+                                         final_k=10))
 with jax.set_mesh(mesh):
-    ids0, sc0 = jax.jit(plain)(sharded, jnp.asarray(ds.Q))
+    ids0, sc0 = search(sharded, jnp.asarray(ds.Q))
     ones = jnp.ones((8,), jnp.uint8)
-    ids1, sc1 = jax.jit(degr)(sharded, jnp.asarray(ds.Q), ones)
+    ids1, sc1 = search(sharded, jnp.asarray(ds.Q), health=ones)
     assert np.array_equal(np.asarray(ids0), np.asarray(ids1)), "healthy != plain"
     assert np.array_equal(np.asarray(sc0), np.asarray(sc1))
     h = HealthTracker(fail_threshold=1)
     h.failure(3)                        # shard 3 down
     mask = h.mask(8)
     assert shards_ok_from_mask(mask) == (0, 1, 2, 4, 5, 6, 7)
-    ids2, sc2 = jax.jit(degr)(sharded, jnp.asarray(ds.Q), jnp.asarray(mask))
+    ids2, sc2 = search(sharded, jnp.asarray(ds.Q), health=jnp.asarray(mask))
 ids0, ids2 = np.asarray(ids0), np.asarray(ids2)
 per = 8_000 // 8
 lo, hi = 3 * per, 4 * per

@@ -5,7 +5,7 @@ import pytest
 
 from repro.configs import get_config
 from repro.models import transformer as T
-from repro.serve.engine import ServeEngine, make_serve_step
+from repro.launch.serve import ServeEngine, make_serve_step
 
 
 @pytest.fixture(scope="module")

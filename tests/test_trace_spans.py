@@ -248,19 +248,19 @@ def _scope_names(hlo: str) -> set:
     ("pq", STAGES),
     ("pq_filtered", STAGES + ("escalate",)),
     ("exact", ("probe", "gather", "score", "dedup")),
-    ("sharded", ("merge",)),
+    ("sharded", STAGES + ("merge",)),
 ])
 def test_named_stages_reach_the_compiled_hlo(ds, engine, case, expect):
     Q = jnp.asarray(ds.Q[:16])
     if case == "sharded":
-        from repro.core.distributed import (build_sharded_ivf_pq,
-                                            make_distributed_search_pq)
+        from repro.core.distributed import (build_sharded_ivf,
+                                            make_distributed_search)
         mesh = jax.make_mesh((1,), ("data",))
-        sharded = build_sharded_ivf_pq(jax.random.PRNGKey(1), ds.X,
-                                       n_shards=1, n_partitions=16,
-                                       pq_subspaces=8, train_iters=3)
-        fn = make_distributed_search_pq(mesh, ("data",), top_t=4, final_k=5,
-                                        rerank_k=32, q_chunk=16)
+        sharded = build_sharded_ivf(jax.random.PRNGKey(1), ds.X, n_shards=1,
+                                    n_partitions=16, pq_subspaces=8,
+                                    train_iters=3)
+        fn = make_distributed_search(mesh, ("data",), top_t=4, final_k=5,
+                                     rerank_budget=32, bq=16)
         with jax.set_mesh(mesh):
             hlo = jax.jit(fn).lower(sharded, Q).compile().as_text()
     else:
