@@ -33,6 +33,7 @@ from benchmarks.common import Timer, emit
 from repro.core import build_ivf, pack_ivf, search_jit, true_neighbors
 from repro.core.search import PackedIVF, search_jit_batched
 from repro.data.vectors import glove_like
+from repro.kernels.pq_score import code_table_codes
 from repro.quant.pq import pq_lut
 
 
@@ -41,7 +42,8 @@ def seed_search_jit(packed: PackedIVF, Q, top_t: int, final_k: int,
                     rerank_budget: int = 256):
     """The seed implementation, kept verbatim as the baseline: per-query
     closure, dense (n,)-scatter dedup, top_k over the whole database.
-    `packed.rerank` holds the (n, d) rows, as the seed's layout had it."""
+    `packed.rerank` holds the (n, d) rows and `packed.part_codes` the
+    (c, pmax, m) codes, as the seed's layout had them."""
     C, ids_all, codes_all = packed.centroids, packed.part_ids, packed.part_codes
     n = packed.rerank.shape[0]
 
@@ -104,7 +106,11 @@ def run(n: int, nq: int, c: int, top_t: int, rerank_budget: int,
     Q = jnp.asarray(ds.Q)
     kw = dict(top_t=top_t, final_k=10, rerank_budget=rerank_budget)
 
-    seed_packed = packed._replace(rerank=jnp.asarray(idx.rerank_f32))
+    seed_packed = packed._replace(
+        rerank=jnp.asarray(idx.rerank_f32),
+        part_codes=jnp.asarray(code_table_codes(
+            np.asarray(packed.part_codes), idx.codes.shape[1],
+            packed.part_ids.shape[1]).astype(np.uint8)))
     new_ids, _ = search_jit(packed, Q, **kw)              # compile + warmup
     seed_ids, _ = seed_search_jit(seed_packed, Q, **kw)
     tiled_ids, _ = search_jit_batched(packed, Q, bq=64, **kw)

@@ -404,13 +404,17 @@ def _mutable_from(meta, arrays):
 
 def _packed_state(p):
     from repro.core.search import rerank_table_rows
+    from repro.kernels.pq_score import code_table_codes
     rmeta, rarr = _router_state(p.router)
-    # the rerank table is written as (n, d) rows, the format's storage
+    # the rerank table is written as (n, d) rows and the code table as
+    # (c, pmax, m) uint8 codes, the format's storage
     rows = rerank_table_rows(p.rerank, p.centroids.shape[1])
     arrays = {"centroids": p.centroids, "part_ids": p.part_ids,
               "sizes": p.sizes, "rerank": rows}
     if p.part_codes is not None:
-        arrays["part_codes"] = p.part_codes
+        arrays["part_codes"] = code_table_codes(
+            np.asarray(p.part_codes), p.pq.centers.shape[0],
+            p.part_ids.shape[1]).astype(np.uint8)
     if p.part_codes2 is not None:
         arrays["part_codes2"] = p.part_codes2
     arrays.update(_pq_state(p.pq))
@@ -420,12 +424,14 @@ def _packed_state(p):
 
 def _packed_from(meta, arrays):
     from repro.core.search import PackedIVF, rerank_table
+    from repro.kernels.pq_score import code_table
     import jax.numpy as jnp
     rt = _router_from_state(meta["router"], arrays)
     j = jnp.asarray
     return PackedIVF(
         j(arrays["centroids"]), j(arrays["part_ids"]),
-        j(arrays["part_codes"]) if "part_codes" in arrays else None,
+        j(code_table(arrays["part_codes"])) if "part_codes" in arrays
+        else None,
         j(arrays["part_codes2"]) if "part_codes2" in arrays else None,
         j(arrays["sizes"]), _pq_from_state(arrays),
         j(rerank_table(arrays["rerank"])),

@@ -32,6 +32,7 @@ from repro.core.build import build_ivf_sharded, spill_plan
 from repro.core.router import FlatRouter, TreeRouter
 from repro.core.search import (PackedIVF, _search_block, _search_tiles,
                                pack_ivf, rerank_table_shape)
+from repro.kernels.pq_score import code_table_shape
 from repro.kernels.soar_assign import assign_fused
 from repro.quant.pq import PQCodebook
 
@@ -40,10 +41,10 @@ class ShardedIVF(NamedTuple):
     """One `PackedIVF` per shard, stacked: every leaf of `packed` carries
     a leading shard dim D (centroids (D, c, d), part_ids (D, c, pmax)
     shard-LOCAL ids with -1 padding, the rerank table (D, R, 128), and,
-    on a PQ-scored index, part_codes (D, c, pmax, m) and pq.centers
-    (D, m, 16, s)). An exact-score index has part_codes and pq None, as a
-    single-host PackedIVF does; part_codes2 and router are always None
-    (each shard's router travels as its own argument)."""
+    on a PQ-scored index, the code table part_codes (D, c, R', W·128)
+    and pq.centers (D, m, 16, s)). An exact-score index has part_codes
+    and pq None, as a single-host PackedIVF does; part_codes2 and router
+    are always None (each shard's router travels as its own argument)."""
     packed: PackedIVF
     local_base: jax.Array    # (D,) int32 global id offset of each shard
 
@@ -104,18 +105,20 @@ def sharded_from_indexes(indexes) -> ShardedIVF:
     """Stack per-shard indexes (IVFIndex or MutableIVF) into a ShardedIVF.
 
     Each shard's live snapshot is packed and padded to the common
-    envelope: partitions to the longest one (-1 ids, zero codes), the
-    rerank table to the longest one (zero rows — the ids they would hold
-    never appear in any partition slot, so they are unreachable). The PQ
-    codes and codebooks are stacked iff the shards have them. Local ids
-    keep their shard-stable values and globalize through the cumulative
-    base offsets, so this is also the refresh path after online mutation.
+    envelope: partitions to the longest one (-1 ids; zero code-table rows,
+    whose slots the -1 ids mask), the rerank table to the longest one
+    (zero rows — the ids they would hold never appear in any partition
+    slot, so they are unreachable). The PQ codes and codebooks are
+    stacked iff the shards have them. Local ids keep their shard-stable
+    values and globalize through the cumulative base offsets, so this is
+    also the refresh path after online mutation.
     """
     shards = list(map(_resolve_shard, indexes))
     packed = [pack_ivf(idx, pair_codes=False) for idx in shards]
     pmax = max(pk.part_ids.shape[1] for pk in packed)
     rmax = max(pk.rerank.shape[0] for pk in packed)
     has_pq = packed[0].part_codes is not None
+    code_rows = max(pk.part_codes.shape[1] for pk in packed) if has_pq else 0
 
     def stack(arrays):
         return jnp.asarray(np.stack([np.asarray(a) for a in arrays]))
@@ -124,8 +127,8 @@ def sharded_from_indexes(indexes) -> ShardedIVF:
     return ShardedIVF(PackedIVF(
         centroids=stack(pk.centroids for pk in packed),
         part_ids=stack(_padded(pk.part_ids, 1, pmax, -1) for pk in packed),
-        part_codes=(stack(_padded(pk.part_codes, 1, pmax) for pk in packed)
-                    if has_pq else None),
+        part_codes=(stack(_padded(pk.part_codes, 1, code_rows)
+                          for pk in packed) if has_pq else None),
         part_codes2=None,
         sizes=stack(pk.sizes for pk in packed),
         pq=(PQCodebook(stack(idx.pq.centers for idx in shards))
@@ -191,7 +194,7 @@ def abstract_sharded_ivf(n_shards: int, n_local: int, n_partitions: int,
     return ShardedIVF(PackedIVF(
         f((n_partitions, d), jnp.float32),
         f((n_partitions, pmax), jnp.int32),
-        f((n_partitions, pmax, m), jnp.uint8) if m else None,
+        f(code_table_shape(n_partitions, pmax, m), jnp.int32) if m else None,
         None,
         f((n_partitions,), jnp.int32),
         PQCodebook(f((m, 16, d // m), jnp.float32)) if m else None,

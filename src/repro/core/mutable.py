@@ -50,6 +50,7 @@ from repro.ckpt.wal import REC_ADD, REC_COMPACT, REC_HARDEN, REC_REMOVE
 from repro.core.build import build_ivf_sharded, spill_plan
 from repro.core.ivf import IVFIndex
 from repro.core.search import LANES, PackedIVF, _paired_codes, rerank_table
+from repro.kernels.pq_score import code_table
 from repro.kernels.soar_assign import assign_fused
 from repro.quant.pq import PQCodebook, pq_encode
 
@@ -555,7 +556,8 @@ class MutableIVF:
                 jnp.asarray((rows >= 0).sum(axis=1).astype(np.int32)))
             if part_codes is not None:
                 crows = self.part_codes[dirty]
-                part_codes = part_codes.at[di].set(jnp.asarray(crows))
+                part_codes = part_codes.at[di].set(
+                    jnp.asarray(code_table(crows)))
                 if part_codes2 is not None:
                     part_codes2 = part_codes2.at[di].set(
                         jnp.asarray(_paired_codes(crows)))
@@ -603,7 +605,7 @@ class MutableIVF:
         live_sizes = (ids >= 0).sum(axis=1).astype(np.int32)
         self._packed = PackedIVF(
             jnp.asarray(self.centroids), jnp.asarray(ids),
-            jnp.asarray(codes) if codes is not None else None,
+            jnp.asarray(code_table(codes)) if codes is not None else None,
             (jnp.asarray(_paired_codes(codes))
              if codes is not None and pair_codes else None),
             jnp.asarray(live_sizes), self.pq,

@@ -12,9 +12,10 @@ sublinear at serving time.
 
 - `search_jit`: fixed-budget, fully-jit pipeline (padded partitions) — the
   TPU-target path the Pallas kernels and the distributed serving engine use.
-  Batched centroid GEMM + top-t, gathered candidate windows, PQ LUT scoring
-  through the one-hot MXU Pallas kernel on TPU (jnp gather fallback
-  elsewhere), sort-based dedup-by-max over the window, exact rerank.
+  Batched centroid GEMM + top-t, gathered candidate ids, PQ LUT scoring
+  by the Pallas window kernel reading the code table in place on TPU
+  (jnp gather fallback elsewhere), sort-based dedup-by-max over the
+  window, exact rerank.
   `search_jit_batched` streams large query batches through `bq`-sized tiles
   so live buffers stay bounded regardless of nq.
 
@@ -48,6 +49,7 @@ from repro.core.ivf import IVFIndex
 from repro.core.router import FlatRouter, check_query_dim
 from repro import kernels
 from repro.kernels import note_route
+from repro.kernels.pq_score import code_table, code_table_codes
 from repro.quant.pq import pq_lut, PQCodebook
 
 
@@ -299,7 +301,15 @@ class PackedIVF(NamedTuple):
     """Dense, padded IVF layout for the jit path.
 
     part_ids:    (c, pmax) int32 point ids, -1 padded
-    part_codes:  (c, pmax, m) uint8 PQ codes (zeros where padded)
+    part_codes:  (c, R, W·128) int32 code table, the only device copy of
+                 the 4-bit PQ codes (`kernels/pq_score.py::code_table`,
+                 the layout's one definition): partition p is one
+                 tile-aligned block, slots lane-dense, 8 subspaces a
+                 word. Why (DESIGN.md §3.6): the window kernel DMAs each
+                 probed partition's block from HBM as it lies, where a
+                 (c, pmax, m) uint8 array was laid out to pad least and
+                 every search program copied it whole (130 MB at
+                 glove-100) before gathering an int32 window from it.
     part_codes2: (c, pmax, ceil(m/2)) int16/int32 pre-offset PAIR-merged
                  codes (ScaNN-style LUT merging, DESIGN.md §3.6): entry j
                  is codes[2j]·16 + codes[2j+1] + j·256 (+ a single-subspace
@@ -397,7 +407,7 @@ def pack_ivf(index: IVFIndex, pmax: Optional[int] = None,
     rt = index.router
     return PackedIVF(
         jnp.asarray(index.centroids), jnp.asarray(ids),
-        jnp.asarray(codes) if codes is not None else None,
+        jnp.asarray(code_table(codes)) if codes is not None else None,
         (jnp.asarray(_paired_codes(codes))
          if codes is not None and pair_codes else None),
         jnp.asarray(np.minimum(sizes, pmax).astype(np.int32)),
@@ -405,26 +415,31 @@ def pack_ivf(index: IVFIndex, pmax: Optional[int] = None,
         rt.device() if rt is not None else None)
 
 
-def window_pq_scores(luts, codes):
-    """(nq, m, 16) LUTs × (nq, cand, m) candidate-window codes → (nq, cand).
+def window_pq_scores(luts, table, parts, pmax: int):
+    """(nq, m, 16) LUTs × the code table (c, R, W·128) × probed partitions
+    (nq, t) → (nq, t, pmax) scores of each probed partition's slots.
 
-    Routes through the one-hot MXU Pallas kernel on TPU. Elsewhere: flat
-    per-query LUT gather — indexing the (nq, m·16) LUT with precomputed
-    flat offsets keeps the gather operand tiny, where the naive
+    On TPU the Pallas window kernel reads each probed partition's block
+    from the table in place. Elsewhere the probed blocks are gathered and
+    unpacked to a (nq, t·pmax, m) window scored by a flat per-query LUT
+    gather — indexing the (nq, m·16) LUT with precomputed flat offsets
+    keeps the gather operand tiny, where the naive
     `take_along_axis(luts[:, None], ...)` form (kernels/ref.py) broadcasts
     the LUT to (nq, cand, m, 16) — gigabytes at serving shapes.
     """
+    nq, t = parts.shape
     if jax.default_backend() == "tpu":
         from repro.kernels.ops import pq_score_window
         note_route("pq_score_window",
                    "interpret" if kernels.interpret_mode() else "mosaic")
-        return pq_score_window(luts, codes)
+        return pq_score_window(luts, table, parts)[:, :, :pmax]
     note_route("pq_score_window", "xla")
-    nq, cand, m = codes.shape
+    m = luts.shape[1]
+    codes = code_table_codes(table[parts], m, pmax).reshape(nq, t * pmax, m)
     lutflat = luts.reshape(nq, m * luts.shape[-1])
-    idx = codes.astype(jnp.int32) + jnp.arange(m, dtype=jnp.int32) * luts.shape[-1]
-    g = jnp.take_along_axis(lutflat, idx.reshape(nq, cand * m), axis=-1)
-    return g.reshape(nq, cand, m).sum(axis=-1)
+    idx = codes + jnp.arange(m, dtype=jnp.int32) * luts.shape[-1]
+    g = jnp.take_along_axis(lutflat, idx.reshape(nq, t * pmax * m), axis=-1)
+    return g.reshape(nq, t, pmax, m).sum(axis=-1)
 
 
 def dedup_topk_window(ids, scores, k: int, multiplicity: int = 2):
@@ -540,16 +555,18 @@ def _search_pass(packed: PackedIVF, Q, router, top_t: int, final_k: int,
             idx = packed.part_codes2[parts].reshape(nq, -1).astype(jnp.int32)
         with jax.named_scope("score"):
             g = jnp.take_along_axis(_merged_luts(luts), idx, axis=-1)
-            approx = g.reshape(nq, t * pmax, -1).sum(axis=-1)
+            approx = g.reshape(nq, t, pmax, -1).sum(axis=-1)
     else:
-        # TPU one-hot MXU kernel, or raw-code fallback (pair_codes=False)
-        with jax.named_scope("gather"):
-            codes = packed.part_codes[parts].reshape(nq, t * pmax, -1)
+        # TPU: the window kernel reads the code table in place; elsewhere
+        # the raw-code fallback (pair_codes=False)
         with jax.named_scope("score"):
-            approx = window_pq_scores(luts, codes)
+            approx = window_pq_scores(luts, packed.part_codes, parts, pmax)
     with jax.named_scope("score"):
-        approx = approx + jnp.repeat(psc, pmax, axis=-1)       # + <q, centroid>
-        approx = jnp.where(valid, approx, -jnp.inf)
+        # + <q, centroid>, and mask, before the flattening: one pass over
+        # the kernel's output on the way to the dedup
+        approx = jnp.where(valid.reshape(nq, t, pmax),
+                           approx + psc[:, :, None],
+                           -jnp.inf).reshape(nq, t * pmax)
     with jax.named_scope("dedup"):
         bi, bv = dedup_topk_window(ids, approx, rerank_budget, multiplicity)
     if filter is not None:
@@ -608,8 +625,9 @@ def search_jit(packed: PackedIVF, Q, top_t: int, final_k: int,
     """Fully-jit batched search. Returns (ids, scores) of shape (nq, final_k).
 
     Pipeline: router probe top-t (flat: batched centroid MIPS; tree: fused
-    two-level kernel) → gather per-query candidate windows → PQ LUT
-    scoring (+ coarse offset; Pallas one-hot MXU kernel on TPU) →
+    two-level kernel) → gather per-query candidate ids → PQ LUT
+    scoring (+ coarse offset; on TPU the Pallas window kernel reads the
+    probed partitions' codes from the code table in place) →
     sort-based dedup-by-max over the window → top rerank_budget → exact
     rerank → top final_k. No intermediate scales with n.
 

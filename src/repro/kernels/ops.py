@@ -18,10 +18,11 @@ def pq_score(luts, codes, **kw):
                            **kw)
 
 
-def pq_score_window(luts, codes, **kw):
-    """Per-query candidate-window scoring: (nq, m, 16) × (nq, cand, m) →
-    (nq, cand) — the candidate-local search_jit hot path."""
-    return pq_score_window_pallas(luts, codes,
+def pq_score_window(luts, table, parts, **kw):
+    """Per-query candidate-window scoring from the packed code table in
+    place: (nq, m, 16) × table (c, R, W·128) × probes (nq, t) →
+    (nq, t, R·128) — the candidate-local search_jit hot path."""
+    return pq_score_window_pallas(luts, table, parts,
                                   interpret=kernels.interpret_mode(), **kw)
 
 

@@ -1,23 +1,30 @@
-"""Pallas TPU kernels: batched PQ LUT scoring as a one-hot MXU contraction.
+"""Pallas TPU kernels: batched PQ LUT scoring.
 
-TPU adaptation of ScaNN's AVX2 LUT16 (DESIGN.md §3): instead of in-register
-shuffles, codes are expanded to one-hot IN VMEM and contracted against the
-per-query LUTs on the MXU. The LUT block stays VMEM-resident across the whole
-point dimension; HBM traffic is one streaming read of the (packed) codes.
+TPU adaptation of ScaNN's AVX2 LUT16 (DESIGN.md §3). Two variants:
 
-Two variants:
-
-- `pq_score_pallas`: shared code matrix — every query scores every point.
+- `pq_score_pallas`: shared code matrix — every query scores every point,
+  as a one-hot MXU contraction. Codes are expanded to one-hot IN VMEM and
+  contracted against the per-query LUTs; the LUT block stays
+  VMEM-resident across the whole point dimension.
       score[q, i] = sum_m luts[q, m, codes[i, m]]
                   = luts[q].reshape(m*16) · onehot(codes[i]).reshape(m*16)
 
-- `pq_score_window_pallas`: per-query candidate windows — query q scores only
-  ITS OWN gathered candidates (the t·pmax window the IVF search probes), the
-  shape the candidate-local `search_jit` pipeline produces (DESIGN.md §3.6).
-      score[q, i] = sum_m luts[q, m, codes[q, i, m]]
-  Each query has its own codes, so there is no shared operand for the MXU:
-  the lookup is a 16-way select per subspace on the VPU over lane-dense
-  (BQ, BN) code tiles, accumulated over the m subspaces.
+- `pq_score_window_pallas`: per-query candidate windows, scored from the
+  packed code table in place (DESIGN.md §3.6). Query q scores only the
+  slots of ITS OWN probed partitions `parts[q]`:
+      score[q, j, i] = sum_m luts[q, m, codes[parts[q, j], i, m]]
+  The table is the index's only device copy of the codes, and this
+  module owns its layout (`code_table`): partition p is one tile-aligned
+  (R, W·128) int32 block, slot r·128 + l in lane l of row r, word w of
+  the slot at lanes [w·128, (w+1)·128), subspace 8w + s in nibble s of
+  word w. The probed ids arrive by scalar prefetch; each block is fetched
+  by its own DMA from HBM into a ring of VMEM buffers that runs ahead
+  across queries, so no window of codes is ever gathered into HBM. Each
+  query has its own codes, so there is no shared operand for the MXU: a
+  subspace's nibble picks its LUT entry on the VPU, by a sublane gather
+  from each 8-entry half of the LUT (laid down on the sublanes of every
+  lane once a query) and a select on the nibble's high bit; subspaces
+  add in order j = 0..m-1.
 """
 from __future__ import annotations
 
@@ -26,6 +33,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -38,9 +46,47 @@ from repro import kernels
 DEFAULT_BQ = 128
 DEFAULT_BN = 512
 
-# Window variant: codes m×BQ×BN·4B (400 KB at m=25) + luts + out « 16 MB.
-DEFAULT_WIN_BQ = 8
-DEFAULT_WIN_BN = 512
+# Window variant: code blocks in flight. Each is one partition's
+# (R, W·128) int32 block (32 KB at glove-100's 2,048 slots and m=25).
+WIN_DEPTH = 8
+LANES = 128      # lanes of a TPU vector register and tile
+NIBBLES = 8      # 4-bit codes a word of the code table
+
+
+def code_table_shape(c: int, pmax: int, m: int) -> tuple:
+    """Shape of the code table of c partitions of pmax slots and m 4-bit
+    subspaces: (c, R, W·128), R = ⌈pmax/1024⌉·8 slot rows (a whole number
+    of (8, 128) tiles, so each partition is one aligned DMA) and W =
+    ⌈m/8⌉ words a slot."""
+    return (c, max(-(-pmax // (8 * LANES)), 1) * 8, -(-m // NIBBLES) * LANES)
+
+
+def code_table(codes) -> np.ndarray:
+    """(c, pmax, m) 4-bit PQ codes → the code table: word w of slot
+    r·128 + l at [p, r, w·128 + l], subspace 8w + s in nibble s (bits
+    4s..4s+3) of word w; padding slots and nibbles are 0. Two nibbles
+    make a byte and four little-endian bytes a word."""
+    codes = np.asarray(codes, np.uint8)
+    c, pmax, m = codes.shape
+    _, rows, wl = code_table_shape(c, pmax, m)
+    words = wl // LANES
+    nib = np.zeros((c, rows * LANES, words * NIBBLES), np.uint8)
+    nib[:, :pmax, :m] = codes
+    packed = (nib[..., 0::2] | nib[..., 1::2] << 4).view("<u4")
+    table = packed.reshape(c, rows, LANES, words).transpose(0, 1, 3, 2)
+    return np.ascontiguousarray(table).reshape(c, rows, wl).view(np.int32)
+
+
+def code_table_codes(table, m: int, pmax: int):
+    """Inverse of `code_table` over the last two axes: (..., R, W·128)
+    table rows → (..., pmax, m) int32 codes. numpy in, numpy out; a jax
+    array (the CPU search fallback) stays on the device."""
+    xp = np if isinstance(table, np.ndarray) else jnp
+    *lead, rows, wl = table.shape
+    words = table.reshape(*lead, rows, wl // LANES, LANES)
+    codes = xp.stack([(words[..., j // NIBBLES, :] >> 4 * (j % NIBBLES)) & 15
+                      for j in range(m)], axis=-1)
+    return codes.reshape(*lead, rows * LANES, m)[..., :pmax, :]
 
 
 def _resolve_interpret(interpret: Optional[bool]) -> bool:
@@ -96,58 +142,102 @@ def pq_score_pallas(luts, codes, n_centers: int = 16,
     return out[:nq, :n]
 
 
-def _pq_score_window_kernel(lut_ref, codes_ref, out_ref, *, n_centers: int):
-    # codes arrive subspace-major, (m, BQ, BN): codes_ref[j] is one
-    # lane-dense (BQ, BN) tile, and lut column j·16+k broadcasts along the
-    # lanes of its query row — no 3-D one-hot, no batched dot_general
-    # (Mosaic refuses a batch-dim dot_general)
-    lut = lut_ref[...]                                       # (BQ, m*16)
-    acc = jnp.zeros(out_ref.shape, jnp.float32)
-    for j in range(codes_ref.shape[0]):
-        cj = codes_ref[j]                                    # (BQ, BN) int32
-        g = jnp.zeros_like(acc)
-        for k in range(n_centers):
-            col = j * n_centers + k
-            g = jnp.where(cj == k, lut[:, col:col + 1], g)
-        acc = acc + g
-    out_ref[...] = acc
+def _lookup(tables, nib):
+    """tables[nib] elementwise for (8, 128) nibbles in [0, 16): a sublane
+    gather of the low 3 bits from each 8-entry half, then a select on
+    the high bit (exact)."""
+    low = nib & 7
+    lo, hi = (jnp.take_along_axis(tab, low, axis=0, mode="promise_in_bounds")
+              for tab in tables)
+    return jnp.where(nib > 7, hi, lo)
 
 
-@functools.partial(jax.jit, static_argnames=("n_centers", "bq", "bn", "interpret"))
-def pq_score_window_pallas(luts, codes, n_centers: int = 16,
-                           bq: int = DEFAULT_WIN_BQ, bn: int = DEFAULT_WIN_BN,
+def _pq_score_window_kernel(parts_ref, lut_ref, table_ref, out_ref, buf,
+                            sem, lut_rows, *, t: int, m: int, depth: int):
+    # Block b = q·t + j is partition parts[b]; it lands in ring slot
+    # b % depth. Block b + depth - 1 is started as block b is waited on,
+    # into the slot block b - 1 has just freed, so depth - 1 blocks are in
+    # flight while one is scored; the ring runs across grid steps (the
+    # grid is sequential), primed once at the first query.
+    q = pl.program_id(0)
+    nblk = pl.num_programs(0) * t
+
+    def fetch(b):
+        slot = b % depth
+        return pltpu.make_async_copy(table_ref.at[parts_ref[b]],
+                                     buf.at[slot], sem.at[slot])
+
+    @pl.when(q == 0)
+    def _():
+        for b in range(depth - 1):
+            @pl.when(b < nblk)
+            def _():
+                fetch(b).start()
+
+    # LUT half h of subspace i, entry 8h + e, on sublane e of every lane:
+    # the source of the sublane gather, built once a query
+    lut = lut_ref[0]                                        # (8, 2m)
+    for c in range(2 * m):
+        lut_rows[c] = jnp.broadcast_to(lut[:, c:c + 1], (8, LANES))
+    groups = out_ref.shape[2] // 8
+
+    def probe(j, carry):
+        b = q * t + j
+
+        @pl.when(b + depth - 1 < nblk)
+        def _():
+            fetch(b + depth - 1).start()
+
+        fetch(b).wait()
+        block = buf.at[b % depth]
+        acc = [jnp.zeros((8, LANES), jnp.float32) for _ in range(groups)]
+        for i in range(m):
+            w, s = divmod(i, NIBBLES)
+            tables = lut_rows[2 * i], lut_rows[2 * i + 1]
+            for r in range(groups):
+                word = block[8 * r:8 * r + 8, w * LANES:(w + 1) * LANES]
+                acc[r] = acc[r] + _lookup(tables, (word >> 4 * s) & 15)
+        for r in range(groups):
+            out_ref[0, j, 8 * r:8 * r + 8] = acc[r]
+        return carry
+
+    jax.lax.fori_loop(0, t, probe, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def pq_score_window_pallas(luts, table, parts,
                            interpret: Optional[bool] = None):
-    """luts (nq, m, 16) f32, codes (nq, cand, m) int → (nq, cand) f32 scores.
-
-    Per-query candidate-window scoring: row q of `codes` is query q's own
-    gathered candidate window (already in partition-probe order). This is the
-    hot-path shape of the candidate-local `search_jit` pipeline.
+    """luts (nq, m, 16) f32, table (c, R, W·128) int32 packed code table,
+    parts (nq, t) int32 probed partitions → (nq, t, R·128) f32 scores:
+    row [q, j] scores partition parts[q, j]'s R·128 slots (padding slots
+    hold code 0 and score as such; the caller masks them by id).
     """
     interpret = _resolve_interpret(interpret)
     nq, m, k = luts.shape
-    assert k == n_centers
-    assert codes.shape[0] == nq and codes.shape[2] == m, (luts.shape, codes.shape)
-    cand = codes.shape[1]
-    lutmat = luts.reshape(nq, m * k)
-    qpad = (-nq) % bq
-    npad = (-cand) % bn
-    lutmat = jnp.pad(lutmat, ((0, qpad), (0, 0)))
-    # int32 cast, padding and the subspace-major transpose fuse into one copy
-    codes_p = jnp.pad(codes.astype(jnp.int32).transpose(2, 0, 1),
-                      ((0, 0), (0, qpad), (0, npad)))        # (m, nq, cand)
-    grid = (lutmat.shape[0] // bq, codes_p.shape[2] // bn)
+    _, rows, wl = table.shape
+    assert k == 16 and table.shape[1:] == code_table_shape(
+        1, rows * LANES, m)[1:], (luts.shape, table.shape)
+    t = parts.shape[1]
+    depth = min(WIN_DEPTH, nq * t)
+    # [q, e, 2i + h] = luts[q, i, 8h + e]: a query's halves as columns
+    lut_cols = luts.reshape(nq, m, 2, 8).transpose(0, 3, 1, 2).reshape(
+        nq, 8, 2 * m)
     out = pl.pallas_call(
-        functools.partial(_pq_score_window_kernel, n_centers=n_centers),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bq, m * k), lambda i, j: (i, 0)),
-            pl.BlockSpec((m, bq, bn), lambda i, j: (0, i, j)),
-        ],
-        out_specs=pl.BlockSpec((bq, bn), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct(
-            (lutmat.shape[0], codes_p.shape[2]), jnp.float32),
+        functools.partial(_pq_score_window_kernel, t=t, m=m, depth=depth),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(nq,),
+            in_specs=[
+                pl.BlockSpec((1, 8, 2 * m), lambda q, p: (q, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((1, t, rows, LANES),
+                                   lambda q, p: (q, 0, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((depth, rows, wl), jnp.int32),
+                            pltpu.SemaphoreType.DMA((depth,)),
+                            pltpu.VMEM((2 * m, 8, LANES), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((nq, t, rows, LANES), jnp.float32),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(lutmat, codes_p)
-    return out[:nq, :cand]
+    )(parts.reshape(-1).astype(jnp.int32), lut_cols, table)
+    return out.reshape(nq, t, rows * LANES)

@@ -33,6 +33,7 @@ from repro.core.mutable import MutableIVF
 from repro.data.vectors import make_manifold
 from repro.kernels.lloyd import (_grouped_argmin, lloyd_sweep,
                                  lloyd_sweep_batched, lloyd_sweep_pallas)
+from repro.kernels.pq_score import code_table_codes
 from repro.quant.pq import train_pq, train_pq_sequential
 
 
@@ -268,6 +269,10 @@ def test_delta_pack_identical_to_full_repack(ds):
                           np.asarray(full.part_ids))
     assert np.array_equal(np.asarray(delta.part_codes),
                           np.asarray(full.part_codes))
+    # both hold the code table of the host codes, at capacity width
+    assert np.array_equal(
+        code_table_codes(np.asarray(delta.part_codes), mut.pq.centers.shape[0],
+                         mut.part_ids.shape[1]), mut.part_codes)
     if delta.part_codes2 is not None:
         assert np.array_equal(np.asarray(delta.part_codes2),
                               np.asarray(full.part_codes2))

@@ -48,6 +48,56 @@ def test_pq_score_dtype_bf16_lut():
                                rtol=1e-4, atol=1e-4)
 
 
+# --------------------------------------------------------- pq_score_window
+def _window_case(nq, t, pmax, m, c=24, seed=0):
+    """Random 4-bit codes of c partitions, their code table, and nq
+    queries' t probed partitions (repeats allowed, as across queries)."""
+    from repro.kernels.pq_score import code_table
+    rng = np.random.default_rng(seed)
+    codes = rng.integers(0, 16, (c, pmax, m), dtype=np.uint8)
+    parts = rng.integers(0, c, (nq, t)).astype(np.int32)
+    return codes, jnp.asarray(code_table(codes)), jnp.asarray(parts)
+
+
+def _window_scores(luts, table, parts, pmax, **kw):
+    nq, t = parts.shape
+    out = ops.pq_score_window(luts, table, parts, **kw)
+    assert out.shape == (nq, t, table.shape[1] * 128)
+    return np.asarray(out)[:, :, :pmax].reshape(nq, t * pmax)
+
+
+@pytest.mark.parametrize("m", [16, 25])
+@pytest.mark.parametrize("t", [1, 16])
+@pytest.mark.parametrize("nq", [1, 8, 13, 256])
+def test_pq_score_window_matches_ref(nq, t, m):
+    """The in-place window kernel against the reference on the window
+    gathered from the raw codes: bitwise with integer-valued LUTs (every
+    summation order is exact), to f32 rounding with random ones, and
+    bitwise with random ones against the sum in subspace order. pmax
+    1,678 is glove-100's capacity, not a multiple of 128; m = 25 leaves
+    the last word one nibble."""
+    pmax = 1678
+    codes, table, parts = _window_case(nq, t, pmax, m, seed=nq * t + m)
+    window = jnp.asarray(codes[np.asarray(parts)].reshape(nq, t * pmax, m))
+    ints = jnp.round(_rand(6, nq, m, 16) * 100)
+    got = _window_scores(ints, table, parts, pmax)
+    assert np.array_equal(got, np.asarray(ref.pq_score_window_ref(ints,
+                                                                  window)))
+    luts = _rand(7, nq, m, 16)
+    got = _window_scores(luts, table, parts, pmax)
+    np.testing.assert_allclose(
+        got, np.asarray(ref.pq_score_window_ref(luts, window)),
+        rtol=1e-5, atol=1e-5)
+    # subspaces add in order j = 0..m-1, as the int32-window kernel added
+    # them: with random LUTs too the scores are that f32 sum, bitwise
+    lut, win = np.asarray(luts), np.asarray(window)
+    rows = np.arange(nq)[:, None]
+    in_order = np.zeros(win.shape[:2], np.float32)
+    for j in range(m):
+        in_order = in_order + lut[rows, j, win[:, :, j]]
+    assert np.array_equal(got, in_order)
+
+
 # ---------------------------------------------------------------- vq_assign
 @pytest.mark.parametrize("n,c,d", [
     (100, 16, 32), (513, 100, 64), (1000, 777, 128), (64, 2000, 100),

@@ -44,16 +44,18 @@ def _compile(fn, one_chip, *shapes):
 
 def _kernel_cases():
     from repro.kernels.lloyd import lloyd_sweep_pallas
-    from repro.kernels.pq_score import pq_score_pallas, pq_score_window_pallas
+    from repro.kernels.pq_score import (code_table_shape, pq_score_pallas,
+                                        pq_score_window_pallas)
     from repro.kernels.soar_assign import soar_assign_pallas
     from repro.kernels.tree_route import tree_route_pallas
     from repro.kernels.vq_assign import vq_assign_pallas
-    f32, i32, u8 = jnp.float32, jnp.int32, jnp.uint8
+    f32, i32 = jnp.float32, jnp.int32
     s = int(round(C ** 0.5))                  # TreeRouter default supers
     return {
         "pq_score_window": (
             functools.partial(pq_score_window_pallas, interpret=False),
-            ((BQ, M, 16), f32), ((BQ, 32 * 1000, M), u8)),
+            ((BQ, M, 16), f32), (code_table_shape(C, PMAX, M), i32),
+            ((BQ, 32), i32)),
         "pq_score": (functools.partial(pq_score_pallas, interpret=False),
                      ((BQ, M, 16), f32), ((65_536, M), i32)),
         "vq_assign": (functools.partial(vq_assign_pallas, interpret=False),
@@ -86,6 +88,7 @@ def test_serving_step_compiles_for_v5e(one_chip, monkeypatch):
     The backend branches of core/search.py are steered to their TPU side
     here, since the process itself runs on the CPU."""
     from repro.core import search
+    from repro.kernels.pq_score import code_table_shape
     from repro.quant.pq import PQCodebook
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
@@ -94,7 +97,8 @@ def test_serving_step_compiles_for_v5e(one_chip, monkeypatch):
 
     packed = search.PackedIVF(
         sds((C, D), jnp.float32), sds((C, PMAX), jnp.int32),
-        sds((C, PMAX, M), jnp.uint8), None, sds((C,), jnp.int32),
+        sds(code_table_shape(C, PMAX, M), jnp.int32), None,
+        sds((C,), jnp.int32),
         PQCodebook(sds((M, 16, D // M), jnp.float32)),
         sds(search.rerank_table_shape(N, D), jnp.float32))
     txt = search.search_jit_batched.lower(
@@ -114,6 +118,7 @@ def glove_step(request, one_chip):
     """The compiled entry computation of one `search_jit_batched` call
     at glove100's shapes for a described v5e, one compile per bq."""
     from repro.core import search
+    from repro.kernels.pq_score import code_table_shape
     from repro.quant.pq import PQCodebook
     mp = pytest.MonkeyPatch()
     mp.setattr(jax, "default_backend", lambda: "tpu")
@@ -124,7 +129,8 @@ def glove_step(request, one_chip):
     table = search.rerank_table_shape(GN, GD)
     packed = search.PackedIVF(
         sds((GC, GD), jnp.float32), sds((GC, GCAP), jnp.int32),
-        sds((GC, GCAP, GM), jnp.uint8), None, sds((GC,), jnp.int32),
+        sds(code_table_shape(GC, GCAP, GM), jnp.int32), None,
+        sds((GC,), jnp.int32),
         PQCodebook(sds((GM, 16, GD // GM), jnp.float32)),
         sds(table, jnp.float32))
     try:
@@ -160,3 +166,43 @@ def test_rerank_table_is_read_in_place_at_glove100(glove_step):
                for s in re.findall(r"\[([\d,]+)\]", result)):
             copies.append(line.strip()[:160])
     assert not copies, copies
+
+
+def _result_shapes(line):
+    """Array shapes of the result of one HLO instruction line."""
+    result = re.search(r" = (.+?) [\w.-]+\(", line)
+    return ([tuple(map(int, s.split(",")))
+             for s in re.findall(r"\[([\d,]+)\]", result.group(1))]
+            if result else [])
+
+
+def test_code_table_is_read_in_place_at_glove100(glove_step):
+    """The code table is committed row-major, (8, 128) tiles, and only the
+    window kernel reads it: no operation takes the table and makes an
+    array of its size, and no window of codes — (bq, t·pmax, m),
+    (m, bq, cand) or a gathered (·, pmax, m) slab — is made at all."""
+    from repro.kernels.pq_score import code_table_shape
+    _, entry = glove_step
+    table = code_table_shape(GC, GCAP, GM)
+    param = re.search(
+        r"%(packed_part_codes[\w.]*) = s32\[" + ",".join(map(str, table))
+        + r"\]\{([^}]*)\} parameter\(", entry)
+    assert param, "code table is not a parameter of the entry computation"
+    name, layout = param.groups()
+    assert layout == "2,1,0:T(8,128)"
+    operand = re.compile(r"[(, ]%" + re.escape(name) + r"[,)]")
+    readers, copies, windows = [], [], []
+    for line in entry.splitlines():
+        if "parameter(" in line:
+            continue
+        shapes = _result_shapes(line)
+        if operand.search(line):
+            readers.append(line.strip()[:160])
+            if any(math.prod(s) >= math.prod(table) for s in shapes):
+                copies.append(line.strip()[:160])
+        if any(GM in s and max(s) >= GCAP for s in shapes):
+            windows.append(line.strip()[:160])
+    assert not copies, copies
+    assert not windows, windows
+    assert len(readers) == 1 and "pq_score_window_pallas" in readers[0], \
+        readers
